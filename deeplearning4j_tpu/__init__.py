@@ -33,14 +33,6 @@ pjit-sharded) XLA program per train/inference step.
 __version__ = "0.1.0"
 
 from deeplearning4j_tpu.common.dtypes import DtypePolicy, get_policy, set_policy
-from deeplearning4j_tpu.common.env import env as _env
-
-if _env.compile_cache_dir:
-    # DL4J_TPU_COMPILE_CACHE=<dir>: persist XLA compiles across processes
-    # (and register the dl4j_compile_* metrics bridge)
-    from deeplearning4j_tpu.monitoring.compile import configure_compile_cache
-
-    configure_compile_cache()
 
 __all__ = [
     "DtypePolicy",

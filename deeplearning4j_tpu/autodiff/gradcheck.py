@@ -18,11 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    _enable_x64 = jax.enable_x64
-except AttributeError:  # pragma: no cover — pre-0.5 jax keeps it in experimental
-    from jax.experimental import enable_x64 as _enable_x64
-
 
 def grad_check(
     fn: Callable,
@@ -44,7 +39,7 @@ def grad_check(
     params). Returns {"ok": bool, "max_rel_error": float, "failures": [...]}.
     """
     argnums = tuple(range(len(args))) if argnums is None else argnums
-    with _enable_x64():
+    with jax.enable_x64():
         args = tuple(
             jnp.asarray(np.asarray(a, dtype=np.float64))
             if np.issubdtype(np.asarray(a).dtype, np.floating) else jnp.asarray(a)

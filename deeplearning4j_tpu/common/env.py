@@ -73,9 +73,6 @@ class Environment:
     # ragged tails stop compiling one XLA program per shape. Default ON;
     # =0 feeds batches through at their raw shapes.
     PAD_TAIL = "DL4J_TPU_PAD_TAIL"
-    # Persistent XLA compilation cache directory (monitoring/compile.py
-    # wires it plus the dl4j_compile_* metrics tier). Unset = no cache.
-    COMPILE_CACHE = "DL4J_TPU_COMPILE_CACHE"
     # SpanTracer ring-buffer capacity: oldest events are dropped (and
     # counted in dl4j_trace_events_dropped_total) past this many, so a
     # long-running gateway with tracing armed holds memory flat.
@@ -113,8 +110,6 @@ class Environment:
         self.import_opt = _flag(self.IMPORT_OPT, True)
         self.async_steps = max(0, _int(self.ASYNC_STEPS, 2))
         self.pad_tail = _flag(self.PAD_TAIL, True)
-        self.compile_cache_dir = (os.environ.get(self.COMPILE_CACHE)
-                                  or "").strip() or None
         self.trace_max_events = max(1, _int(self.TRACE_MAX_EVENTS, 100_000))
         self.tracing = _flag(self.TRACING)
         self.flight = _flag(self.FLIGHT)

@@ -340,7 +340,7 @@ class _CompileMonitor:
     """XLA compile-time instruments (monitoring/compile.py bridges
     jax.monitoring events here): every backend compile lands in
     ``dl4j_compile_seconds``/``dl4j_compiles_total``; persistent-cache
-    probes (DL4J_TPU_COMPILE_CACHE) in ``dl4j_compile_cache_events_total``
+    probes in ``dl4j_compile_cache_events_total``
     by hit/miss — cold-vs-warm process start is one /metrics read."""
 
     def __init__(self, reg: MetricsRegistry):

@@ -3,47 +3,59 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 from pathlib import Path
 from typing import Optional
 
 _ROOT = Path(__file__).resolve().parent.parent.parent
 _SRC = _ROOT / "native" / "dl4jtpu_native.cpp"
-# committed PORTABLE artifact: codec-free, no shared-library dependencies
-# beyond libc/libstdc++ — the fallback for toolchain-less hosts
-_SO = _ROOT / "native" / "build" / "libdl4jtpu.so"
-# locally-built variant (preferred): includes the JPEG/PNG decode front
-# when this host has the codec dev files; never committed
-_SO_LOCAL = _ROOT / "native" / "build" / "libdl4jtpu_local.so"
+_BUILD_DIR = _ROOT / "native" / "build"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _so_path() -> Path:
+    """The library for THIS source: the name carries a hash of the .cpp, so
+    a stale binary can never be loaded for an edited source, and a copied
+    tree (which does not preserve mtimes) rebuilds exactly when it must."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libdl4jtpu_{digest}.so"
+
+
 def _build(out: Path) -> bool:
     out.parent.mkdir(parents=True, exist_ok=True)
+    # built under a private name and renamed into place, so a concurrent
+    # process never dlopens a half-written file
+    tmp = out.with_name(f".{out.name}.{os.getpid()}")
     base = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-            "-shared", "-o", str(out), str(_SRC)]
-    # preferred: with the native JPEG/PNG decode front; fall back to a
-    # codec-less build on hosts without libjpeg/libpng dev files (the
-    # Python layer then decodes via PIL)
-    attempts = [base + ["-DDL4J_WITH_CODECS", "-ljpeg", "-lpng"], base]
+            "-shared", "-o", str(tmp), str(_SRC)]
+    # preferred: with the native JPEG/PNG decode front; without the
+    # libjpeg/libpng dev files a codec-less build (the Python layer then
+    # decodes via PIL)
     err = ""
-    for cmd in attempts:
+    for cmd in (base + ["-DDL4J_WITH_CODECS", "-ljpeg", "-lpng"], base):
         try:
             res = subprocess.run(cmd, capture_output=True, text=True,
                                  timeout=300)
-        except (FileNotFoundError, subprocess.TimeoutExpired):
-            return False
+        except (FileNotFoundError, subprocess.TimeoutExpired) as e:
+            err = f"{type(e).__name__}: {e}"
+            break
         if res.returncode == 0:
+            os.replace(tmp, out)
+            for old in out.parent.glob("libdl4jtpu_*.so"):
+                if old != out:          # builds of earlier sources
+                    old.unlink(missing_ok=True)
             return True
         err = res.stderr
-    import warnings
-
-    warnings.warn(f"native build failed:\n{err[-2000:]}")
+    tmp.unlink(missing_ok=True)
+    warnings.warn(f"native build failed; the pure-Python paths are used:\n"
+                  f"{err[-2000:]}")
     return False
 
 
@@ -171,13 +183,10 @@ def native_csv_parse(path, delimiter: str = ",", skip_header: bool = False,
         lib.dl4j_csv_free(h)
 
 
-def trim_compile_cache(cache_dir: Optional[str] = None,
-                       cap_bytes: int = 2 << 30) -> int:
-    """LRU-trim the persistent XLA compilation cache directory down to
+def trim_compile_cache(cache_dir: str, cap_bytes: int) -> int:
+    """LRU-trim a persistent XLA compilation cache directory down to
     cap_bytes (PJRT executable-cache management; libnd4j GraphHolder analog).
     Returns bytes evicted (0 if under cap), -1 on error/no native lib."""
-    cache_dir = cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                            str(_ROOT / ".jax_cache"))
     lib = load_native_lib()
     if lib is None or not os.path.isdir(cache_dir):
         return -1
@@ -186,60 +195,16 @@ def trim_compile_cache(cache_dir: Optional[str] = None,
 
 def load_native_lib() -> Optional[ctypes.CDLL]:
     """Build (if needed) and load the native library; None if unavailable.
-    One attempt per process — success and failure are both cached.
-
-    Load order: locally-built variant (rebuilt when the source is newer;
-    may carry codec dependencies this host satisfies by construction) ->
-    committed portable artifact (codec-free; loads anywhere a libc does).
-    A failed load of one candidate falls through to the next, so a
-    committed artifact with missing sonames can never disable the whole
-    native layer on a toolchain-less host."""
+    One attempt per process — success and failure are both cached, so a
+    failed build warns exactly once."""
     global _lib, _tried
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if _SRC.exists():
-            stale_local = (not _SO_LOCAL.exists()
-                           or _SO_LOCAL.stat().st_mtime
-                           < _SRC.stat().st_mtime)
-            if stale_local:
-                _build(_SO_LOCAL)      # failure is fine: fall back below
-        for cand in (_SO_LOCAL, _SO):
-            if not cand.exists():
-                continue
-            try:
-                _lib = _declare(ctypes.CDLL(str(cand)))
-                return _lib
-            except (OSError, AttributeError):
-                # OSError: unsatisfied dependency on this host;
-                # AttributeError: stale binary missing newer symbols —
-                # dlopen caches by pathname, so retry under a unique path
-                # after a rebuild when that is possible
-                _lib = None
-                if cand == _SO_LOCAL and _build(_SO_LOCAL):
-                    import shutil
-                    import tempfile
-
-                    alt = None
-                    try:
-                        # same dir: /tmp may be mounted noexec
-                        with tempfile.NamedTemporaryFile(
-                                suffix=".so", dir=str(cand.parent),
-                                delete=False) as f:
-                            alt = f.name
-                        shutil.copy2(cand, alt)
-                        _lib = _declare(ctypes.CDLL(alt))
-                        return _lib
-                    except (OSError, AttributeError):
-                        _lib = None
-                    finally:
-                        # the dlopen mapping survives the unlink on Linux
-                        if alt is not None:
-                            try:
-                                os.unlink(alt)
-                            except OSError:
-                                pass
+        so = _so_path()
+        if so.exists() or _build(so):
+            _lib = _declare(ctypes.CDLL(str(so)))
         return _lib
 
 

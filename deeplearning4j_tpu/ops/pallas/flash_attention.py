@@ -30,21 +30,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops.pallas.interpret import interpret_mode
 from deeplearning4j_tpu.ops.registry import register_impl
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _sds(shape, dtype, vma=None):
     """ShapeDtypeStruct with varying-mesh-axes annotation when running under
     shard_map (ring attention) with VMA checking on."""
     if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
-        except TypeError:  # pragma: no cover — pre-0.7 jax tracks no VMA
-            pass           # (shard_map runs check_rep there; see _compat)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
@@ -375,7 +369,7 @@ def flash_block_fwd(q, k, v, *, causal, scale, block_q=512, block_k=1024,
     """(o, lse) for one attention block pair; lse is [B,H,Tq,1] float32."""
     return _flash_forward(q, k, v, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k,
-                          interpret=_interpret(), kmask=kmask, vma=vma)
+                          interpret=interpret_mode(), kmask=kmask, vma=vma)
 
 
 def flash_block_bwd(q, k, v, do, lse, delta, *, causal, scale,
@@ -384,7 +378,7 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, causal, scale,
     delta = rowsum(do * o)."""
     return _flash_backward(q, k, v, do, lse, delta, causal=causal,
                            scale=scale, block_q=block_q, block_k=block_k,
-                           interpret=_interpret(), kmask=kmask, vma=vma)
+                           interpret=interpret_mode(), kmask=kmask, vma=vma)
 
 
 # --------------------------------------------------------------------------
@@ -396,14 +390,14 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, causal, scale,
 def _flash(q, k, v, kmask, causal, scale, block_q, block_k):
     out, _ = _flash_forward(q, k, v, causal=causal, scale=scale,
                             block_q=block_q, block_k=block_k,
-                            interpret=_interpret(), kmask=kmask)
+                            interpret=interpret_mode(), kmask=kmask)
     return out
 
 
 def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k):
     out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
-                              interpret=_interpret(), kmask=kmask)
+                              interpret=interpret_mode(), kmask=kmask)
     return out, (q, k, v, kmask, out, lse)
 
 
@@ -442,7 +436,7 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
         axis=-1, keepdims=True)
     dq, dk, dv = _flash_backward(q, k, v, g, lse, delta, causal=causal,
                                  scale=scale, block_q=bq, block_k=bk,
-                                 interpret=_interpret(), kmask=kmask)
+                                 interpret=interpret_mode(), kmask=kmask)
     dkm = None if kmask is None else jnp.zeros_like(kmask)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dkm
 

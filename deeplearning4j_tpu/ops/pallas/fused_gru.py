@@ -36,9 +36,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.common.env import env
-from deeplearning4j_tpu.ops.pallas.fused_lstm import (_interpret, _pad_gates,
+from deeplearning4j_tpu.ops.pallas.fused_lstm import (_pad_gates,
                                                       _pad_to_lanes,
                                                       _panel_dtype)
+from deeplearning4j_tpu.ops.pallas.interpret import interpret_mode
 from deeplearning4j_tpu.ops.registry import register_impl
 
 
@@ -208,7 +209,7 @@ def _project_gates(x, W, b, reverse):
 def _kernel_forward(x, h0, W, R, b, reverse, save_residuals=False):
     xg = _project_gates(x, W, b, reverse)
     out, hT, residuals = _fused_gru_recurrence(
-        xg, R, h0, interpret=_interpret(), save_residuals=save_residuals)
+        xg, R, h0, interpret=interpret_mode(), save_residuals=save_residuals)
     if reverse:
         out = jnp.flip(out, axis=0)
     return (jnp.swapaxes(out, 0, 1), hT), residuals
@@ -384,7 +385,7 @@ def _fused_bwd(reverse, res, g):
     hprev_k = jnp.concatenate([h0[None].astype(out_k.dtype), out_k[:-1]], 0)
 
     ga_r, ga_z, ga_n, dh0 = _bwd_recurrence(
-        residuals, R, hprev_k, dout_k, plan=plan, interpret=_interpret())
+        residuals, R, hprev_k, dout_k, plan=plan, interpret=interpret_mode())
     # hg_n's gradient (for dR's n block and the recurrent path already
     # inside the kernel) is r*ga_n; cheap elementwise, XLA fuses it here
     ga_hn = rr * ga_n

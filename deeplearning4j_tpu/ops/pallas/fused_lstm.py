@@ -66,18 +66,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.common.env import env
+from deeplearning4j_tpu.ops.pallas.interpret import interpret_mode
 from deeplearning4j_tpu.ops.registry import register_impl
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _panel_dtype(dtype):
     """MXU operand dtype for the R panels: bf16 on TPU (XLA's own default-
     precision truncation for f32 dots), operand dtype in interpret mode
     (XLA-CPU does full-f32 dots — the parity target off-TPU)."""
-    return jnp.bfloat16 if not _interpret() else dtype
+    return dtype if interpret_mode() else jnp.bfloat16
 
 
 def _lstm_kernel(xg_ref, r_ref, h0_ref, c0_ref, p_ref, out_ref, hT_ref,
@@ -160,9 +157,9 @@ def lstm_tile(B, H, rdtype_bytes=2, budget=13 << 20, save_residuals=False):
     boundaries. If the pipeline still allocates a second buffer for them,
     the under-count is bounded by 2*B*H*4 (<= 0.5 MB at every shipped
     chunk size) and is absorbed by the ~3 MB gap between this 13 MB budget
-    and the ~16 MB scoped-VMEM limit; `bench.py smoke` compiles the
-    batch-blocked plans on the real chip continuously, so a budget
-    violation surfaces there, not in production. R panels are bf16 on TPU
+    and the ~16 MB scoped-VMEM limit; `chip_smoke.py` compiles the
+    batch-blocked plans on the real chip, so a budget violation surfaces
+    there, not in production. R panels are bf16 on TPU
     (rdtype_bytes=2)."""
     for hb in (H, 1024, 512, 256, 128):
         if hb > H or H % hb:
@@ -344,7 +341,7 @@ def _kernel_forward(x, h0, c0, W, R, b, peephole, forget_gate_bias, reverse,
     H = R.shape[0]
     xg = _project_gates(x, W, b, H, forget_gate_bias, reverse)
     out, hT, cT, residuals = _fused_recurrence(
-        xg, R, h0, c0, peephole, interpret=_interpret(),
+        xg, R, h0, c0, peephole, interpret=interpret_mode(),
         save_residuals=save_residuals)
     if reverse:
         out = jnp.flip(out, axis=0)
@@ -573,7 +570,7 @@ def _fused_bwd(forget_gate_bias, reverse, res, g):
 
     dgi, dgf, dgo, dgz, dc0 = _bwd_recurrence(
         residuals, R, cprev_k, dout_k, g_cT, peephole, plan=plan,
-        interpret=_interpret())
+        interpret=interpret_mode())
     dgs = (dgi, dgf, dgo, dgz)
 
     # ---- everything non-sequential: big MXU matmuls outside the kernel

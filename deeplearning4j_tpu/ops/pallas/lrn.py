@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops.pallas.interpret import interpret_mode
 from deeplearning4j_tpu.ops.registry import register_impl
 
 
@@ -72,9 +73,8 @@ def _lrn_forward(x, *, depth, alpha, beta, k, block_rows, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
 def _lrn(x, depth, alpha, beta, k, block_rows):
-    interpret = jax.default_backend() != "tpu"
     return _lrn_forward(x, depth=depth, alpha=alpha, beta=beta, k=k,
-                        block_rows=block_rows, interpret=interpret)
+                        block_rows=block_rows, interpret=interpret_mode())
 
 
 def _lrn_fwd(x, depth, alpha, beta, k, block_rows):
@@ -134,9 +134,9 @@ def _lrn_backward(x, g, *, depth, alpha, beta, k, block_rows, interpret):
 
 
 def _lrn_bwd(depth, alpha, beta, k, block_rows, x, g):
-    interpret = jax.default_backend() != "tpu"
     return (_lrn_backward(x, g, depth=depth, alpha=alpha, beta=beta, k=k,
-                          block_rows=block_rows, interpret=interpret),)
+                          block_rows=block_rows,
+                          interpret=interpret_mode()),)
 
 
 _lrn.defvjp(_lrn_fwd, _lrn_bwd)

@@ -39,23 +39,18 @@ class OpImpl:
     requires: Callable[..., bool] | None = None    # structural: ALWAYS enforced
     priority: int = 0  # higher wins among applicable impls
 
-    def _check(self, pred, *args, **kwargs) -> bool:
-        if pred is None:
-            return True
-        try:
-            return bool(pred(*args, **kwargs))
-        except Exception:
-            return False
-
     def supported(self, *args, **kwargs) -> bool:
         """Structural applicability — the impl can produce a correct answer
         for this call at all (e.g. flash attention cannot take a mask). Not
-        bypassed by FORCE_PALLAS."""
-        return self._check(self.requires, *args, **kwargs)
+        bypassed by FORCE_PALLAS. Predicates are pure shape/dtype functions:
+        one that raises is a bug and propagates — it must never turn into a
+        silent choice of the XLA lowering."""
+        return self.requires is None or bool(self.requires(*args, **kwargs))
 
     def applicable(self, *args, **kwargs) -> bool:
         return (self.supported(*args, **kwargs)
-                and self._check(self.predicate, *args, **kwargs))
+                and (self.predicate is None
+                     or bool(self.predicate(*args, **kwargs))))
 
 
 class _Op:

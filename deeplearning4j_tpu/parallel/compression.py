@@ -24,10 +24,9 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from deeplearning4j_tpu.parallel._compat import shard_map
 
 
 def threshold_encode(g, thr):

@@ -57,7 +57,10 @@ class ParallelWrapper:
         from deeplearning4j_tpu.nn.multilayer import _unpack
 
         x, y, mask, label_mask = _unpack(ds)
-        n = np.asarray(x).shape[0] if not isinstance(x, (list, tuple, dict)) else None
+        # np.shape, not np.asarray(x).shape: a batch the prefetch thread has
+        # already sharded onto the mesh must not be gathered back to the
+        # host just to read its leading dimension
+        n = np.shape(x)[0] if not isinstance(x, (list, tuple, dict)) else None
         dp = self.mesh.shape["data"]
         if n is not None and n % dp:
             raise ValueError(f"batch size {n} not divisible by data-parallel degree {dp}")

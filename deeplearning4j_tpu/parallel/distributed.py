@@ -47,14 +47,6 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     if process_id is None and "PROCESS_ID" in os.environ:
         process_id = int(os.environ["PROCESS_ID"])
     if coordinator_address is not None or num_processes is not None:
-        try:
-            # pre-0.5 jax needs the CPU cross-process transport selected
-            # explicitly before backend init; newer jax defaults to gloo
-            # (no-op elsewhere: the option only affects the CPU backend)
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
-
         def _connect():
             plan = faults.active()
             if plan is not None and plan.fires("coord_connect"):

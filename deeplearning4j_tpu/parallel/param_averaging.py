@@ -25,11 +25,10 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from deeplearning4j_tpu import monitoring
-from deeplearning4j_tpu.parallel._compat import shard_map
 
 
 class ParameterAveragingTrainer:

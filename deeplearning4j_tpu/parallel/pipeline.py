@@ -23,12 +23,12 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import DeviceMesh
 
-from deeplearning4j_tpu.parallel._compat import pvary as _pvary, shard_map
+_pvary = functools.partial(lax.pcast, to="varying")
 
 
 def stack_stage_params(stage_params_list):

@@ -30,10 +30,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from deeplearning4j_tpu.parallel._compat import pvary as _pvary, shard_map
+_pvary = functools.partial(lax.pcast, to="varying")
 
 
 def _ring_attention_local(q, k, v, kmask=None, *, axis, causal, scale):

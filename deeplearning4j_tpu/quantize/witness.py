@@ -16,6 +16,7 @@ over the whole jaxpr, mirroring the zero-overhead monitoring guard pattern.
 from __future__ import annotations
 
 import jax
+from jax.extend import core as jax_core
 
 
 def _walk(jaxpr):
@@ -29,9 +30,9 @@ def _walk(jaxpr):
 
 
 def _subjaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax_core.Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for item in v:

@@ -596,25 +596,29 @@ class TestPrefetchSharding:
 
 # ---------------------------------------------------------- compile cache
 class TestCompileCache:
-    def test_compile_metrics_bridge(self, tmp_path):
-        """Satellite: DL4J_TPU_COMPILE_CACHE wires the persistent cache and
-        the dl4j_compile_* monitoring tier — backend compiles show up in
-        the registry when monitoring is on."""
+    def test_checkout_cache_and_metrics_bridge(self):
+        """One rule: without JAX_COMPILATION_CACHE_DIR the cache is the
+        fixed <checkout>/.jax_cache (what conftest already configured), and
+        the call wires the dl4j_compile_* monitoring tier — backend compiles
+        show up in the registry when monitoring is on."""
+        import os
+
         import jax
         import jax.numpy as jnp
 
         from deeplearning4j_tpu import monitoring
         from deeplearning4j_tpu.monitoring.compile import (
-            configure_compile_cache, configured_cache_dir,
+            CHECKOUT_CACHE_DIR, configure_compile_cache,
         )
 
-        saved = jax.config.jax_compilation_cache_dir
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            assert configure_compile_cache() == CHECKOUT_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == CHECKOUT_CACHE_DIR
         try:
             monitoring.reset()
             monitoring.enable()
-            d = configure_compile_cache(str(tmp_path / "xla_cache"))
-            assert d and configured_cache_dir() == d
-            assert jax.config.jax_compilation_cache_dir == d
 
             @jax.jit
             def f(a):
@@ -625,8 +629,33 @@ class TestCompileCache:
             assert reg.get("dl4j_compiles_total").value >= 1
             assert reg.get("dl4j_compile_seconds").count >= 1
         finally:
-            jax.config.update("jax_compilation_cache_dir", saved)
             monitoring.reset()
+
+    def test_outside_directory_is_left_alone(self, tmp_path):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX already uses it: the
+        function sets no other directory and never trims one it does not
+        own. Checked in a fresh interpreter, where JAX reads the variable."""
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        outside = tmp_path / "outside_cache"
+        outside.mkdir()
+        (outside / "theirs.bin").write_bytes(b"x" * 4096)
+        code = (
+            "import jax\n"
+            "from deeplearning4j_tpu.monitoring import compile as c\n"
+            "c.CHECKOUT_CACHE_CAP_BYTES = 1\n"
+            "d = c.configure_compile_cache()\n"
+            "assert d == jax.config.jax_compilation_cache_dir, d\n"
+            "print(d)\n")
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(outside))
+        out = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == str(outside)
+        assert (outside / "theirs.bin").exists()
 
     def test_bridge_silent_when_monitoring_off(self, tmp_path):
         import jax

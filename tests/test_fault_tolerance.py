@@ -20,7 +20,6 @@ _TRAIN_SCRIPT = r"""
 import sys, os
 sys.path.insert(0, {repo!r})
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 from deeplearning4j_tpu.nn import InputType, MultiLayerNetwork, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer

@@ -29,7 +29,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
-jax.config.update("jax_platforms", "cpu")
 pid = int(sys.argv[1]); port = sys.argv[2]
 from deeplearning4j_tpu.parallel import initialize_distributed
 info = initialize_distributed(coordinator_address=f"127.0.0.1:{port}",
@@ -37,7 +36,7 @@ info = initialize_distributed(coordinator_address=f"127.0.0.1:{port}",
 assert info["process_count"] == 2, info
 assert info["global_devices"] == 8, info
 import numpy as np, jax.numpy as jnp
-from deeplearning4j_tpu.parallel._compat import shard_map  # jax-version shim
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 devs = np.array(jax.devices()).reshape(8)
 mesh = Mesh(devs, ("data",))
@@ -114,7 +113,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
-jax.config.update("jax_platforms", "cpu")
 pid = int(sys.argv[1]); port = sys.argv[2]
 from deeplearning4j_tpu.parallel import initialize_distributed
 info = initialize_distributed(coordinator_address=f"127.0.0.1:{port}",
@@ -229,7 +227,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
-jax.config.update("jax_platforms", "cpu")
 pid = int(sys.argv[1]); port = sys.argv[2]
 ckpt_dir = sys.argv[3]; phase = sys.argv[4]     # "crash" | "resume"
 from deeplearning4j_tpu.parallel import initialize_distributed
