@@ -3,7 +3,7 @@
 Reference analog: org.nd4j.config.ND4JEnvironmentVars (backend selection,
 workspace debug, OMP threads) and libnd4j's Environment singleton
 (verbose/debug toggles over JNI). Here the flags steer op-impl selection
-(Pallas vs plain XLA), debug checks, and profiling — the things that still
+(Pallas vs plain XLA), debug checks, and monitoring — the things that still
 exist in an XLA world.
 """
 
@@ -41,8 +41,6 @@ class Environment:
     NAN_PANIC = "DL4J_TPU_NAN_PANIC"
     # Verbose op-dispatch logging (libnd4j Environment::setVerbose analog).
     VERBOSE = "DL4J_TPU_VERBOSE"
-    # Per-op timing profiler (org.nd4j.linalg.profiler.OpProfiler analog).
-    PROFILING = "DL4J_TPU_PROFILING"
     # Unified monitoring layer (metrics registry + fit-loop instrumentation,
     # deeplearning4j_tpu/monitoring). Default OFF: the fit hot path then
     # performs no registry/tracer calls (tests enforce zero overhead).
@@ -103,7 +101,6 @@ class Environment:
         self.force_pallas = _flag(self.FORCE_PALLAS)
         self.nan_panic = _flag(self.NAN_PANIC)
         self.verbose = _flag(self.VERBOSE)
-        self.profiling = _flag(self.PROFILING)
         self.monitoring = _flag(self.MONITORING)
         self.lstm_scan_bwd = _flag(self.LSTM_SCAN_BWD)
         self.gru_scan_bwd = _flag(self.GRU_SCAN_BWD)

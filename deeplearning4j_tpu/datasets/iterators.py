@@ -16,6 +16,7 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.datasets.dataset import DataSet
 
 
@@ -156,11 +157,15 @@ class AsyncPrefetchIterator(DataSetIterator):
 
         def worker():
             try:
-                for ds in self.inner:
+                for seq, ds in enumerate(self.inner):
                     if stop.is_set():
                         return
                     if self.device_put or self.sharder is not None:
-                        ds = self._stage(ds)
+                        mon = monitoring.fit_monitor()
+                        if mon is None:
+                            ds = self._stage(ds)
+                        else:
+                            ds = mon.stage(self._stage, ds, seq, q.qsize())
                     # bounded put, re-checking stop: a consumer that
                     # abandons the generator mid-epoch would otherwise
                     # leave this thread blocked on a full queue forever

@@ -239,17 +239,17 @@ class Guardrail:
                     lst.iteration_done(model, step_i, epoch_i, value)
                 result = value
         elif window is not None:
-            with mon.phase("dispatch"):
+            with mon.phase("dispatch", step=step_i):
                 loss, word = self._dispatch(model, step_i, data, masks, 0.0)
             result = self._submit(model, window, step_i, loss, word)
         else:
-            with mon.phase("device_step"):
+            with mon.phase("device_step", step=step_i):
                 loss, word = self._dispatch(model, step_i, data, masks, 0.0)
                 # the host fetch is the device sync: step time includes it
                 w = _fetch_word(word)
             value = self._deliver_sync(model, step_i, epoch_i, w)
             model._score_value = value
-            with mon.phase("listeners"):
+            with mon.phase("listeners", step=step_i):
                 for lst in model.listeners:
                     lst.iteration_done(model, step_i, epoch_i, value)
             mon.iteration_done(value)

@@ -8,8 +8,10 @@ memory/GC reporting. Here the two production-grade halves it lacked:
   thread-safe) with Prometheus text exposition, scraped from ``GET
   /metrics`` on both the UI server and every serving/ server;
 - a host-side **SpanTracer** (``span("name")`` context manager, nestable,
-  thread-aware) emitting Chrome trace-event JSON for Perfetto — the HOST
-  timeline complementing ``profiler.trace()``'s device timeline.
+  thread-aware, on ``time.time_ns()`` — the profiler's clock) kept in a
+  ring in memory: read out with ``spans()``, saved as Chrome trace-event
+  JSON for Perfetto, and mirrored as ``TraceAnnotation``s into
+  ``profiler.trace()``'s own trace.
 
 Instrumented subsystems (fit loops, local-SGD rounds, serving, checkpoints)
 fetch their instrument bundle through the ``*_monitor()`` accessors below,
@@ -18,18 +20,23 @@ is to skip ALL instrumentation on ``None``, so the default-off hot path
 performs exactly one boolean check and no registry/tracer calls (enforced
 by tests/test_monitoring.py's zero-overhead guard).
 
-Enablement: the ``DL4J_TPU_MONITORING`` env flag (default off, read at
-import) or ``monitoring.enable()`` / ``disable()`` at runtime. Tracing is a
-separate, additive switch: ``start_tracing()`` installs the global tracer
-(spans are recorded only while one is installed), ``stop_tracing(path)``
-detaches it and optionally writes the trace JSON.
+Enablement, one switch: the ``DL4J_TPU_MONITORING`` env flag (default off,
+read at import) or ``monitoring.enable()`` / ``disable()`` at runtime.
+``enable()`` installs the span ring if none is installed, so an enabled fit
+path records its spans and its histograms together; ``spans()`` reads them
+out (after ``disable()`` too). ``start_tracing()`` / ``stop_tracing(path)``
+bracket a stretch to save as a Chrome trace: a fresh ring in, the ring out
+and optionally written.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import time
 from typing import Optional
+
+import jax
 
 from deeplearning4j_tpu.common.env import env
 from deeplearning4j_tpu.monitoring import flight
@@ -38,11 +45,13 @@ from deeplearning4j_tpu.monitoring.registry import (
     DEFAULT_BUCKETS, SIZE_BUCKETS, Counter, Gauge, Histogram, MetricFamily,
     MetricsRegistry,
 )
-from deeplearning4j_tpu.monitoring.tracing import SpanTracer, validate_nesting
+from deeplearning4j_tpu.monitoring.tracing import (
+    Span, SpanTracer, validate_nesting,
+)
 
 _REGISTRY = MetricsRegistry()
 _enabled: bool = env.monitoring
-_tracer: Optional[SpanTracer] = None
+_tracer: Optional[SpanTracer] = SpanTracer() if _enabled else None
 _fit_mon = None
 _serving_mon = None
 _localsgd_mon = None
@@ -67,8 +76,12 @@ def enabled() -> bool:
 
 
 def enable() -> None:
-    global _enabled
+    """Switch monitoring on: instrument bundles record into the registry and
+    spans into the ring (installed here if none is)."""
+    global _enabled, _tracer
     _enabled = True
+    if _tracer is None:
+        _tracer = SpanTracer()
 
 
 def disable() -> None:
@@ -85,8 +98,8 @@ def reset() -> None:
     global _recovery_mon, _compile_mon, _generate_mon, _quantize_mon
     global _tenant_mon, _slo_mon, _guardrail_mon
     _REGISTRY = MetricsRegistry()
-    _tracer = None
     _enabled = env.monitoring
+    _tracer = SpanTracer() if _enabled else None
     _fit_mon = _serving_mon = _localsgd_mon = _ckpt_mon = None
     _import_mon = _recovery_mon = _compile_mon = _generate_mon = None
     _quantize_mon = _tenant_mon = _slo_mon = _guardrail_mon = None
@@ -110,9 +123,10 @@ def start_tracing() -> SpanTracer:
 
 def stop_tracing(path: Optional[str] = None) -> Optional[SpanTracer]:
     """Detach the global tracer; with ``path``, save its Chrome trace
-    JSON there first. Returns the detached tracer (None if none active)."""
+    JSON there first. Returns the detached tracer (None if none active).
+    While monitoring is enabled a fresh ring takes its place."""
     global _tracer
-    t, _tracer = _tracer, None
+    t, _tracer = _tracer, (SpanTracer() if _enabled else None)
     if t is not None and path is not None:
         t.save(path)
     return t
@@ -120,6 +134,14 @@ def stop_tracing(path: Optional[str] = None) -> Optional[SpanTracer]:
 
 def tracer() -> Optional[SpanTracer]:
     return _tracer
+
+
+def spans() -> list:
+    """The spans recorded so far, as plain ``Span`` tuples (name, start_ns,
+    end_ns, tid, thread, id, parent, args) on ``time.time_ns()``; empty with
+    no ring installed."""
+    t = _tracer
+    return [] if t is None else t.spans()
 
 
 @contextlib.contextmanager
@@ -137,12 +159,15 @@ def span(name: str, **args):
 
 # ---- per-subsystem instrument bundles -----------------------------------
 class _FitMonitor:
-    """Fit-loop instruments: the per-iteration wall-time split as histograms
+    """Fit-path instruments: the per-iteration wall-time split as histograms
     + spans, plus iteration counter and score gauge. Sync mode times
     "device_step" (dispatch + host fetch, i.e. the device sync); async mode
     (optimize/async_dispatch) splits that into "dispatch" (enqueue only,
     host never blocks) and "drain" (the deferred host fetch) — the
-    host-blocked fraction of a fit is then drain/(dispatch+drain)."""
+    host-blocked fraction of a fit is then drain/(dispatch+drain). Under the
+    async window the host has no measure of the device's time at all: the
+    profiler's trace has, and the spans here share its clock. The prefetch
+    thread's staging is "prefetch.stage", with the bytes it moved."""
 
     def __init__(self, reg: MetricsRegistry):
         self.reg = reg
@@ -167,37 +192,61 @@ class _FitMonitor:
                 "dl4j_train_listener_seconds",
                 "Per-iteration time in host-side listener callbacks"),
         }
+        self.stage_seconds = reg.histogram(
+            "dl4j_prefetch_stage_seconds",
+            "Prefetch thread: one host batch staged and ready on the device")
+        self.staged_bytes = reg.counter(
+            "dl4j_prefetch_staged_bytes_total",
+            "Bytes the prefetch thread has staged onto the device")
+        self.queue_depth = reg.gauge(
+            "dl4j_prefetch_queue_depth",
+            "Staged batches waiting in the prefetch queue at hand-over")
 
     @contextlib.contextmanager
-    def phase(self, name: str):
-        """Time one fit phase into its histogram (and the tracer, when a
-        trace is active)."""
-        t = _tracer
-        cm = t.span("fit." + name) if t is not None else None
-        if cm is not None:
-            cm.__enter__()
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._hists[name].observe(time.perf_counter() - t0)
-            if cm is not None:
-                cm.__exit__(None, None, None)
+    def phase(self, name: str, **ids):
+        """Time one fit phase into its histogram and as the span
+        ``fit.<name>`` carrying ``ids`` (``step``: the model's step count
+        the work belongs to; ``seq``: the batch's ordinal)."""
+        with span("fit." + name, **ids):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self._hists[name].observe(time.perf_counter() - t0)
 
     def iteration_done(self, score: float) -> None:
         self.iterations.inc()
         self.score.set(float(score))
 
-    def wrap_batches(self, data):
-        """Iterate ``data`` timing each pull as the data-wait phase."""
+    def wrap_batches(self, data, model):
+        """Iterate ``data`` timing each pull as the data-wait phase of the
+        step of ``model`` it feeds."""
         it = iter(data)
-        while True:
-            with self.phase("data_wait"):
+        for seq in itertools.count():
+            with self.phase("data_wait", step=model.step_count, seq=seq):
                 try:
                     ds = next(it)
                 except StopIteration:
                     return
             yield ds
+
+    def stage(self, put, ds, seq: int, depth: int):
+        """The prefetch thread's staging of batch ``seq`` as the span
+        ``prefetch.stage``. ``put`` returns once the transfer is enqueued,
+        so the span waits until the staged arrays are ready: it times the
+        re-tiling and the transfer, not the enqueue."""
+        with span("prefetch.stage", seq=seq):
+            t0 = time.perf_counter()
+            staged = put(ds)
+            arrays = [a for a in (staged.features, staged.labels,
+                                  staged.features_mask, staged.labels_mask)
+                      if a is not None]
+            jax.block_until_ready(arrays)
+            self.stage_seconds.observe(time.perf_counter() - t0)
+        self.staged_bytes.inc(sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(arrays)))
+        self.queue_depth.set(depth)
+        return staged
 
 
 class _ServingMonitor:
@@ -557,10 +606,11 @@ from deeplearning4j_tpu.monitoring.context import (  # noqa: E402 (cycle: contex
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricFamily", "MetricsRegistry",
-    "SpanTracer", "MetricsListener", "DEFAULT_BUCKETS", "SIZE_BUCKETS",
+    "Span", "SpanTracer", "MetricsListener", "DEFAULT_BUCKETS", "SIZE_BUCKETS",
     "FlightRecorder", "RequestTrace", "RequestTracer", "flight",
     "registry", "enabled", "enable", "disable", "reset", "metrics_text",
-    "start_tracing", "stop_tracing", "tracer", "span", "validate_nesting",
+    "start_tracing", "stop_tracing", "tracer", "span", "spans",
+    "validate_nesting",
     "fit_monitor", "serving_monitor", "localsgd_monitor",
     "checkpoint_monitor", "import_monitor", "recovery_monitor",
     "compile_monitor", "generate_monitor", "quantize_monitor",

@@ -7,9 +7,10 @@ compilation, and a fit loop that recompiles per ragged tail shape hides that
 cost inside ordinary step time. Two tools here:
 
 - **install_hooks()** registers ``jax.monitoring`` listeners that land every
-  backend compile in ``dl4j_compile_seconds``/``dl4j_compiles_total`` (and
-  persistent-cache hits/misses in ``dl4j_compile_cache_events_total``) when
-  monitoring is enabled — cold-vs-warm compile time becomes a /metrics
+  backend compile in ``dl4j_compile_seconds``/``dl4j_compiles_total`` and as
+  a ``compile`` span under the span that caused it (and persistent-cache
+  hits/misses in ``dl4j_compile_cache_events_total``) when monitoring is
+  enabled — cold-vs-warm compile time becomes a /metrics
   read. Registration is idempotent and the callbacks fire only on compiles
   and cache probes, never on the step hot path.
 - **configure_compile_cache()** is the one place that decides where JAX's
@@ -53,6 +54,11 @@ def install_hooks() -> bool:
             return
         mon.compiles.inc()
         mon.compile_seconds.observe(duration)
+        tracer = monitoring.tracer()
+        if tracer is not None:
+            # an already-measured span whose parent is the span open on
+            # this thread: a step that recompiles names itself
+            tracer.complete("compile", duration)
 
     def _on_event(event: str, **kwargs) -> None:
         kind = None

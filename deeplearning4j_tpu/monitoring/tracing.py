@@ -1,4 +1,4 @@
-"""Host-side span tracer emitting Chrome trace-event JSON.
+"""Host-side span tracer: the program's one span spine.
 
 Complements profiler.trace() (the jax.profiler DEVICE timeline) with the
 HOST timeline the reference never had: where a training step's wall time
@@ -7,11 +7,19 @@ Spans are nestable context managers and thread-aware (each span records the
 emitting thread's id), so serving worker threads and the fit loop interleave
 correctly on separate tracks.
 
-The output is the Chrome trace-event format — begin/end ("B"/"E") event
+One clock: a span starts and ends at ``time.time_ns()``, the clock the
+profiler stamps its trace with (the ``Task Environment`` plane's
+``profile_start_time``), so a span lands on a device trace's timeline by one
+subtraction. Each span also records the span that caused it — the
+enclosing span on its thread, or an explicit ``parent=`` — and enters a
+``jax.profiler.TraceAnnotation`` of the same name and arguments, so a
+profiler trace taken with its host tracer on shows the same spans beside
+the device's operations. ``spans()`` reads the ring out as plain tuples.
+
+The saved file is the Chrome trace-event format — begin/end ("B"/"E") event
 pairs, "X" complete events, and "M" metadata under ``{"traceEvents": [...]}``
 — which Perfetto (https://ui.perfetto.dev) and chrome://tracing load
-directly. Timestamps are microseconds from tracer start (``perf_counter``
-based, so spans are comparable across threads of this process).
+directly. Its timestamps are microseconds from tracer start.
 
 The event buffer is a RING: past ``max_events`` (constructor arg, else
 ``DL4J_TPU_TRACE_MAX_EVENTS``, default 100k) the oldest events are dropped
@@ -28,13 +36,30 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from deeplearning4j_tpu.common.env import env
+
+
+class Span(NamedTuple):
+    """One recorded span, as ``SpanTracer.spans()`` reads it out. Times are
+    ``time.time_ns()``; ``parent`` is the ``id`` of the span that caused it."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    tid: int
+    thread: str
+    id: int
+    parent: Optional[int]
+    args: Dict
 
 
 def _json_safe(v):
@@ -61,7 +86,9 @@ class SpanTracer:
         self._cap = max(1, int(max_events if max_events is not None
                                else env.trace_max_events))
         self._events: Deque[Dict] = collections.deque()
-        self._t0 = time.perf_counter()
+        self.start_ns = time.time_ns()
+        self._ids = itertools.count(1)
+        self._open = threading.local()      # per thread: ids of open spans
         self._pid = os.getpid()
         self._named_tids: set = set()
         self._meta: List[Dict] = [{
@@ -69,8 +96,13 @@ class SpanTracer:
             "args": {"name": process_name}}]
         self.dropped = 0
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    def _us(self, t_ns: int) -> float:
+        return (t_ns - self.start_ns) * 1e-3
+
+    def current(self) -> Optional[int]:
+        """The id of the innermost span open on this thread."""
+        stack = getattr(self._open, "stack", None)
+        return stack[-1] if stack else None
 
     def _append(self, ev: Dict) -> None:
         """Ring append: names the emitting thread on first sight, evicts
@@ -99,24 +131,41 @@ class SpanTracer:
                 ).inc()
 
     @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Time a section as a begin/end event pair on this thread."""
+    def span(self, name: str, parent: Optional[int] = None, **args):
+        """Time a section as a begin/end event pair on this thread, and as
+        a ``TraceAnnotation`` in the profiler's own trace. ``parent`` names
+        the span that caused this one where that is not the enclosing span
+        on this thread."""
         tid = threading.get_ident()
-        begin: Dict = {"name": name, "ph": "B", "ts": self._now_us(),
-                       "pid": self._pid, "tid": tid}
-        if args:
-            begin["args"] = {k: _json_safe(v) for k, v in args.items()}
-        self._append(begin)
-        try:
-            yield self
-        finally:
-            self._append({"name": name, "ph": "E", "ts": self._now_us(),
-                          "pid": self._pid, "tid": tid})
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        sid = next(self._ids)
+        if parent is None and stack:
+            parent = stack[-1]
+        args = {k: _json_safe(v) for k, v in args.items()}
+        with TraceAnnotation(name, **args):
+            t = time.time_ns()
+            begin: Dict = {"name": name, "ph": "B", "ts": self._us(t),
+                           "pid": self._pid, "tid": tid, "t_ns": t,
+                           "sid": sid, "parent": parent}
+            if args:
+                begin["args"] = args
+            self._append(begin)
+            stack.append(sid)
+            try:
+                yield self
+            finally:
+                stack.pop()
+                t = time.time_ns()
+                self._append({"name": name, "ph": "E", "ts": self._us(t),
+                              "pid": self._pid, "tid": tid, "t_ns": t,
+                              "sid": sid})
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker event (thread-scoped)."""
         ev: Dict = {"name": name, "ph": "i", "s": "t",
-                    "ts": self._now_us(), "pid": self._pid,
+                    "ts": self._us(time.time_ns()), "pid": self._pid,
                     "tid": threading.get_ident()}
         if args:
             ev["args"] = {k: _json_safe(v) for k, v in args.items()}
@@ -124,16 +173,46 @@ class SpanTracer:
 
     def complete(self, name: str, dur_s: float, **args) -> None:
         """Record an already-measured span (ended ~now, ``dur_s`` long) as
-        an "X" complete event — how request-trace spans
-        (monitoring/context.py) mirror into the process timeline without
+        an "X" complete event, caused by the span open on this thread — how
+        request-trace spans (monitoring/context.py) and compiles
+        (monitoring/compile.py) mirror into the process timeline without
         holding the tracer lock for their whole duration."""
-        dur_us = max(0.0, float(dur_s)) * 1e6
-        ev: Dict = {"name": name, "ph": "X",
-                    "ts": max(0.0, self._now_us() - dur_us), "dur": dur_us,
-                    "pid": self._pid, "tid": threading.get_ident()}
+        dur_ns = int(max(0.0, float(dur_s)) * 1e9)
+        t = max(self.start_ns, time.time_ns() - dur_ns)
+        ev: Dict = {"name": name, "ph": "X", "ts": self._us(t),
+                    "dur": dur_ns * 1e-3, "pid": self._pid,
+                    "tid": threading.get_ident(), "t_ns": t,
+                    "sid": next(self._ids), "parent": self.current()}
         if args:
             ev["args"] = {k: _json_safe(v) for k, v in args.items()}
         self._append(ev)
+
+    def spans(self) -> List[Span]:
+        """Every finished span still in the ring, by start: begin/end pairs
+        and already-measured ("X") spans. A span whose begin the ring has
+        dropped, or that is still open, is left out."""
+        with self._lock:
+            events = list(self._events)
+            threads = {m["tid"]: m["args"]["name"] for m in self._meta
+                       if m["name"] == "thread_name"}
+        out, begun = [], {}
+        for ev in events:
+            ph = ev["ph"]
+            if ph == "B":
+                begun[ev["sid"]] = ev
+            elif ph == "E":
+                b = begun.pop(ev["sid"], None)
+                if b is not None:
+                    out.append(Span(b["name"], b["t_ns"], ev["t_ns"], b["tid"],
+                                    threads.get(b["tid"], ""), b["sid"],
+                                    b["parent"], b.get("args", {})))
+            elif ph == "X":
+                out.append(Span(ev["name"], ev["t_ns"],
+                                ev["t_ns"] + int(ev["dur"] * 1e3), ev["tid"],
+                                threads.get(ev["tid"], ""), ev["sid"],
+                                ev["parent"], ev.get("args", {})))
+        out.sort(key=lambda s: s.start_ns)
+        return out
 
     def events(self) -> List[Dict]:
         with self._lock:

@@ -34,6 +34,13 @@ from deeplearning4j_tpu.optimize.async_dispatch import (
 from deeplearning4j_tpu.optimize.updaters import NoOp, get_updater
 
 
+def _scope_name(name: str, vertex) -> str:
+    """``<vertex>.<LayerClass>`` (the vertex's own class where it holds no
+    layer): a trace's reader tells layer kinds apart without a table."""
+    kind = vertex.layer if isinstance(vertex, LayerVertex) else vertex
+    return f"{name}.{type(kind).__name__}"
+
+
 class ComputationGraph:
     def __init__(self, conf: ComputationGraphConfiguration):
         if not conf.topological_order:
@@ -117,21 +124,26 @@ class ComputationGraph:
             k = jax.random.fold_in(rng, i) if rng is not None else None
             p = params.get(name, {})
             s = state.get(name, {})
+            # <vertex>.<LayerClass>: JAX writes jvp(<scope>) on the forward and
+            # transpose(jvp(<scope>)) on the backward operations' op_name
+            scope = jax.named_scope(_scope_name(name, v))
             if want_preout and name in self._output_vertices and isinstance(v, LayerVertex) \
                     and hasattr(v.layer, "preout"):
                 out_feats[name] = ins[0]
-                preouts[name] = v.layer.preout(p, ins[0])
+                with scope:
+                    preouts[name] = v.layer.preout(p, ins[0])
                 acts[name] = preouts[name]
                 if s:
                     new_state[name] = s
                 continue
-            if self.conf.remat and train:
-                out, s2 = jax.checkpoint(
-                    lambda pp, ss, ii, kk, _v=v: _v.apply(
-                        pp, ss, ii, train=True, rng=kk, masks=masks)
-                )(p, s, ins, k)
-            else:
-                out, s2 = v.apply(p, s, ins, train=train, rng=k, masks=masks)
+            with scope:
+                if self.conf.remat and train:
+                    out, s2 = jax.checkpoint(
+                        lambda pp, ss, ii, kk, _v=v: _v.apply(
+                            pp, ss, ii, train=True, rng=kk, masks=masks)
+                    )(p, s, ins, k)
+                else:
+                    out, s2 = v.apply(p, s, ins, train=train, rng=k, masks=masks)
             acts[name] = out
             if s2:
                 new_state[name] = s2
@@ -291,6 +303,17 @@ class ComputationGraph:
         semantics)."""
         acts, new_state, preouts, out_feats = self._forward(
             params, state, inputs, train, rng, masks=masks, want_preout=True)
+        with jax.named_scope("loss"):
+            loss = self._loss_terms(params, state, acts, new_state, preouts,
+                                    out_feats, labels, masks, labels_masks,
+                                    denom)
+        return loss, new_state
+
+    def _loss_terms(self, params, state, acts, new_state, preouts, out_feats,
+                    labels, masks, labels_masks, denom):
+        """The outputs' losses plus the regularization terms, from one
+        forward's activations. A center-loss head writes its persisted
+        centers into ``new_state``."""
         from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
 
         # the shared [B, T] sequence mask (the same list contract the
@@ -386,7 +409,7 @@ class ComputationGraph:
         for name, v in self.conf.vertices.items():
             if isinstance(v, LayerVertex) and name in params:
                 loss = loss + v.layer.regularization(params[name])
-        return loss, new_state
+        return loss
 
     def _make_train_step(self, guarded: bool = False,
                          clip_active: bool = True):
@@ -410,12 +433,14 @@ class ComputationGraph:
             if guarded:
                 # screen the RAW grads (NaN survives any clip scale, so the
                 # clips below cannot launder a non-finite gradient)
-                grads, word = _sentinel.screen(grads, loss, ctrl,
-                                               with_clip=clip_active)
-            if max_norm > 0:
-                grads = global_norm_clip(grads, max_norm)
-            if conf_clipnorm > 0:
-                grads = global_norm_clip(grads, conf_clipnorm)
+                with jax.named_scope("guard"):
+                    grads, word = _sentinel.screen(grads, loss, ctrl,
+                                                   with_clip=clip_active)
+            with jax.named_scope("clip"):
+                if max_norm > 0:
+                    grads = global_norm_clip(grads, max_norm)
+                if conf_clipnorm > 0:
+                    grads = global_norm_clip(grads, conf_clipnorm)
             new_params, new_opt = {}, {}
             for name, p in params.items():
                 g = grads[name]
@@ -423,9 +448,12 @@ class ComputationGraph:
                 # per-vertex updater override: clip only that subtree
                 ucn = float(getattr(u, "clipnorm", 0.0) or 0.0)
                 if ucn > 0 and u is not self.conf.updater:
-                    g = global_norm_clip(g, ucn)
-                upd, ost = u.update(g, opt_state[name], p, step)
-                new_params[name] = jax.tree_util.tree_map(lambda a, d: a - d, p, upd)
+                    with jax.named_scope("clip"):
+                        g = global_norm_clip(g, ucn)
+                with jax.named_scope("updater"):
+                    upd, ost = u.update(g, opt_state[name], p, step)
+                    new_params[name] = jax.tree_util.tree_map(
+                        lambda a, d: a - d, p, upd)
                 new_opt[name] = ost
             # carry forward unchanged state entries
             for k, v in state.items():
@@ -433,10 +461,11 @@ class ComputationGraph:
             if not guarded:
                 return new_params, new_state, new_opt, loss
             # tripped step: keep the old params/opt/state ON DEVICE
-            ok = word[_sentinel.WORD_OK] > 0
-            new_params = _sentinel.tree_select(ok, new_params, params)
-            new_opt = _sentinel.tree_select(ok, new_opt, opt_state)
-            new_state = _sentinel.tree_select(ok, new_state, state)
+            with jax.named_scope("guard"):
+                ok = word[_sentinel.WORD_OK] > 0
+                new_params = _sentinel.tree_select(ok, new_params, params)
+                new_opt = _sentinel.tree_select(ok, new_opt, opt_state)
+                new_state = _sentinel.tree_select(ok, new_state, state)
             return new_params, new_state, new_opt, loss, word
 
         return train_step
@@ -564,17 +593,17 @@ class ComputationGraph:
             self.params, self.state, self.opt_state, loss = fn(*args)
             result = deliver_score(self, loss, window, None)
         elif window is None:
-            with mon.phase("device_step"):
+            with mon.phase("device_step", step=self.step_count):
                 self.params, self.state, self.opt_state, loss = fn(*args)
                 # the host fetch is the device sync: step time includes it
                 result = self._score_value = _fetch_scalar(loss)
-            with mon.phase("listeners"):
+            with mon.phase("listeners", step=self.step_count):
                 for lst in self.listeners:
                     lst.iteration_done(self, self.step_count,
                                        self.epoch_count, result)
             mon.iteration_done(result)
         else:
-            with mon.phase("dispatch"):
+            with mon.phase("dispatch", step=self.step_count):
                 self.params, self.state, self.opt_state, loss = fn(*args)
             try:
                 result = window.submit(loss)  # drains oldest once over capacity
@@ -605,7 +634,8 @@ class ComputationGraph:
             # pipeline vs device step split); None = monitoring off
             mon = monitoring.fit_monitor()
             try:
-                for ds in (data if mon is None else mon.wrap_batches(data)):
+                for ds in (data if mon is None
+                           else mon.wrap_batches(data, self)):
                     self.fit_batch(ds)
             except BaseException:
                 # best-effort drain; the batch-loop exception wins

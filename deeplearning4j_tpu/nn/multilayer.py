@@ -74,6 +74,12 @@ def merge_carry_rows(carries, sub, rows):
     return jax.tree_util.tree_map(lambda a, r: a.at[idx].set(r), carries, sub)
 
 
+def _scope_name(index: int, layer) -> str:
+    """``<index>.<LayerClass>`` (the layer's own name where it has one): a
+    trace's reader tells layer kinds apart without a table."""
+    return f"{getattr(layer, 'name', None) or index}.{type(layer).__name__}"
+
+
 def global_norm_clip(grads, max_norm):
     """DL4J GradientNormalization.ClipL2PerParamType analog (global L2 form)."""
     leaves = jax.tree_util.tree_leaves(grads)
@@ -149,20 +155,26 @@ class MultiLayerNetwork:
             if i in self.conf.preprocessors:
                 x = self.conf.preprocessors[i](x)
             k = jax.random.fold_in(rng, i) if rng is not None else None
+            # <index>.<LayerClass>: JAX writes jvp(<scope>) on the forward and
+            # transpose(jvp(<scope>)) on the backward operations' op_name
+            scope = jax.named_scope(_scope_name(i, layer))
             if i == n - 1 and hasattr(layer, "preout"):
-                x = layer._maybe_dropout(x, train, k) if train else x
+                with scope:
+                    x = layer._maybe_dropout(x, train, k) if train else x
+                    preout = layer.preout(params[i], x)
                 new_states.append(state[i])
-                return layer.preout(params[i], x), new_states, mask, x
-            if self.conf.remat and train:
-                # remat policy (workspace-tuning analog): save only each
-                # layer's input; recompute its internals during backprop
-                x, s = jax.checkpoint(
-                    lambda p, st, xx, kk, mm, _l=layer: _l.apply(
-                        p, st, xx, train=True, rng=kk, mask=mm)
-                )(params[i], state[i], x, k, mask)
-            else:
-                x, s = layer.apply(params[i], state[i], x, train=train, rng=k,
-                                   mask=mask)
+                return preout, new_states, mask, x
+            with scope:
+                if self.conf.remat and train:
+                    # remat policy (workspace-tuning analog): save only each
+                    # layer's input; recompute its internals during backprop
+                    x, s = jax.checkpoint(
+                        lambda p, st, xx, kk, mm, _l=layer: _l.apply(
+                            p, st, xx, train=True, rng=kk, mask=mm)
+                    )(params[i], state[i], x, k, mask)
+                else:
+                    x, s = layer.apply(params[i], state[i], x, train=train,
+                                       rng=k, mask=mask)
             mask = layer.feed_forward_mask(mask, itype_chain[i])
             new_states.append(s)
         return x, new_states, mask, x
@@ -226,49 +238,53 @@ class MultiLayerNetwork:
         else:
             preout, new_states, out_mask, features, new_carries = (
                 self._forward_carry(params, state, x, carries, True, rng, mask))
-        if label_mask is not None:
-            out_mask = label_mask
-        out_layer = self.layers[-1]
-        per = out_layer.score_from_preout(y, preout, out_mask)
-        if isinstance(out_layer, CenterLossOutputLayer):
-            # a per-example loss mask must cover the center term and the
-            # persisted center update too (r5)
-            cmask = None
-            if (out_mask is not None
-                    and int(np.prod(out_mask.shape)) == preout.shape[0]):
-                cmask = out_mask.reshape(preout.shape[0])
-            cscore, cstate = out_layer.center_score_and_state(
-                params[-1], state[-1], features, y, mask=cmask)
-            per = per + cscore
-            new_states[-1] = cstate
-        if out_mask is not None and per.ndim == 1:
-            # masked per-sample sums normalized by valid count — a 1-D [B]
-            # per-example mask normalizes exactly like [B, 1]/[B, T] (r5;
-            # matches ComputationGraph._loss)
-            d = denom if denom is not None else jnp.maximum(out_mask.sum(),
-                                                            1.0)
-            loss = per.sum() / d
-        else:
-            loss = per.mean()
-        reg = sum(l.regularization(p) for l, p in zip(self.layers, params))
+        with jax.named_scope("loss"):
+            if label_mask is not None:
+                out_mask = label_mask
+            out_layer = self.layers[-1]
+            per = out_layer.score_from_preout(y, preout, out_mask)
+            if isinstance(out_layer, CenterLossOutputLayer):
+                # a per-example loss mask must cover the center term and the
+                # persisted center update too (r5)
+                cmask = None
+                if (out_mask is not None
+                        and int(np.prod(out_mask.shape)) == preout.shape[0]):
+                    cmask = out_mask.reshape(preout.shape[0])
+                cscore, cstate = out_layer.center_score_and_state(
+                    params[-1], state[-1], features, y, mask=cmask)
+                per = per + cscore
+                new_states[-1] = cstate
+            if out_mask is not None and per.ndim == 1:
+                # masked per-sample sums normalized by valid count — a 1-D [B]
+                # per-example mask normalizes exactly like [B, 1]/[B, T] (r5;
+                # matches ComputationGraph._loss)
+                d = denom if denom is not None else jnp.maximum(out_mask.sum(),
+                                                                1.0)
+                loss = per.sum() / d
+            else:
+                loss = per.mean()
+            reg = sum(l.regularization(p) for l, p in zip(self.layers, params))
         return loss + reg, new_states, new_carries
 
     def _apply_updaters(self, grads, params, opt_state, step):
-        if self.conf.max_grad_norm > 0:
-            grads = global_norm_clip(grads, self.conf.max_grad_norm)
-        cn = float(getattr(self.conf.updater, "clipnorm", 0.0) or 0.0)
-        if cn > 0:
-            grads = global_norm_clip(grads, cn)
+        with jax.named_scope("clip"):
+            if self.conf.max_grad_norm > 0:
+                grads = global_norm_clip(grads, self.conf.max_grad_norm)
+            cn = float(getattr(self.conf.updater, "clipnorm", 0.0) or 0.0)
+            if cn > 0:
+                grads = global_norm_clip(grads, cn)
         new_params, new_opt = [], []
         for i, u in enumerate(self._updaters):
             g = grads[i]
             # per-layer updater override: clip only that layer's subtree
             ucn = float(getattr(u, "clipnorm", 0.0) or 0.0)
             if ucn > 0 and u is not self.conf.updater:
-                g = global_norm_clip(g, ucn)
-            upd, ost = u.update(g, opt_state[i], params[i], step)
-            new_params.append(jax.tree_util.tree_map(lambda p, d: p - d,
-                                                     params[i], upd))
+                with jax.named_scope("clip"):
+                    g = global_norm_clip(g, ucn)
+            with jax.named_scope("updater"):
+                upd, ost = u.update(g, opt_state[i], params[i], step)
+                new_params.append(jax.tree_util.tree_map(
+                    lambda p, d: p - d, params[i], upd))
             new_opt.append(ost)
         return new_params, new_opt
 
@@ -295,16 +311,18 @@ class MultiLayerNetwork:
                 return new_params, new_states, new_opt, loss
             # screen the RAW grads (NaN * clip_scale is still NaN, so the
             # clip below cannot launder a non-finite gradient past the word)
-            grads, word = _sentinel.screen(grads, loss, ctrl,
-                                           with_clip=clip_active)
+            with jax.named_scope("guard"):
+                grads, word = _sentinel.screen(grads, loss, ctrl,
+                                               with_clip=clip_active)
             new_params, new_opt = self._apply_updaters(grads, params,
                                                        opt_state, step)
             # a tripped step keeps the old params/opt/state ON DEVICE: the
             # bad update never materializes host-side or in checkpoints
-            ok = word[_sentinel.WORD_OK] > 0
-            new_params = _sentinel.tree_select(ok, new_params, params)
-            new_opt = _sentinel.tree_select(ok, new_opt, opt_state)
-            new_states = _sentinel.tree_select(ok, new_states, state)
+            with jax.named_scope("guard"):
+                ok = word[_sentinel.WORD_OK] > 0
+                new_params = _sentinel.tree_select(ok, new_params, params)
+                new_opt = _sentinel.tree_select(ok, new_opt, opt_state)
+                new_states = _sentinel.tree_select(ok, new_states, state)
             return new_params, new_states, new_opt, loss, word
 
         return train_step
@@ -321,18 +339,23 @@ class MultiLayerNetwork:
             if i in self.conf.preprocessors:
                 x = self.conf.preprocessors[i](x)
             k = jax.random.fold_in(rng, i) if rng is not None else None
+            scope = jax.named_scope(_scope_name(i, layer))
             if i == n - 1 and hasattr(layer, "preout"):
-                x = layer._maybe_dropout(x, train, k) if train else x
+                with scope:
+                    x = layer._maybe_dropout(x, train, k) if train else x
+                    preout = layer.preout(params[i], x)
                 new_states.append(state[i])
-                return layer.preout(params[i], x), new_states, mask, x, new_carries
+                return preout, new_states, mask, x, new_carries
             if i in carries and hasattr(layer, "apply_with_carry"):
-                x = layer._maybe_dropout(x, train, k) if train else x
-                x, new_carries[i] = layer.apply_with_carry(params[i], x,
-                                                           carries[i], mask=mask)
+                with scope:
+                    x = layer._maybe_dropout(x, train, k) if train else x
+                    x, new_carries[i] = layer.apply_with_carry(
+                        params[i], x, carries[i], mask=mask)
                 new_states.append(state[i])
             else:
-                x, s = layer.apply(params[i], state[i], x, train=train, rng=k,
-                                   mask=mask)
+                with scope:
+                    x, s = layer.apply(params[i], state[i], x, train=train,
+                                       rng=k, mask=mask)
                 new_states.append(s)
             mask = layer.feed_forward_mask(mask, itype_chain[i])
         return x, new_states, mask, x, new_carries
@@ -531,17 +554,17 @@ class MultiLayerNetwork:
             self.params, self.state, self.opt_state, loss = step_fn(*args)
             result = deliver_score(self, loss, window, None)
         elif window is None:
-            with mon.phase("device_step"):
+            with mon.phase("device_step", step=self.step_count):
                 self.params, self.state, self.opt_state, loss = step_fn(*args)
                 # the host fetch is the device sync: step time includes it
                 result = self._score_value = _fetch_scalar(loss)
-            with mon.phase("listeners"):
+            with mon.phase("listeners", step=self.step_count):
                 for lst in self.listeners:
                     lst.iteration_done(self, self.step_count,
                                        self.epoch_count, result)
             mon.iteration_done(result)
         else:
-            with mon.phase("dispatch"):
+            with mon.phase("dispatch", step=self.step_count):
                 self.params, self.state, self.opt_state, loss = step_fn(*args)
             try:
                 result = window.submit(loss)  # drains oldest once over capacity
@@ -573,7 +596,8 @@ class MultiLayerNetwork:
             # pipeline vs device step split); None = monitoring off
             mon = monitoring.fit_monitor()
             try:
-                for ds in (data if mon is None else mon.wrap_batches(data)):
+                for ds in (data if mon is None
+                           else mon.wrap_batches(data, self)):
                     self.fit_batch(ds)
             except BaseException:
                 # best-effort drain; the batch-loop exception wins

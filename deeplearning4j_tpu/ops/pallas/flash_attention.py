@@ -154,6 +154,7 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
         functools.partial(_flash_kernel, causal=causal, scale=scale,
                           block_q=bq, block_k=bk, seq_k=Tk,
                           has_kmask=kmask is not None),
+        name="flash_attention_fwd",
         out_shape=(_sds(qf.shape, q.dtype, vma),
                    _sds((B * H, Tq, 1), jnp.float32, vma)),
         grid=grid,
@@ -322,6 +323,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
         functools.partial(_flash_dq_kernel, causal=causal, scale=scale,
                           block_q=bq, block_k=bk, seq_q=Tq, seq_k=Tk,
                           has_kmask=has_km),
+        name="flash_attention_bwd_dq",
         out_shape=_sds(qf.shape, jnp.float32, vma),
         grid=(B * H, pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)),
         in_specs=in_specs,
@@ -346,6 +348,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
         functools.partial(_flash_dkv_kernel, causal=causal, scale=scale,
                           block_q=bq, block_k=bk, seq_q=Tq, seq_k=Tk,
                           has_kmask=has_km),
+        name="flash_attention_bwd_dkv",
         out_shape=(_sds(kf.shape, jnp.float32, vma),
                    _sds(vf.shape, jnp.float32, vma)),
         grid=(B * H, pl.cdiv(Tk, bk), pl.cdiv(Tq, bq)),
@@ -531,4 +534,5 @@ def _flash_applicable(q, k, v, *, mask=None, scale=None, causal=False, **kw):
 
 register_impl("dot_product_attention", platform="pallas",
               predicate=_flash_applicable, requires=_flash_requires,
-              priority=1)(flash_attention)
+              priority=1,
+              scope="flash_attention")(flash_attention)

@@ -174,6 +174,7 @@ def _fused_gru_recurrence(xg, R, h0, *, interpret, save_residuals=False):
 
     res = pl.pallas_call(
         functools.partial(_gru_kernel, hb=hb, save_residuals=save_residuals),
+        name="fused_gru_fwd",
         out_shape=tuple(out_shape),
         grid=(nb, T, nj),
         in_specs=[
@@ -331,6 +332,7 @@ def _bwd_recurrence(residuals, R, hprev_seq, dout, *, plan, interpret):
 
     return pl.pallas_call(
         functools.partial(_gru_bwd_kernel, hb=hb),
+        name="fused_gru_bwd",
         out_shape=(jax.ShapeDtypeStruct((T, B, H), jnp.float32),) * 3
         + (jax.ShapeDtypeStruct((B, H), jnp.float32),),
         grid=(nb, T, nj),
@@ -461,4 +463,5 @@ def _gru_applicable(x, h0, W, R, b, **kw):
 
 
 register_impl("gru_layer", platform="pallas", predicate=_gru_applicable,
-              requires=_gru_requires, priority=1)(fused_gru_layer)
+              requires=_gru_requires, priority=1,
+              scope="fused_gru")(fused_gru_layer)

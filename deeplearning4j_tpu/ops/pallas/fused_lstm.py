@@ -294,6 +294,7 @@ def _fused_recurrence(xg, R, h0, c0, peephole, *, interpret,
     res = pl.pallas_call(
         functools.partial(_lstm_kernel, hb=hb, has_peephole=has_p,
                           save_residuals=save_residuals),
+        name="fused_lstm_fwd",
         out_shape=tuple(out_shape),
         grid=(nb, T, nj),
         in_specs=[
@@ -491,6 +492,7 @@ def _bwd_recurrence(residuals, R, cprev_seq, dout, dcT, peephole, *,
 
     out = pl.pallas_call(
         functools.partial(_lstm_bwd_kernel, hb=hb, has_peephole=has_p),
+        name="fused_lstm_bwd",
         out_shape=(jax.ShapeDtypeStruct((T, B, H), jnp.float32),) * 4
         + (jax.ShapeDtypeStruct((B, H), jnp.float32),),
         grid=(nb, T, nj),
@@ -694,4 +696,5 @@ def _lstm_applicable(x, h0, c0, W, R, b, *, peephole=None, **kw):
 
 
 register_impl("lstm_layer", platform="pallas", predicate=_lstm_applicable,
-              requires=_lstm_requires, priority=1)(fused_lstm_layer)
+              requires=_lstm_requires, priority=1,
+              scope="fused_lstm")(fused_lstm_layer)

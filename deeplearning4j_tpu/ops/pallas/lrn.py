@@ -58,6 +58,7 @@ def _lrn_forward(x, *, depth, alpha, beta, k, block_rows, interpret):
     band = _band(C, depth)
     out = pl.pallas_call(
         functools.partial(_lrn_kernel, alpha=alpha, beta=beta, k=k),
+        name="lrn_fwd",
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         grid=(pl.cdiv(R, br),),
         in_specs=[
@@ -119,6 +120,7 @@ def _lrn_backward(x, g, *, depth, alpha, beta, k, block_rows, interpret):
     band = _band(C, depth)
     dx = pl.pallas_call(
         functools.partial(_lrn_bwd_kernel, alpha=alpha, beta=beta, k=k),
+        name="lrn_bwd",
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         grid=(pl.cdiv(R, br),),
         in_specs=[
@@ -172,4 +174,5 @@ def _lrn_applicable(x, *, depth=5, **kw):
 
 
 register_impl("lrn", platform="pallas", predicate=_lrn_applicable,
-              requires=_lrn_requires, priority=1)(pallas_lrn)
+              requires=_lrn_requires, priority=1,
+              scope="lrn")(pallas_lrn)

@@ -38,6 +38,7 @@ class OpImpl:
     predicate: Callable[..., bool] | None = None   # perf heuristic (FORCE_PALLAS bypasses)
     requires: Callable[..., bool] | None = None    # structural: ALWAYS enforced
     priority: int = 0  # higher wins among applicable impls
+    scope: str | None = None  # jax.named_scope the call runs under (kernel family)
 
     def supported(self, *args, **kwargs) -> bool:
         """Structural applicability — the impl can produce a correct answer
@@ -88,7 +89,14 @@ class _Op:
         impl = self.select(*args, **kwargs)
         if env.verbose:
             print(f"[dl4j-tpu] op {self.name} -> {impl.platform}")
-        out = impl.fn(*args, **kwargs)
+        if impl.scope is None:
+            out = impl.fn(*args, **kwargs)
+        else:
+            # the kernels' stable name in a trace: <scope>_fwd / <scope>_bwd*
+            # are the pallas_call names, jvp(<scope>) / transpose(jvp(<scope>))
+            # the op_name of whatever XLA runs around them
+            with jax.named_scope(impl.scope):
+                out = impl.fn(*args, **kwargs)
         if env.nan_panic:
             out = _nan_check(self.name, out)
         return out
@@ -114,19 +122,21 @@ def register_op(name: str):
 
 
 def register_impl(name: str, platform: str = "pallas", predicate=None,
-                  requires=None, priority: int = 1):
+                  requires=None, priority: int = 1, scope: str | None = None):
     """Decorator: register an accelerated implementation of op ``name``.
 
     ``predicate(*call_args, **call_kwargs)`` gates applicability on perf
     heuristics (the TPU-native ``isUsablePlatform``); FORCE_PALLAS bypasses
     it. ``requires`` states structural constraints the impl cannot operate
     without (unsupported arguments, shape contracts) — never bypassed.
+    ``scope`` is the ``jax.named_scope`` a call of this impl runs under:
+    for a Pallas impl the prefix its ``pallas_call`` names share.
     """
 
     def deco(fn):
         get_op(name).impls.append(
             OpImpl(name=name, platform=platform, fn=fn, predicate=predicate,
-                   requires=requires, priority=priority)
+                   requires=requires, priority=priority, scope=scope)
         )
         return fn
 

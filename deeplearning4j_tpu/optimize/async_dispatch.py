@@ -247,7 +247,7 @@ class AsyncScoreWindow:
                                           handle.epoch,
                                           guardrails._fetch_word(word), self)
                 else:
-                    with mon.phase("drain"):
+                    with mon.phase("drain", step=handle.step):
                         value = guard.deliver(self.model, handle.step,
                                               handle.epoch,
                                               guardrails._fetch_word(word),
@@ -255,7 +255,7 @@ class AsyncScoreWindow:
             elif mon is None:
                 value = _fetch_scalar(loss)
             else:
-                with mon.phase("drain"):
+                with mon.phase("drain", step=handle.step):
                     value = _fetch_scalar(loss)
         except Exception as e:  # surfaced with the step it belongs to
             handle._error = AsyncStepError(handle.step, handle.epoch, e,
@@ -269,7 +269,7 @@ class AsyncScoreWindow:
                 lst.iteration_done(self.model, handle.step, handle.epoch,
                                    value)
         else:
-            with mon.phase("listeners"):
+            with mon.phase("listeners", step=handle.step):
                 for lst in listeners:
                     lst.iteration_done(self.model, handle.step, handle.epoch,
                                        value)
@@ -344,7 +344,7 @@ def deliver_score(model, loss, window: Optional[AsyncScoreWindow],
             lst.iteration_done(model, model.step_count, model.epoch_count,
                                value)
     else:
-        with mon.phase("listeners"):
+        with mon.phase("listeners", step=model.step_count):
             for lst in model.listeners:
                 lst.iteration_done(model, model.step_count,
                                    model.epoch_count, value)

@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.parallel.mesh import DeviceMesh
 
 
@@ -104,8 +105,12 @@ class ParallelWrapper:
             data = AsyncPrefetchIterator(data, queue_size=self.prefetch_buffer,
                                          device_put=False, sharder=sharder)
         for _ in range(epochs):
+            # the same phases as the networks' own fit: data-wait spans time
+            # the iterator pull per batch; None = monitoring off
+            mon = monitoring.fit_monitor()
             try:
-                for ds in data:
+                for ds in (data if mon is None
+                           else mon.wrap_batches(data, self.model)):
                     self.fit_batch(ds)
             except BaseException:
                 drain_scores(self.model, suppress=True)

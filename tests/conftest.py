@@ -39,3 +39,23 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def monitoring_off(monkeypatch):
+    """Owns the process-wide monitoring state for a test that asserts the
+    default-off path: the env flag cleared, a fresh registry, no ring, no
+    flight recorder, monitoring disabled — whatever another file on this
+    worker left armed — and what was there before restored after."""
+    from deeplearning4j_tpu import monitoring
+    from deeplearning4j_tpu.common.env import env
+
+    was_enabled = monitoring.enabled()
+    monkeypatch.delenv("DL4J_TPU_MONITORING", raising=False)
+    monkeypatch.setattr(env, "monitoring", False)
+    monitoring.reset()
+    monitoring.disable()
+    yield monitoring
+    monitoring.reset()
+    if was_enabled:
+        monitoring.enable()

@@ -480,8 +480,8 @@ class TestStreamingHTTP:
 
 # ----------------------------------------------------------- zero overhead
 class TestZeroOverhead:
-    def test_monitor_none_and_no_metrics_by_default(self, lstm_net):
-        monitoring.reset()
+    def test_monitor_none_and_no_metrics_by_default(self, lstm_net,
+                                                    monitoring_off):
         assert monitoring.generate_monitor() is None
         eng = GenerationEngine(lstm_net, slots=1, max_len=16)
         eng.generate([1], max_new_tokens=2)

@@ -41,25 +41,30 @@ from deeplearning4j_tpu.optimize.updaters import (
 )
 
 
+#: every flag a test of this file sets. DL4J_TPU_MONITORING among them: left
+#: out, the teardown's reload baked it into the singleton and every later
+#: file on the worker started with monitoring armed
+_ENV_VARS = ("DL4J_TPU_ASYNC_STEPS", "DL4J_TPU_PAD_TAIL", "DL4J_TPU_GUARDRAILS",
+             "DL4J_TPU_GUARDRAILS_DIR", "DL4J_TPU_MONITORING")
+
+
 @pytest.fixture(autouse=True)
 def _isolate(monkeypatch):
     """Fresh env/faults/metrics around every test; async default."""
-    for var in ("DL4J_TPU_ASYNC_STEPS", "DL4J_TPU_PAD_TAIL",
-                "DL4J_TPU_GUARDRAILS", "DL4J_TPU_GUARDRAILS_DIR"):
+    for var in _ENV_VARS:
         monkeypatch.delenv(var, raising=False)
     env.reload()
     faults.configure("")
     monitoring.reset()
     yield
     faults.configure("")
-    monitoring.reset()
     # monkeypatch undoes setenv AFTER this teardown runs, so reloading
     # here would bake a test's env vars into the singleton and leak them
     # into whatever suite runs next — clear them first
-    for var in ("DL4J_TPU_ASYNC_STEPS", "DL4J_TPU_PAD_TAIL",
-                "DL4J_TPU_GUARDRAILS", "DL4J_TPU_GUARDRAILS_DIR"):
+    for var in _ENV_VARS:
         os.environ.pop(var, None)
     env.reload()
+    monitoring.reset()      # enablement back to the (now cleared) env flag
 
 
 def _async(monkeypatch, steps):

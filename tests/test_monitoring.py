@@ -12,6 +12,7 @@ import json
 import threading
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -289,8 +290,13 @@ class TestZeroOverheadGuard:
     makes NO registry/tracer calls — observability can never silently
     regress training throughput."""
 
-    def test_disabled_fit_touches_no_instruments(self, monkeypatch):
-        assert not monitoring.enabled()   # default-off env flag
+    @pytest.mark.parametrize("entry", ["fit", "prefetch", "parallel_wrapper"])
+    def test_disabled_fit_touches_no_instruments(self, monkeypatch,
+                                                 monitoring_off, entry):
+        """``fit`` on the bare iterator, behind the prefetch thread (the
+        ``prefetch.stage`` span's place) and under ``ParallelWrapper`` (which
+        stages through its own prefetch thread)."""
+        assert not monitoring.enabled() and monitoring.tracer() is None
         calls = []
 
         def spy(name):
@@ -303,12 +309,28 @@ class TestZeroOverheadGuard:
         monkeypatch.setattr(Gauge, "inc", spy("Gauge.inc"))
         monkeypatch.setattr(Histogram, "observe", spy("Histogram.observe"))
         monkeypatch.setattr(SpanTracer, "span", spy("SpanTracer.span"))
+        monkeypatch.setattr(SpanTracer, "complete", spy("SpanTracer.complete"))
+        monkeypatch.setattr(monitoring._FitMonitor, "stage", spy("stage"))
+        monkeypatch.setattr(monitoring._FitMonitor, "wrap_batches",
+                            spy("wrap_batches"))
 
         model = _model()
         x, y = _data(16)
-        from deeplearning4j_tpu.datasets.iterators import ArrayDataSetIterator
+        from deeplearning4j_tpu.datasets.iterators import (
+            ArrayDataSetIterator, AsyncPrefetchIterator,
+        )
 
-        model.fit(ArrayDataSetIterator(x, y, batch_size=8), epochs=2)
+        it = ArrayDataSetIterator(x, y, batch_size=8)
+        if entry == "fit":
+            model.fit(it, epochs=2)
+        elif entry == "prefetch":
+            model.fit(AsyncPrefetchIterator(it), epochs=2)
+        else:
+            from deeplearning4j_tpu.parallel import DeviceMesh, ParallelWrapper
+
+            mesh = DeviceMesh(devices=jax.devices()[:4])
+            ParallelWrapper(model, mesh).fit(it, epochs=2)
+        assert model.step_count == 4
         assert calls == []
 
     def test_enable_disable_round_trip(self):

@@ -481,8 +481,7 @@ class TestServingQuantize:
 
 # ------------------------------------------------------------ monitoring
 class TestQuantizeMonitoring:
-    def test_disabled_is_free(self):
-        monitoring.reset()
+    def test_disabled_is_free(self, monitoring_off):
         assert monitoring.quantize_monitor() is None
         net = _dense_net(seed=31, n_in=4, hidden=8, n_out=3)
         net.quantize()

@@ -498,7 +498,8 @@ class TestChaosTracePropagation:
             gw.stop()
             flight.reset()
 
-    def test_unconfigured_chaos_lane_zero_instrument_calls(self, monkeypatch):
+    def test_unconfigured_chaos_lane_zero_instrument_calls(
+            self, monkeypatch, monitoring_off):
         """With tracing, flight, and monitoring ALL unconfigured, a full
         predict round-trip performs zero tracer/recorder instrument calls
         (the spy-guarded half of the acceptance gate)."""
@@ -533,3 +534,289 @@ class TestChaosTracePropagation:
         finally:
             gw.stop()
         assert calls == []
+
+
+# ------------------------------------------------- the fit path's span spine
+def _mln(seed=5):
+    from deeplearning4j_tpu.nn import (
+        InputType, MultiLayerNetwork, NeuralNetConfiguration,
+    )
+    from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.optimize import Sgd
+
+    conf = (NeuralNetConfiguration.builder().seed(seed)
+            .updater(Sgd(lr=0.1)).list()
+            .layer(DenseLayer(n_out=8, activation="relu"))
+            .layer(OutputLayer(n_out=3, activation="softmax", loss="mcxent"))
+            .set_input_type(InputType.feed_forward(4)).build())
+    return MultiLayerNetwork(conf).init()
+
+
+def _cg(seed=3):
+    from deeplearning4j_tpu.nn import InputType, NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.optimize import Sgd
+
+    conf = (NeuralNetConfiguration.builder().seed(seed)
+            .updater(Sgd(lr=0.1)).graph_builder()
+            .add_inputs("in")
+            .set_input_types(**{"in": InputType.feed_forward(4)})
+            .add_layer("d", DenseLayer(n_out=8, activation="relu"), "in")
+            .add_layer("o", OutputLayer(n_out=3, activation="softmax",
+                                        loss="mcxent"), "d")
+            .set_outputs("o").build())
+    return ComputationGraph(conf).init()
+
+
+def _xy(n=24, seed=0):
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(n, 4)).astype(np.float32)
+    y = np.eye(3, dtype=np.float32)[rng.integers(0, 3, n)]
+    return x, y
+
+
+class TestSpanFields:
+    def test_clock_parent_and_ids(self):
+        """A span is stamped with ``time.time_ns()`` (the profiler's clock),
+        names the span that caused it, and carries its identifiers."""
+        tr = SpanTracer()
+        before = time.time_ns()
+        with tr.span("outer", step=7):
+            with tr.span("inner", seq=2):
+                pass
+            tr.complete("measured", 0.001)
+        after = time.time_ns()
+        outer, inner, measured = (
+            next(s for s in tr.spans() if s.name == n)
+            for n in ("outer", "inner", "measured"))
+        assert before <= outer.start_ns <= inner.start_ns
+        assert inner.end_ns <= outer.end_ns <= after
+        assert outer.parent is None and outer.args == {"step": 7}
+        assert inner.parent == outer.id and inner.args == {"seq": 2}
+        assert measured.parent == outer.id
+        assert measured.end_ns - measured.start_ns == 1_000_000
+        assert outer.thread == threading.current_thread().name
+        assert isinstance(outer, tuple)
+        # the Chrome export keeps microseconds from the tracer's start
+        begin = next(e for e in tr.events() if e["ph"] == "B")
+        assert begin["ts"] == pytest.approx(
+            (outer.start_ns - tr.start_ns) / 1e3)
+        validate_nesting(tr.events())
+
+    def test_explicit_parent_crosses_threads(self):
+        tr = SpanTracer()
+        with tr.span("submit") as _:
+            cause = tr.current()
+
+            def work():
+                with tr.span("worker", parent=cause):
+                    pass
+
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+        worker = next(s for s in tr.spans() if s.name == "worker")
+        submit = next(s for s in tr.spans() if s.name == "submit")
+        assert worker.parent == submit.id and worker.tid != submit.tid
+
+    def test_enable_arms_the_ring_and_spans_outlive_disable(self):
+        """One switch: ``enable()`` alone, no ``start_tracing()``."""
+        assert monitoring.tracer() is None and monitoring.spans() == []
+        monitoring.enable()
+        ring = monitoring.tracer()
+        assert ring is not None
+        with monitoring.span("x", step=1):
+            pass
+        monitoring.enable()                       # a second call keeps the ring
+        assert monitoring.tracer() is ring
+        monitoring.disable()
+        assert [s.name for s in monitoring.spans()] == ["x"]
+        # stop_tracing hands the ring out; enabled, a fresh one takes its place
+        monitoring.enable()
+        assert monitoring.stop_tracing() is ring
+        assert monitoring.tracer() not in (None, ring)
+
+    def test_span_is_in_the_profilers_own_trace(self, tmp_path):
+        """``SpanTracer.span`` enters a ``TraceAnnotation`` of the same name
+        and arguments, and ``profiler.trace`` records it (host tracer level 1)
+        on the clock ``profile_start_time`` anchors."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from deeplearning4j_tpu.profiler import profiler
+
+        tr = SpanTracer()
+        with profiler.trace(str(tmp_path)):
+            with tr.span("fit.dispatch", step=3):
+                jax.block_until_ready(jax.numpy.ones(8) + 1)
+        (span,) = tr.spans()
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        data = ProfileData.from_file(path)
+        start = next(dict(p.stats)["profile_start_time"]
+                     for p in data.planes if p.name == "Task Environment")
+        found = [e for p in data.planes for line in p.lines
+                 for e in line.events if e.name == "fit.dispatch"]
+        assert len(found) == 1
+        assert str(dict(found[0].stats)["step"]) == "3"
+        # the annotation wraps the span: it starts first, within a millisecond
+        assert 0 <= span.start_ns - (start + found[0].start_ns) < 1_000_000
+
+
+class TestFitPathSpans:
+    @pytest.mark.parametrize("entry", ["mln", "cg", "parallel_wrapper"])
+    def test_three_steps_leave_one_span_of_each_phase_a_step(self, entry):
+        from deeplearning4j_tpu.datasets.iterators import (
+            ArrayDataSetIterator, AsyncPrefetchIterator,
+        )
+
+        monitoring.enable()
+        model = _cg() if entry == "cg" else _mln()
+        model.fit(*_xy(8))             # step 0: the spans below start at step 1
+        model.score_value              # (drains it)
+        first = model.step_count
+        ring = monitoring.start_tracing()
+        it = ArrayDataSetIterator(*_xy(24), batch_size=8)
+        if entry == "parallel_wrapper":
+            import jax
+
+            from deeplearning4j_tpu.parallel import DeviceMesh, ParallelWrapper
+
+            ParallelWrapper(model, DeviceMesh(devices=jax.devices()[:4])).fit(it)
+        else:
+            model.fit(AsyncPrefetchIterator(it))
+        spans = ring.spans()
+        validate_nesting(ring.events())
+        steps = list(range(first, first + 3))
+        by_name = {}
+        for s in spans:
+            by_name.setdefault(s.name, []).append(s)
+        for name in ("fit.dispatch", "fit.drain", "fit.listeners"):
+            assert [s.args["step"] for s in by_name[name]] == steps, name
+        # one pull a step and the one that ends the epoch
+        waits = by_name["fit.data_wait"]
+        assert [(s.args["step"], s.args["seq"]) for s in waits] == [
+            (first, 0), (first + 1, 1), (first + 2, 2), (first + 3, 3)]
+        staged = by_name["prefetch.stage"]
+        assert [s.args["seq"] for s in staged] == [0, 1, 2]
+        fit_tid = waits[0].tid
+        assert {s.tid for s in staged} != {fit_tid} and len({s.tid for s in staged}) == 1
+        assert all(s.tid == fit_tid for n in ("fit.dispatch", "fit.drain")
+                   for s in by_name[n])
+        assert "fit.device_step" not in by_name
+        # the step's spans in causal order: waited for, dispatched, drained
+        for k, step in enumerate(steps):
+            assert (waits[k].end_ns <= by_name["fit.dispatch"][k].start_ns
+                    <= by_name["fit.drain"][k].start_ns
+                    <= by_name["fit.listeners"][k].start_ns)
+        reg = monitoring.registry()
+        assert reg.get("dl4j_prefetch_staged_bytes_total").value == 24 * (4 + 3) * 4
+        assert reg.get("dl4j_prefetch_stage_seconds").count == 3
+        assert reg.get("dl4j_train_data_wait_seconds").count == 4
+
+    def test_sync_mode_records_device_step(self, monkeypatch):
+        monkeypatch.setattr(env, "async_steps", 0)
+        monitoring.enable()
+        model = _mln()
+        model.fit(*_xy(8))
+        model.fit(*_xy(8))
+        names = [(s.name, s.args.get("step")) for s in monitoring.spans()
+                 if s.name.startswith("fit.")]      # a cold cache adds "compile"
+        assert names == [("fit.device_step", 0), ("fit.listeners", 0),
+                         ("fit.device_step", 1), ("fit.listeners", 1)]
+
+    def test_recompile_inside_a_span_is_its_child(self):
+        """``monitoring/compile.py`` records a backend compile as an
+        already-measured span whose parent is the span open on the thread."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.monitoring.compile import install_hooks
+
+        install_hooks()
+        monitoring.enable()
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            with monitoring.span("fit.dispatch", step=11):
+                jax.jit(lambda a: jnp.tanh(a) * 3.25 + 11)(jnp.ones(7))
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+        spans = monitoring.spans()
+        dispatch = next(s for s in spans if s.name == "fit.dispatch")
+        compiles = [s for s in spans if s.name == "compile"]
+        assert compiles and all(c.parent == dispatch.id for c in compiles)
+        assert all(c.end_ns > c.start_ns for c in compiles)
+        assert monitoring.registry().get("dl4j_compiles_total").value == len(compiles)
+
+
+class TestLayerScopes:
+    """``jax.named_scope`` on every layer of both cores: the lowered train
+    step names forward operations ``jvp(<name>.<Class>)`` and backward ones
+    ``transpose(jvp(<name>.<Class>))``, and the loss and the updater theirs."""
+
+    @staticmethod
+    def _lowered(model, args):
+        if model._jit_cache.get("train") is None:
+            model._jit_cache["train"] = model._make_train_step()
+        return model._jit_cache["train"].lower(*args).as_text(debug_info=True)
+
+    def test_multilayer_train_step(self):
+        import jax
+        import jax.numpy as jnp
+
+        m = _mln()
+        x, y = _xy(8)
+        text = self._lowered(m, (m.params, m.state, m.opt_state,
+                                 jnp.asarray(0, jnp.int32), jnp.asarray(x),
+                                 jnp.asarray(y), jax.random.key(0), None, None))
+        for want in ("jvp(0.DenseLayer)/dot_general",
+                     "transpose(jvp(0.DenseLayer))/dot_general",
+                     "jvp(1.OutputLayer)/dot_general",
+                     "transpose(jvp(1.OutputLayer))/dot_general",
+                     "jvp(loss)/", "transpose(jvp(loss))/", "/updater/sub"):
+            assert want in text, want
+
+    @pytest.mark.parametrize("remat", [False, True])
+    def test_graph_train_step(self, remat):
+        import jax
+        import jax.numpy as jnp
+
+        g = _cg()
+        g.conf.remat = remat
+        x, y = _xy(8)
+        text = self._lowered(g, (g.params, g.state, g.opt_state,
+                                 jnp.asarray(0, jnp.int32),
+                                 {"in": jnp.asarray(x)}, {"o": jnp.asarray(y)},
+                                 jax.random.key(0), None, None))
+        for want in ("jvp(d.DenseLayer)", "transpose(jvp(d.DenseLayer))",
+                     "jvp(o.OutputLayer)/dot_general",
+                     "transpose(jvp(o.OutputLayer))/dot_general",
+                     "jvp(loss)/", "transpose(jvp(loss))/", "/updater/sub"):
+            assert want in text, want
+
+    def test_guarded_and_clipped_step_names_guard_and_clip(self):
+        import jax
+        import jax.numpy as jnp
+
+        m = _mln()
+        m.conf.max_grad_norm = 1.0
+        step = m._make_train_step(guarded=True)
+        x, y = _xy(8)
+        ctrl = jnp.zeros((4,), jnp.float32)
+        text = step.lower(m.params, m.state, m.opt_state,
+                          jnp.asarray(0, jnp.int32), jnp.asarray(x),
+                          jnp.asarray(y), jax.random.key(0), None, None,
+                          ctrl).as_text(debug_info=True)
+        assert "/guard/" in text and "/clip/" in text and "/updater/" in text
+
+    def test_pallas_impls_run_under_their_kernels_scope(self):
+        from deeplearning4j_tpu.ops import registry
+        import deeplearning4j_tpu.ops.pallas  # noqa: F401 (registers the impls)
+
+        scopes = {impl.scope for op in ("dot_product_attention", "lrn",
+                                        "gru_layer", "lstm_layer")
+                  for impl in registry.get_op(op).impls
+                  if impl.platform == "pallas"}
+        assert scopes == {"flash_attention", "lrn", "fused_gru", "fused_lstm"}
