@@ -24,7 +24,7 @@ import time
 import jax
 import numpy as np
 
-from benchmarks import reference_train, trace_reduce
+from benchmarks import program_trace, reference_train, trace_reduce
 from benchmarks.harness import ROOT, Cell, device_stamp
 from benchmarks.traffic_gen import make_pool
 
@@ -220,10 +220,22 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, devices: list, pe
         facts["trace_bytes"] = tracing.counters["trace_bytes"]
         busy = statistics.mean(trace_reduce.busy_seconds(red, d) for d in red.devices)
         out["device"].update(busy_s=busy, window_s=red.window_s)
-        out["breakdown"] = trace_reduce.breakdown(red)
         out["layer_context"] = {
             "trace": red, "cell": cell, "chips": chips, "peaks": peaks, "module": prog.module,
             "counters": tracing.counters}
+        out["breakdown"] = breakdown(out["layer_context"])
+    return out
+
+
+def breakdown(context: dict) -> dict:
+    """The heaviest device operations and the longest idle gaps of the busiest
+    device; a gap takes the name of the program's span over it (the spine,
+    ``program_trace.named_gaps``) where the traced run recorded spans on the
+    trace's clock, else of the benchmark's own host span."""
+    out = trace_reduce.breakdown(context["trace"])
+    named = program_trace.named_gaps(context)
+    if named:
+        out["idle_gaps"] = named
     return out
 
 

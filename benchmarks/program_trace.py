@@ -41,7 +41,9 @@ text and an event stat are tried first, for a runtime that puts it there.
 Operations the compiler itself puts in to move data (``copy``, ``copy-start``
 / ``copy-done``, a slice's ``async-start`` / ``async-done``: prefetches
 between memory spaces) come from no line of the program and carry no
-``op_name`` by nature: they are counted as ``moves``. Any other operation of
+``op_name`` by nature: they are counted as ``moves``. An exchange between
+chips (``COLLECTIVE``) is counted as ``collective`` whatever scope it carries:
+GSPMD gives an all-reduce the ``op_name`` of the sum it completes. Any other operation of
 the ``train_step`` executions without an ``op_name`` is ``unnamed``, and those
 may hold ``UNNAMED_MAX`` of the step's device time; past that the scope readers
 return nothing: a stale compile cache or a lost scope leaves a metric out,
@@ -60,6 +62,7 @@ import glob
 import os
 import re
 import statistics
+import struct
 
 from benchmarks import trace_reduce
 
@@ -74,6 +77,8 @@ NORM_SCOPE = re.compile(r"[(/][^()/]*\.(Batch|Layer)Normalization[^()/]*[)/]")
 LAYER_SCOPE = re.compile(r"jvp\([^()/]+\.[A-Za-z0-9_]+\)")
 MOVE_OPCODES = ("copy", "copy-start", "copy-done")
 MOVE_ASYNC = re.compile(r"^(dynamic-)?slice-(start|done)")     # async-start / async-done of a slice
+COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+                        r"(-start|-done)?( fusion)?$")
 FIT_THREAD_SPAN, NO_SPAN = "fit.dispatch", "no program span"
 
 
@@ -83,11 +88,18 @@ class NamedOp:
     op_name: str | None     # None: the event carries none
     start: float            # seconds on the trace's clock
     end: float
+    category: str | None = None     # ``hlo_category`` of the metadata record, if it has one
 
     @property
     def moves_data(self) -> bool:
         instruction, _, opcode = self.name.rpartition(" ")
         return opcode in MOVE_OPCODES or MOVE_ASYNC.match(instruction) is not None
+
+    @property
+    def is_collective(self) -> bool:
+        """An exchange between chips, by ``hlo_category`` where the record has
+        one, else by opcode."""
+        return COLLECTIVE.match(self.category or self.name.rpartition(" ")[2]) is not None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,6 +129,7 @@ class ProgramTrace:
     step_ops: list          # [NamedOp] inside the stretch's train_step executions, busiest device
     steps: int              # executions those operations belong to
     causality: Causality
+    device_ops: dict = dataclasses.field(default_factory=dict)   # {device id: [NamedOp]}, the whole trace
 
 
 # ------------------------------------------------------------------ op names
@@ -170,13 +183,15 @@ def _map_value(entry):
     return next((v for f, v in _fields(entry) if f == 2), b"")
 
 
-def metadata_op_names(path: str) -> dict:
-    """{event name: op_name} from the stats of the events' *metadata* records
-    (``XPlane.event_metadata[...].stats``, stat ``tf_op`` = ``<op_name>:<type>``)
-    of every plane of the ``.xplane.pb``. XSpace: planes = 1. XPlane: lines =
-    3 (skipped whole), event_metadata = 4, stat_metadata = 5. XEventMetadata:
-    name = 2, stats = 5. XStat: metadata_id = 1, str_value = 5, ref_value = 7.
-    XStatMetadata: id = 1, name = 2."""
+def metadata_stats(path: str, wanted: tuple) -> dict:
+    """{event name: {stat name: value}} for the stats named in ``wanted``, from
+    the events' *metadata* records (``XPlane.event_metadata[...].stats``) of
+    every plane of the ``.xplane.pb``: ``tf_op`` (= ``<op_name>:<type>``),
+    ``hlo_category``, ``flops``, ``bytes_accessed``. XSpace: planes = 1.
+    XPlane: lines = 3 (skipped whole), event_metadata = 4, stat_metadata = 5.
+    XEventMetadata: name = 2, stats = 5. XStat: metadata_id = 1, double_value
+    = 2, uint64_value = 3, int64_value = 4, str_value = 5, ref_value = 7
+    (the id of a stat's name). XStatMetadata: id = 1, name = 2."""
     with open(path, "rb") as f:
         space = memoryview(f.read())
     out = {}
@@ -190,21 +205,45 @@ def metadata_op_names(path: str) -> dict:
                 stat_names[meta.get(1, 0)] = bytes(meta.get(2, b"")).decode("utf-8", "replace")
             elif f == 4:
                 events.append(_map_value(value))
-        wanted = {i for i, name in stat_names.items() if name in OP_NAME_STATS}
-        if not wanted:
+        ids = {i for i, name in stat_names.items() if name in wanted}
+        if not ids:
             continue
         for event in events:
-            name, op_name = None, None
+            name, found = None, {}
             for f, value in _fields(event):
                 if f == 2:
                     name = bytes(value).decode("utf-8", "replace")
                 elif f == 5:
                     stat = dict(_fields(value))
-                    if stat.get(1) in wanted:
-                        text = (stat_names.get(stat[7], "") if 7 in stat
-                                else bytes(stat.get(5, b"")).decode("utf-8", "replace"))
-                        op_name = text.rpartition(":")[0] if ":" in text else text
-            if name and op_name:
+                    if stat.get(1) not in ids:
+                        continue
+                    if 7 in stat:
+                        got = stat_names.get(stat[7], "")
+                    elif 5 in stat:
+                        got = bytes(stat[5]).decode("utf-8", "replace")
+                    elif 2 in stat:
+                        got = struct.unpack("<d", stat[2])[0]
+                    else:
+                        got = stat.get(3, stat.get(4, 0))
+                    found[stat_names[stat[1]]] = got
+            if name and found:
+                out.setdefault(name, {}).update(found)
+    return out
+
+
+def metadata_op_names(path: str) -> dict:
+    return op_names_of(metadata_stats(path, OP_NAME_STATS))
+
+
+def op_names_of(records: dict) -> dict:
+    """{event name: op_name} from ``metadata_stats``'s records: the stat
+    ``tf_op`` (``op_name`` where a runtime calls it that), less its ``:<type>``."""
+    out = {}
+    for name, stats in records.items():
+        text = stats.get("tf_op") or stats.get("op_name")
+        if text:
+            op_name = text.rpartition(":")[0] if ":" in text else text
+            if op_name:
                 out[name] = op_name
     return out
 
@@ -240,14 +279,20 @@ def scope_seconds(pt: ProgramTrace) -> dict | None:
     input gradient and weight gradient, with whatever XLA fused onto them),
     ``norm`` (under a ``*.BatchNormalization*`` / ``*.LayerNormalization*``
     scope, forward and backward), ``other``, ``moves`` (the compiler's own
-    copies between memory spaces), ``unnamed``; and ``scoped``:
-    whether any operation carries a layer's scope at all."""
+    copies between memory spaces), ``collective`` (an exchange between chips,
+    whatever scope it carries: GSPMD gives a gradient's all-reduce the
+    convolution's ``op_name``), ``unnamed``; and ``scoped``: whether any
+    operation carries a layer's scope at all."""
     if not pt.steps or not pt.step_ops:
         return None
-    kinds = {"conv_dot": 0.0, "norm": 0.0, "other": 0.0, "moves": 0.0, "unnamed": 0.0}
+    kinds = {"conv_dot": 0.0, "norm": 0.0, "other": 0.0, "moves": 0.0, "unnamed": 0.0,
+             "collective": 0.0}
     scoped = False
     for op, seconds in self_seconds(pt.step_ops):
         name = op.op_name
+        if op.is_collective:        # carries the scope of what it reduces: not that layer's time
+            kinds["collective"] += seconds
+            continue
         if name is None:
             kinds["moves" if op.moves_data else "unnamed"] += seconds
             continue
@@ -355,7 +400,9 @@ def read_file(path: str) -> tuple:
     """(anchor in ns or None, {device id: [NamedOp]}) of an ``.xplane.pb``."""
     from jax.profiler import ProfileData
 
-    anchor, devices, from_metadata = None, {}, metadata_op_names(path)
+    anchor, devices = None, {}
+    records = metadata_stats(path, OP_NAME_STATS + ("hlo_category",))
+    from_metadata = op_names_of(records)
     for plane in ProfileData.from_file(path).planes:
         if plane.name == ANCHOR_PLANE:
             anchor = next((int(v) for k, v in plane.stats if k == ANCHOR_STAT), None)
@@ -368,7 +415,8 @@ def read_file(path: str) -> tuple:
                 devices[int(m.group(1))] = [
                     NamedOp(trace_reduce.short_name(e.name),
                             op_name_of(e.name, e.stats) or from_metadata.get(e.name),
-                            e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+                            e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9,
+                            records.get(e.name, {}).get("hlo_category"))
                     for e in line.events]
     return anchor, devices
 
@@ -399,7 +447,7 @@ def assemble(red, anchor_ns, device_ops: dict, spans) -> ProgramTrace:
     lo, hi = red.window
     stretch = [i for i, p in enumerate(every) if p.start >= lo and p.end <= hi]
     causality = check_causality([(p.start, p.end) for p in every], stretch, shifted)
-    return ProgramTrace(anchor_ns, shifted, step_ops, len(runs), causality)
+    return ProgramTrace(anchor_ns, shifted, step_ops, len(runs), causality, device_ops)
 
 
 def load(ctx) -> ProgramTrace | None:

@@ -177,7 +177,8 @@ def follow(module, cfg: dict, key, batches, *, precision: str = "float32",
     if keep_fraction < 1.0:
         def leading_share(a):
             n = max(1, int(a.shape[0] * keep_fraction))
-            return jnp.concatenate([a[:n]] * (a.shape[0] // n), axis=0)
+            # laid out over the chips as the batch was: the step is compiled for that
+            return jax.device_put(jnp.concatenate([a[:n]] * (a.shape[0] // n), axis=0), a.sharding)
         batches = [(leading_share(x), leading_share(y)) for x, y in batches]
 
     def step_fn(params, state, opt, step, x, y):

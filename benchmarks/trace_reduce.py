@@ -74,15 +74,17 @@ def total(intervals) -> float:
 
 def subtract(a, b) -> list:
     """The part of the disjoint intervals ``a`` that no interval of ``b`` covers."""
-    out, b = [], union(b)
-    for s, e in union(a):
+    out, b, first = [], union(b), 0
+    for s, e in union(a):           # both in order: one pass over each
         cur = s
-        for bs, be in b:
-            if be <= cur or bs >= e:
-                continue
-            if bs > cur:
-                out.append((cur, bs))
-            cur = max(cur, be)
+        while first < len(b) and b[first][1] <= cur:
+            first += 1
+        k = first
+        while k < len(b) and b[k][0] < e:
+            if b[k][0] > cur:
+                out.append((cur, b[k][0]))
+            cur = max(cur, b[k][1])
+            k += 1
         if cur < e:
             out.append((cur, e))
     return out
@@ -115,8 +117,9 @@ def cut_window(devices: list, program: str, skip_first: int = 0, skip_last: int 
     fills), while the device drains its queue; a run without the profiler has
     no such gap. What is left has to hold ``min_steps`` executions or more:
     a shorter stretch is an error, never a different cut, so that a metric
-    means the same in every run. The stretch is chosen on the first device
-    and has to hold whole executions on all."""
+    means the same in every run. The stretch is chosen on the first device;
+    the window runs from the earliest start to the latest end of the same
+    executions over all devices, so that each holds them whole."""
     runs = program_runs(devices[0], program)
     stretches, current = [], []
     for r in runs:
@@ -133,12 +136,17 @@ def cut_window(devices: list, program: str, skip_first: int = 0, skip_last: int 
             f"{[len(s) for s in stretches]} with no stall over {stall_s} s between them: the "
             f"longest, less {skip_first} at its head and {skip_last} at its tail, holds "
             f"{len(best)}, and the cell asks for {max(2, min_steps)}")
-    lo, hi = best[0].start, best[-1].end
+    first, last = best[0].start, best[-1].end
+    lo, hi = first, last
     for d in devices[1:]:
-        inside = [r for r in program_runs(d, program) if r.end > lo and r.start < hi]
-        if len(inside) < 2:
-            raise ValueError(f"device {d.id}: {len(inside)} executions of {program!r} in the stretch")
-        lo, hi = max(lo, inside[0].start), min(hi, inside[-1].end)
+        # the same executions on another device start and end a little apart
+        # from the first's (each has its middle inside the first's stretch):
+        # the window takes them whole on every device
+        same = [r for r in program_runs(d, program) if first < 0.5 * (r.start + r.end) < last]
+        if len(same) != len(best):
+            raise ValueError(f"device {d.id}: {len(same)} executions of {program!r} in the "
+                             f"stretch of {len(best)} on device {devices[0].id}")
+        lo, hi = min(lo, same[0].start), max(hi, same[-1].end)
     return lo, hi
 
 

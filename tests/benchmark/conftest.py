@@ -2,8 +2,8 @@
 per-layer metric of the manifest, and a test that the table is whole. A PR
 that adds a metric may add files to the benchmark and edit none, so its cases
 live in a file of their own; this fixture enters their names in that table
-for the length of a test, and ``test_program_trace.py`` checks that every
-name entered here has its case there."""
+for the length of a test, and each file named here checks that the
+metrics entered under its name have their cases there."""
 
 import pytest
 
@@ -15,6 +15,7 @@ TESTED_IN_THEIR_OWN_FILE = {
     "step_conv_dot_ms": "test_program_trace.py",
     "step_norm_ms": "test_program_trace.py",
     "conv_dot_roofline": "test_program_trace.py",
+    "collective_exposed_pct": "test_four_chip_cell.py",
 }
 
 

@@ -47,7 +47,8 @@ def test_every_metric_entered_in_conftest_has_its_reader_and_case_here(tested_in
     here = {r.__name__.rsplit(".", 1)[-1] for r in (
         conv_dot_roofline, dispatch_lead_ms, idle_named_pct, prefetch_stage_ms,
         step_conv_dot_ms, step_norm_ms)}
-    assert set(tested_in_their_own_file) == here
+    assert {name for name, where in tested_in_their_own_file.items()
+            if where == "test_program_trace.py"} == here
     assert here <= {m["name"] for m in harness.load_manifest()["per_layer"]}
 
 
