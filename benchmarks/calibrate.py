@@ -17,7 +17,8 @@ program has to come out correct, the control and every fault not.
 ``--dtype float32`` runs the program in another type than the configuration
 states: a witness for where a gap comes from, never a lower reading.
 Prints one JSON line per seed with the leaves of the widest gaps, and writes
-every reading, leaf by leaf, to ``chiprun_out/readings_<cell>_<seed>.json``.
+every reading, leaf by leaf, to ``chiprun_out/readings_<cell>_<seed>.json``
+(where the cell keeps the first gradient, each leaf's turn in its place).
 """
 
 import argparse
@@ -130,6 +131,12 @@ def main(argv=None) -> int:
                                                           names, 5)
                 out[name + "_change_leaves"] = gaps_by_leaf(other["change_norms"],
                                                             ref["change_norms"], names, 5)
+        if "grad1" in ref:      # 436 MB a side in BERT-base: the leaves' turns are what is written
+            for side in full.values():
+                if isinstance(side, dict) and side is not ref and "grad1" in side:
+                    turns, _ = reference_train.leaf_turns(side.pop("grad1"), ref["grad1"])
+                    side["grad1_turns"] = [float(t) for t in turns]
+            del ref["grad1"]
         out["seconds_and_cache"] = timings
         print(json.dumps(out), flush=True)
         dump = harness.ROOT / "chiprun_out" / f"readings_{cell.name}_{seed}.json"

@@ -132,15 +132,23 @@ class Program:
         spec, model = self.cell.config["updater"], self.model
         state_key, factor = reference_train.first_gradient_from_moment(spec)
         self.fit(self.stage(self.Pool(0, count=1)))
-        grad_norms = [factor * float(n) for n in
-                      reference_train.leaf_norms(moment_tree(model.opt_state, state_key))]
+        moment = moment_tree(model.opt_state, state_key)
+        grad_norms = [factor * float(n) for n in reference_train.leaf_norms(moment)]
+        record = {}
+        if reference_train.takes_direction(self.cell.limits):
+            # the first gradient itself, as the timed step left it in the optimizer's
+            # state (a factor apart, which a direction does not see), kept on the
+            # host: only where the cell's limits name its direction
+            record["grad1"] = jax.device_get(moment)
+        del moment
         self.fit(self.stage(self.Pool(1, count=reference_train.CHECK_STEPS - 1)))
         change = [float(c) for c in
                   reference_train.leaf_norms_of_change(model.params, self._start_host)]
         self._start_host = None
         return {"losses": list(self.stamps.scores), "grad_norms": grad_norms,
                 "change_norms": change,
-                "state_norms": [float(n) for n in reference_train.leaf_norms(model.state)]}
+                "state_norms": [float(n) for n in reference_train.leaf_norms(model.state)],
+                **record}
 
     def train_programs(self) -> int:
         return self.model._jit_cache["train"]._cache_size()
@@ -160,7 +168,8 @@ class Program:
         return reference_train.follow(
             self.module, self.cell.config, self.key,
             _to_devices(self.pool[:reference_train.CHECK_STEPS], self.devices),
-            in_shardings=_reference_shardings(self.devices), **how)
+            in_shardings=_reference_shardings(self.devices),
+            keep_gradient=reference_train.takes_direction(self.cell.limits), **how)
 
 
 def run(cell: Cell, *, seed: int, seconds: float, trace: bool, devices: list, peaks: dict,

@@ -18,6 +18,10 @@ import jax
 import jax.numpy as jnp
 
 CHECK_STEPS = 3
+#: the numbers of the first gradient's direction; the gradient itself is kept,
+#: on both sides, only in a cell whose ``cells/<cell>.json`` gives one of them
+#: a limit
+DIRECTION_NUMBERS = ("grad_largest_turn", "grad_median_turn", "grad_whole_turn")
 #: leaves whose first reference gradient is under this share of the median
 #: leaf's are left out of the parameters' change (a key's bias under softmax:
 #: Adam moves it by round-off alone)
@@ -157,6 +161,31 @@ def leaf_norms_of_change(after, before):
             for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(before))]
 
 
+def takes_direction(limits: dict) -> bool:
+    return any(name in limits for name in DIRECTION_NUMBERS)
+
+
+@jax.jit
+def leaf_turns(got, want):
+    """How far each leaf of ``got`` is turned away from the same leaf of
+    ``want``, and the whole tree as one vector: the length of the difference
+    of the two unit vectors, which is the angle between them in radians while
+    it is small, 1.41 at a right angle and 2 at the opposite; NaN where one
+    of the two has no length. The size of either side does not enter it."""
+    got = [x.astype(jnp.float32) for x in jax.tree.leaves(got)]
+    want = [x.astype(jnp.float32) for x in jax.tree.leaves(want)]
+
+    def length(leaves):
+        return jnp.sqrt(sum((x ** 2).sum() for x in leaves))
+
+    def turn(pairs, a_length, b_length):
+        return jnp.sqrt(sum(((a / a_length - b / b_length) ** 2).sum() for a, b in pairs))
+
+    pairs = list(zip(got, want))
+    return ([turn([(a, b)], length([a]), length([b])) for a, b in pairs],
+            turn(pairs, length(got), length(want)))
+
+
 def leaf_names(tree) -> list:
     return [jax.tree_util.keystr(path) for path, _ in
             jax.tree_util.tree_flatten_with_path(tree)[0]]
@@ -164,12 +193,14 @@ def leaf_names(tree) -> list:
 
 # ------------------------------------------------------------------- follower
 def follow(module, cfg: dict, key, batches, *, precision: str = "float32",
-           keep_fraction: float = 1.0, in_shardings=None) -> dict:
+           keep_fraction: float = 1.0, in_shardings=None, keep_gradient: bool = False) -> dict:
     """Drive the plain reference through ``batches`` (the cell's first
     ``CHECK_STEPS``) from ``make_params(key)``. Returns the readings the
     comparison takes: each step's loss, the norm of every leaf of the first
     gradient as the optimizer gets it, and of every leaf's change after the
-    last step. ``keep_fraction`` < 1 plants the fault of a step that sees only
+    last step; with ``keep_gradient`` also ``grad1``, that first gradient
+    itself, on the host.
+    ``keep_fraction`` < 1 plants the fault of a step that sees only
     that leading share of each batch, its mean taken over it: every row is
     replaced by one of that share before the step, so the shapes, and with
     them the compiled program, stay the same."""
@@ -187,22 +218,29 @@ def follow(module, cfg: dict, key, batches, *, precision: str = "float32",
         grads = clip_global_norm(grads, spec.get("clip_global_norm", 0.0))
         new_params, new_opt = apply_updater(spec, grads, opt, params, step)
         state = {**state, **new_state} if isinstance(state, dict) else new_state
-        return new_params, state, new_opt, loss, leaf_norms(grads)
+        return new_params, state, new_opt, loss, leaf_norms(grads), grads if keep_gradient else ()
 
     placed = {} if in_shardings is None else {"in_shardings": in_shardings}
     step_jit = jax.jit(step_fn, donate_argnums=(1, 2), **placed)
     start, state = jax.jit(lambda k: module.make_params(k, cfg))(key)
+    if in_shardings is None:
+        # committed to the batch's device, as every later step's arguments are:
+        # left to the default, the first step lowers (and compiles) apart
+        start, state = jax.device_put((start, state), batches[0][0].sharding)
     params, opt = start, init_opt(spec, start)
-    losses, grad_norms = [], None
+    record, losses, grad_norms = {}, [], None
     for i, (x, y) in enumerate(batches):
-        params, state, opt, loss, gn = step_jit(params, state, opt, jnp.int32(i), x, y)
+        params, state, opt, loss, gn, grads = step_jit(params, state, opt, jnp.int32(i), x, y)
         losses.append(float(loss))
         if i == 0:
             grad_norms = [float(g) for g in gn]
+            if keep_gradient:
+                record["grad1"] = jax.device_get(grads)
+        del grads
     change = [float(c) for c in leaf_norms_of_change(params, start)]
     return {"losses": losses, "grad_norms": grad_norms, "change_norms": change,
             "state_norms": [float(n) for n in leaf_norms(state)],
-            "leaves": leaf_names(start), "state_leaves": leaf_names(state)}
+            "leaves": leaf_names(start), "state_leaves": leaf_names(state), **record}
 
 
 # ----------------------------------------------------------------- comparison
@@ -242,11 +280,24 @@ def median_leaf_gap(got: list, want: list, skip=()) -> float:
 
 def compare(program: dict, reference: dict, limits: dict) -> dict:
     """The numbers compared, each beside its limit. ``program`` and
-    ``reference`` hold ``losses``, ``grad_norms``, ``change_norms`` and, where
-    the model carries state forward (BatchNorm's running statistics),
-    ``state_norms`` after the last step.
+    ``reference`` hold ``losses``, ``grad_norms``, ``change_norms``, where
+    the model carries state forward (BatchNorm's running statistics)
+    ``state_norms`` after the last step, and where the cell's limits name the
+    first gradient's direction (``DIRECTION_NUMBERS``) ``grad1``, that
+    gradient itself.
     Every number is worked out and printed; one that has no limit in the
     cell's file is not compared (PERF.md says which and why).
+
+    ``grad_*_turn`` is how far the program's first gradient is turned away
+    from the reference's (``leaf_turns``): the leaf with the most elements,
+    the median leaf (over the leaves whose change is compared) and the whole
+    tree as one vector. Rounding is all but orthogonal to the true value, so
+    it enters a gap of norms by its power and a turn by its size: where a
+    step computed in the precision below slips under the gaps of norms, its
+    gradient still points elsewhere. The largest leaf is the steadiest from
+    seed to seed, and in a token model it is the embedding table, whose rows
+    each hold the gradient of one row of the batch: where the batch's
+    residuals all but cancel in the other leaves (PERF.md), they cannot there.
 
     ``*_leaf_gap`` is the worst leaf's gap of norms, ``*_median_gap`` the
     median leaf's (steady from seed to seed), ``*_norm_gap`` the gap of
@@ -272,9 +323,18 @@ def compare(program: dict, reference: dict, limits: dict) -> dict:
                                                       reference["state_norms"])
         numbers["state_median_gap"] = median_leaf_gap(program["state_norms"],
                                                       reference["state_norms"])
+    if "grad1" in program and "grad1" in reference:
+        turns, whole = leaf_turns(program["grad1"], reference["grad1"])
+        sizes = [x.size for x in jax.tree.leaves(reference["grad1"])]
+        numbers["grad_largest_turn"] = float(turns[sizes.index(max(sizes))])
+        turns = [float(t) for i, t in enumerate(turns) if i not in dead]
+        numbers["grad_median_turn"] = (math.nan if any(t != t for t in turns)      # no order
+                                       else statistics.median(turns))
+        numbers["grad_whole_turn"] = float(whole)
     checks = {name: {"value": value, "limit": limits[name]}
               for name, value in numbers.items() if name in limits}
-    ok = bool(checks) and all(c["value"] <= c["limit"] for c in checks.values())
+    ok = (bool(checks) and len(checks) == len(limits)
+          and all(c["value"] <= c["limit"] for c in checks.values()))
     if len(program["losses"]) != len(reference["losses"]):
         ok = False
     names = reference.get("leaves", [])
