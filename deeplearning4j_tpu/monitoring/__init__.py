@@ -31,6 +31,7 @@ and optionally written.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import time
@@ -201,6 +202,17 @@ class _FitMonitor:
         self.queue_depth = reg.gauge(
             "dl4j_prefetch_queue_depth",
             "Staged batches waiting in the prefetch queue at hand-over")
+        self.loop_passes = reg.gauge(
+            "dl4j_train_loop_passes",
+            "Passes the fitted model's looped stacks make over their layers")
+        self.loop_layer_applications = reg.gauge(
+            "dl4j_train_loop_layer_applications",
+            "Layer applications a step of the fitted model's looped stacks (passes x layers)")
+        self.exit_share = reg.gauge(
+            "dl4j_train_exit_share",
+            "Mean exit probability of each pass over the latest step's positions",
+            labels=("pass",))
+        self._exit_shares: collections.deque = collections.deque()
 
     @contextlib.contextmanager
     def phase(self, name: str, **ids):
@@ -217,6 +229,26 @@ class _FitMonitor:
     def iteration_done(self, score: float) -> None:
         self.iterations.inc()
         self.score.set(float(score))
+        if self._exit_shares:
+            # the step whose score this is finished before the score was
+            # fetched: its exit shares are ready, this fetch waits for nothing
+            for t, share in enumerate(self._exit_shares.popleft().tolist()):
+                self.exit_share.labels(**{"pass": str(t + 1)}).set(share)
+
+    def describe_loops(self, layers) -> None:
+        """The looped stacks of a model about to be fitted, as gauges."""
+        loops = [l for l in layers if hasattr(l, "layer_applications")]
+        if loops:
+            self.loop_passes.set(sum(l.times for l in loops))
+            self.loop_layer_applications.set(sum(l.layer_applications for l in loops))
+
+    def hold_exit_share(self, state) -> None:
+        """Keep a dispatched step's exit shares (``LoopExitOutputLayer``'s
+        state, four floats) until its score is delivered: a copy, since the
+        next step is given the state's buffers."""
+        share = state.get("exit_share") if isinstance(state, dict) else None
+        if share is not None:
+            self._exit_shares.append(jax.numpy.copy(share))
 
     def wrap_batches(self, data, model):
         """Iterate ``data`` timing each pull as the data-wait phase of the

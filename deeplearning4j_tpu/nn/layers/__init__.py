@@ -11,6 +11,7 @@ from deeplearning4j_tpu.nn.layers.core import (
 )
 from deeplearning4j_tpu.nn.layers.output import (
     OutputLayer, RnnOutputLayer, LossLayer, CenterLossOutputLayer, CnnLossLayer,
+    LoopExitOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.conv import (
     ConvolutionLayer, Convolution1DLayer, Convolution3DLayer,
@@ -33,14 +34,16 @@ from deeplearning4j_tpu.nn.layers.variational import (
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     SelfAttentionLayer, LearnedSelfAttentionLayer, TransformerEncoderLayer,
+    DecoderBlock,
 )
+from deeplearning4j_tpu.nn.layers.looped import LoopedStack
 
 __all__ = [
     "Layer", "register_layer",
     "DenseLayer", "ActivationLayer", "DropoutLayer", "EmbeddingLayer",
     "EmbeddingSequenceLayer", "ElementWiseMultiplicationLayer",
     "OutputLayer", "RnnOutputLayer", "LossLayer", "CenterLossOutputLayer",
-    "CnnLossLayer",
+    "CnnLossLayer", "LoopExitOutputLayer",
     "ConvolutionLayer", "Convolution1DLayer", "Convolution3DLayer",
     "Deconvolution2DLayer", "SeparableConvolution2DLayer",
     "DepthwiseConvolution2DLayer", "SubsamplingLayer", "Subsampling1DLayer",
@@ -51,5 +54,6 @@ __all__ = [
     "BidirectionalLayer", "GravesBidirectionalLSTMLayer", "LastTimeStepLayer",
     "MaskZeroLayer", "TimeDistributedLayer",
     "SelfAttentionLayer", "LearnedSelfAttentionLayer", "TransformerEncoderLayer",
+    "DecoderBlock", "LoopedStack",
     "Yolo2OutputLayer", "AutoEncoderLayer", "VariationalAutoencoderLayer",
 ]

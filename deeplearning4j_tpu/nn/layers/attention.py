@@ -4,7 +4,8 @@ Reference analog: org.deeplearning4j.nn.conf.layers.{SelfAttentionLayer,
 LearnedSelfAttentionLayer, RecurrentAttentionLayer} [UNVERIFIED in snapshot]
 built on libnd4j's multi_head_dot_product_attention. Extended net-new with a
 full pre-norm TransformerEncoderLayer (the BERT building block the reference
-reaches only via TF-import).
+reaches only via TF-import) and a DecoderBlock (rotary positions, RMSNorm
+before and after each half, a gated MLP, no biases).
 """
 
 from __future__ import annotations
@@ -272,3 +273,97 @@ class TransformerEncoderLayer(Layer):
         if not self.pre_norm:
             x = self._ln(x, params["ln2_g"], params["ln2_b"])
         return x, state
+
+
+def rotary_tables(positions: int, head_dim: int, theta: float):
+    """(cos, sin), each ``[positions, head_dim]`` in float32, of rotary
+    position embeddings in the rotate-half pairing: feature ``i`` is paired
+    with ``i + head_dim / 2`` and both turn by ``position * theta ** (-2 i /
+    head_dim)``."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rotary(t, rope):
+    """Rotate ``t`` ``[B, N, T, Dh]`` by its positions; the turn itself in
+    float32, the result in ``t``'s type."""
+    cos, sin = rope
+    tf = t.astype(jnp.float32)
+    half = t.shape[-1] // 2
+    turned = jnp.concatenate([-tf[..., half:], tf[..., :half]], axis=-1)
+    return (tf * cos + turned * sin).astype(t.dtype)
+
+
+def rms_norm(x, gain, eps: float):
+    """``x / rms(x) * gain`` over the last axis; the mean of squares in float32."""
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt((xf * xf).mean(-1, keepdims=True) + eps)
+    return (xf * inv * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class DecoderBlock(Layer):
+    """Causal decoder block with rotary positions, sandwich RMSNorm and a
+    gated MLP, no biases — net-new (the block of looped / modern decoders).
+
+    ``a = Attn(N1(h)); h = h + N2(a); m = MLP(N3(h)); h = h + N4(m)``, each
+    ``N`` an RMSNorm with its own gain; ``Attn`` projects to ``n_heads`` heads
+    of ``head_dim``, rotates q and k, and attends causally through the op
+    registry (so the flash kernel's predicate decides as for every caller);
+    ``MLP(u) = (silu(u Wg) * (u Wu)) Wd``.
+
+    ``apply`` takes ``rope``, the ``rotary_tables`` of its positions, from a
+    container that applies many blocks in one step (``LoopedStack``) so that
+    they are computed once; alone it makes its own.
+    """
+
+    d_model: int
+    n_heads: int = 8
+    head_dim: Optional[int] = None
+    d_ff: Optional[int] = None
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-6
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.d_model // self.n_heads
+
+    def output_type(self, itype):
+        return InputType.recurrent(self.d_model, itype.shape[0])
+
+    def init(self, key, itype):
+        D, A = self.d_model, self.n_heads * self.head_size
+        F = self.d_ff or 4 * D
+        ks = jax.random.split(key, 7)
+        p = {"Wq": self._w(ks[0], (D, A)), "Wk": self._w(ks[1], (D, A)),
+             "Wv": self._w(ks[2], (D, A)), "Wo": self._w(ks[3], (A, D)),
+             "Wg": self._w(ks[4], (D, F)), "Wu": self._w(ks[5], (D, F)),
+             "Wd": self._w(ks[6], (F, D))}
+        for g in ("n1_g", "n2_g", "n3_g", "n4_g"):
+            p[g] = jnp.ones((D,))
+        return p, {}
+
+    def rope_tables(self, positions: int):
+        return rotary_tables(positions, self.head_size, self.rope_theta)
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None, rope=None):
+        B, T, _ = x.shape
+        if rope is None:
+            rope = self.rope_tables(T)
+
+        def heads(t):
+            return t.reshape(B, T, self.n_heads, self.head_size).transpose(0, 2, 1, 3)
+
+        h = rms_norm(x, params["n1_g"], self.rms_eps)
+        q = apply_rotary(heads(h @ params["Wq"]), rope)
+        k = apply_rotary(heads(h @ params["Wk"]), rope)
+        o = op("dot_product_attention")(q, k, heads(h @ params["Wv"]),
+                                        mask=_attn_mask(mask, T, T), causal=True)
+        a = o.transpose(0, 2, 1, 3).reshape(B, T, -1) @ params["Wo"]
+        x = x + rms_norm(a, params["n2_g"], self.rms_eps)
+        h = rms_norm(x, params["n3_g"], self.rms_eps)
+        m = (jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])) @ params["Wd"]
+        return x + rms_norm(m, params["n4_g"], self.rms_eps), state

@@ -33,6 +33,13 @@ from deeplearning4j_tpu.nn.weights import init_weight
 LAYER_REGISTRY: dict[str, type] = {}
 
 
+def scope_name(index, layer) -> str:
+    """``<index>.<LayerClass>`` (the layer's own name where it has one): the
+    ``jax.named_scope`` a layer runs under, by which a trace's reader tells
+    layer kinds apart without a table."""
+    return f"{getattr(layer, 'name', None) or index}.{type(layer).__name__}"
+
+
 def register_layer(cls):
     """Class decorator: make a layer JSON round-trippable by class name."""
     LAYER_REGISTRY[cls.__name__] = cls
@@ -136,7 +143,7 @@ def _ser(v):
     if hasattr(v, "to_dict"):
         return v.to_dict()
     if isinstance(v, tuple):
-        return list(v)
+        return [_ser(item) for item in v]
     return v
 
 
@@ -144,7 +151,7 @@ def _deser(v, field):
     if isinstance(v, dict) and "@layer" in v:
         return Layer.from_dict(v)
     if isinstance(v, list):
-        return tuple(v)
+        return tuple(_deser(item, field) for item in v)
     if isinstance(v, dict) and "@type" in v:
         from deeplearning4j_tpu.optimize.updaters import updater_from_dict
 

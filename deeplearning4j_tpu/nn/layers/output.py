@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
@@ -93,6 +94,90 @@ class RnnOutputLayer(OutputLayer):
         # sum over time -> per-example score (DL4J averages over *present* steps
         # at the score level; we sum here and normalize in the model by mask sum)
         return per.reshape(b, t).sum(axis=1)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class LoopExitOutputLayer(Layer):
+    """The exits of a looped stack — net-new (looped decoders with a learned
+    exit; no DL4J analog). Input: every pass's state, stacked ``[times, batch,
+    time, features]`` (``LoopedStack``); labels: an int32 class index for
+    every position, ``[batch, time]``.
+
+    One head ``W`` and one gate ``Wg, bg`` serve every pass: ``logits_t = z_t
+    W``, ``g_t = sigmoid(z_t . Wg + bg)``. A position leaves at pass *t* with
+    probability ``p_t = g_t prod_{j<t} (1 - g_j)`` (the last pass takes what
+    is left), and its loss is ``sum_t p_t CE(logits_t, y) - beta H(p)``, ``H``
+    the entropy of ``p``; a row's score is the sum over its positions, as
+    ``RnnOutputLayer``'s. The exit distribution is worked out in float32 from
+    log-sigmoids.
+
+    Four passes' logits cannot live at once at a language model's vocabulary,
+    so the layer scores its *input* (``score_from_features``): the passes are a
+    ``lax.map`` whose body, head and cross-entropy of one pass, is recomputed
+    on the backward pass; only ``[times, batch, time]`` cross-entropies and
+    gate logits leave it. ``output()`` and ``preout`` give the last pass's
+    logits. The step's mean exit probability of each pass is kept as the
+    layer's state (``exit_share``), for a monitor to read.
+    """
+
+    n_out: int
+    n_in: Optional[int] = None
+    times: int = 1          # the passes handed on: the length of ``exit_share``
+    beta: float = 0.05
+    activation: str = "identity"
+
+    def output_type(self, itype):
+        return InputType.recurrent(self.n_out, itype.shape[0] if itype.kind == "rnn" else None)
+
+    def init(self, key, itype):
+        nin = self.n_in or itype.shape[1]
+        k_head, k_gate = jax.random.split(key)
+        return ({"W": self._w(k_head, (nin, self.n_out)),
+                 "Wg": self._w(k_gate, (nin, 1))[:, 0], "bg": jnp.zeros((1,))},
+                {"exit_share": jnp.zeros((self.times,), jnp.float32)})
+
+    def preout(self, params, x):
+        return x[-1] @ params["W"]
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return resolve_activation(self.activation)(self.preout(params, x)), state
+
+    @staticmethod
+    def exit_log_probs(gate_logits):
+        """``log p_t`` ``[times, ...]`` from the gates' logits, in float32."""
+        gate_logits = gate_logits.astype(jnp.float32)
+        stay = jax.nn.log_sigmoid(-gate_logits)                 # log(1 - g_t)
+        stayed = jnp.cumsum(stay, axis=0) - stay                # sum over j < t
+        leave = jax.nn.log_sigmoid(gate_logits[:-1]) + stayed[:-1]
+        return jnp.concatenate([leave, stayed[-1:]], axis=0)    # the last pass takes the rest
+
+    def score_from_features(self, params, state, labels, states, mask=None):
+        """(per-row score ``[batch]``, new state) from the passes' states."""
+        if states.shape[0] != self.times:
+            raise ValueError(f"LoopExitOutputLayer(times={self.times}) is handed "
+                             f"{states.shape[0]} passes: give it the looped stack's ``times``")
+        labels = labels.astype(jnp.int32)
+
+        @jax.checkpoint
+        def one_exit(z):
+            with jax.named_scope("exit"):
+                logits = z @ params["W"]
+                ce = (jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+                      - jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+                      .astype(jnp.float32))
+                gate = (z.astype(jnp.float32) @ params["Wg"].astype(jnp.float32)
+                        + params["bg"].astype(jnp.float32))
+                return ce, gate
+
+        ce, gate = jax.lax.map(one_exit, states)                # [times, batch, time] each
+        log_p = self.exit_log_probs(gate)
+        p = jnp.exp(log_p)
+        per = (p * ce).sum(0) + self.beta * (p * log_p).sum(0)  # - beta H(p)
+        if mask is not None:
+            per = per * mask.reshape(per.shape).astype(per.dtype)
+        share = jax.lax.stop_gradient(p.mean(axis=(1, 2)))
+        return per.sum(axis=1), {**state, "exit_share": share}
 
 
 @register_layer

@@ -27,6 +27,7 @@ from deeplearning4j_tpu.common.dtypes import BF16, FLOAT32
 from deeplearning4j_tpu.common.env import env
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import MultiLayerConfiguration
+from deeplearning4j_tpu.nn.layers.base import scope_name as _scope_name
 from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
 from deeplearning4j_tpu.optimize.async_dispatch import (
     _fetch_scalar, deliver_score, drain_scores, get_window, leading_dim,
@@ -72,12 +73,6 @@ def merge_carry_rows(carries, sub, rows):
     carry dict (functional — inputs are not mutated)."""
     idx = jnp.atleast_1d(jnp.asarray(rows, jnp.int32))
     return jax.tree_util.tree_map(lambda a, r: a.at[idx].set(r), carries, sub)
-
-
-def _scope_name(index: int, layer) -> str:
-    """``<index>.<LayerClass>`` (the layer's own name where it has one): a
-    trace's reader tells layer kinds apart without a table."""
-    return f"{getattr(layer, 'name', None) or index}.{type(layer).__name__}"
 
 
 def global_norm_clip(grads, max_norm):
@@ -147,7 +142,8 @@ class MultiLayerNetwork:
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, state, x, train, rng, mask):
-        """Walk layers; returns (pre-output of final layer, new states, final mask)."""
+        """Walk layers; returns (pre-output of final layer, new states, final
+        mask, the final layer's input)."""
         new_states = []
         itype_chain = self.conf.layer_input_types
         n = len(self.layers)
@@ -165,7 +161,12 @@ class MultiLayerNetwork:
                 new_states.append(state[i])
                 return preout, new_states, mask, x
             with scope:
-                if self.conf.remat and train:
+                if getattr(layer, "remats_itself", False):
+                    # a container of layers: each application inside it is its
+                    # own jax.checkpoint, never the container as a whole
+                    x, s = layer.apply(params[i], state[i], x, train=train, rng=k,
+                                       mask=mask, remat=self.conf.remat and train)
+                elif self.conf.remat and train:
                     # remat policy (workspace-tuning analog): save only each
                     # layer's input; recompute its internals during backprop
                     x, s = jax.checkpoint(
@@ -231,6 +232,11 @@ class MultiLayerNetwork:
         count) — the data-parallel trainers pass global_valid/dp so that a
         mean over replicas reproduces the GLOBAL-batch loss exactly even
         when padding is distributed unevenly across shards."""
+        out_layer = self.layers[-1]
+        # an output layer that scores its input itself (every pass's exit of a
+        # looped stack: its pre-outputs cannot all live at once); the pre-output
+        # ``_forward`` traces is then read by nothing and XLA drops it
+        from_features = hasattr(out_layer, "score_from_features")
         if carries is None:
             preout, new_states, out_mask, features = self._forward(
                 params, state, x, train, rng, mask)
@@ -241,8 +247,11 @@ class MultiLayerNetwork:
         with jax.named_scope("loss"):
             if label_mask is not None:
                 out_mask = label_mask
-            out_layer = self.layers[-1]
-            per = out_layer.score_from_preout(y, preout, out_mask)
+            if from_features:
+                per, new_states[-1] = out_layer.score_from_features(
+                    params[-1], state[-1], y, features, out_mask)
+            else:
+                per = out_layer.score_from_preout(y, preout, out_mask)
             if isinstance(out_layer, CenterLossOutputLayer):
                 # a per-example loss mask must cover the center term and the
                 # persisted center update too (r5)
@@ -556,6 +565,7 @@ class MultiLayerNetwork:
         elif window is None:
             with mon.phase("device_step", step=self.step_count):
                 self.params, self.state, self.opt_state, loss = step_fn(*args)
+                mon.hold_exit_share(self.state[-1])
                 # the host fetch is the device sync: step time includes it
                 result = self._score_value = _fetch_scalar(loss)
             with mon.phase("listeners", step=self.step_count):
@@ -566,6 +576,7 @@ class MultiLayerNetwork:
         else:
             with mon.phase("dispatch", step=self.step_count):
                 self.params, self.state, self.opt_state, loss = step_fn(*args)
+                mon.hold_exit_share(self.state[-1])
             try:
                 result = window.submit(loss)  # drains oldest once over capacity
             except BaseException:
@@ -595,6 +606,8 @@ class MultiLayerNetwork:
             # data-wait spans time the iterator pull per batch (host input
             # pipeline vs device step split); None = monitoring off
             mon = monitoring.fit_monitor()
+            if mon is not None:
+                mon.describe_loops(self.layers)
             try:
                 for ds in (data if mon is None
                            else mon.wrap_batches(data, self)):
