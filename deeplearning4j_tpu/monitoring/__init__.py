@@ -208,6 +208,10 @@ class _FitMonitor:
         self.loop_layer_applications = reg.gauge(
             "dl4j_train_loop_layer_applications",
             "Layer applications a step of the fitted model's looped stacks (passes x layers)")
+        self.loop_kernel_keeping_applications = reg.gauge(
+            "dl4j_train_loop_kernel_keeping_applications",
+            "Layer applications a step of the looped stacks under a checkpoint that keeps the "
+            "attention kernel's named results (0 with remat off)")
         self.exit_share = reg.gauge(
             "dl4j_train_exit_share",
             "Mean exit probability of each pass over the latest step's positions",
@@ -235,12 +239,15 @@ class _FitMonitor:
             for t, share in enumerate(self._exit_shares.popleft().tolist()):
                 self.exit_share.labels(**{"pass": str(t + 1)}).set(share)
 
-    def describe_loops(self, layers) -> None:
-        """The looped stacks of a model about to be fitted, as gauges."""
+    def describe_loops(self, layers, remat: bool = False) -> None:
+        """The looped stacks of a model about to be fitted, as gauges. Under
+        ``remat`` every application is its own ``checkpoint_layer``."""
         loops = [l for l in layers if hasattr(l, "layer_applications")]
         if loops:
+            applications = sum(l.layer_applications for l in loops)
             self.loop_passes.set(sum(l.times for l in loops))
-            self.loop_layer_applications.set(sum(l.layer_applications for l in loops))
+            self.loop_layer_applications.set(applications)
+            self.loop_kernel_keeping_applications.set(applications if remat else 0)
 
     def hold_exit_share(self, state) -> None:
         """Keep a dispatched step's exit shares (``LoopExitOutputLayer``'s

@@ -35,7 +35,9 @@ class MultiLayerConfiguration:
     max_grad_norm: float = 0.0  # 0 = no clipping (GradientNormalization analog)
     remat: bool = False  # rematerialize per-layer activations in backprop
     # (jax.checkpoint; XLA-native replacement for the reference's workspace
-    # memory tuning: trades recompute FLOPs for activation HBM)
+    # memory tuning: trades recompute FLOPs for activation HBM). Kept per
+    # layer: its input, plus the attention kernel's output and log-sum-exp
+    # where the flash kernel runs (nn/layers/base.py::REMAT_POLICY)
 
     # resolved by build(): per-layer input types
     layer_input_types: list = dataclasses.field(default_factory=list)
@@ -162,7 +164,8 @@ class NeuralNetConfiguration:
         return self
 
     def gradient_checkpointing(self, on: bool = True) -> "NeuralNetConfiguration":
-        """Remat per-layer activations during backprop (jax.checkpoint)."""
+        """Remat per-layer activations during backprop (jax.checkpoint): each
+        layer keeps its input, plus the attention kernel's output where it runs."""
         self._remat = bool(on)
         return self
 

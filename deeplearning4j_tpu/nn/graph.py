@@ -24,6 +24,7 @@ from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.graph import LayerVertex
 from deeplearning4j_tpu.common.env import env
+from deeplearning4j_tpu.nn.layers.base import checkpoint_layer
 from deeplearning4j_tpu.nn.multilayer import (
     _check_carry_batch, _tree_cast, _unpack, global_norm_clip,
 )
@@ -138,7 +139,7 @@ class ComputationGraph:
                 continue
             with scope:
                 if self.conf.remat and train:
-                    out, s2 = jax.checkpoint(
+                    out, s2 = checkpoint_layer(
                         lambda pp, ss, ii, kk, _v=v: _v.apply(
                             pp, ss, ii, train=True, rng=kk, masks=masks)
                     )(p, s, ins, k)

@@ -27,10 +27,25 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops.activations import get_activation
+from deeplearning4j_tpu.ops.pallas.flash_attention import SAVED_LSE, SAVED_OUT
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.weights import init_weight
 
 LAYER_REGISTRY: dict[str, type] = {}
+
+#: what a layer's checkpoint keeps besides the layer's input under the
+#: configuration's ``remat``: the flash kernel's output and log-sum-exp, T-sized
+#: results of T^2 work, which exist only where the registry chose the kernel;
+#: a layer that names nothing keeps its input alone. A wider keep-list (XLA's
+#: own products, by name) would go here.
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(SAVED_OUT, SAVED_LSE)
+
+
+def checkpoint_layer(fn):
+    """``jax.checkpoint`` of one layer application, for every network that
+    honours ``remat`` (``MultiLayerNetwork``, ``ComputationGraph``,
+    ``LoopedStack``)."""
+    return jax.checkpoint(fn, policy=REMAT_POLICY)
 
 
 def scope_name(index, layer) -> str:

@@ -11,8 +11,11 @@ the sum over the passes and ``num_params()`` counts it once.
 
 The passes are a ``lax.scan``: one body serves every pass, so the step's
 program holds the held layers once however many times they run. Under the
-configuration's ``remat`` each *layer application* is its own
-``jax.checkpoint`` (``times x len(layers)`` saved inputs); the network does
+configuration's ``remat`` each *layer application* is its own checkpoint
+(``checkpoint_layer``): it keeps the application's input and, where the
+block's attention ran in the flash kernel, the kernel's output and log-sum-exp
+(``times x len(layers)`` of each), so the backward pass recomputes the block's
+products and element-wise passes but not the kernel's forward. The network does
 not wrap the container whole, which would save one input and recompute the
 whole loop in one piece (``remats_itself``).
 """
@@ -25,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, scope_name
+from deeplearning4j_tpu.nn.layers.base import Layer, checkpoint_layer, register_layer, scope_name
 
 
 @register_layer
@@ -82,7 +85,7 @@ class LoopedStack(Layer):
                     return _layer.apply(p, {}, zz, train=train, rng=kk, mask=mm, **extra)[0]
 
                 with jax.named_scope(scope_name(i, layer)):
-                    z = (jax.checkpoint(run) if remat else run)(
+                    z = (checkpoint_layer(run) if remat else run)(
                         params[str(i)], z, k, mask, extras[i])
             if self.norm is not None:
                 with jax.named_scope(scope_name("norm", self.norm)):

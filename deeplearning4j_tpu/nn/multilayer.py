@@ -27,7 +27,7 @@ from deeplearning4j_tpu.common.dtypes import BF16, FLOAT32
 from deeplearning4j_tpu.common.env import env
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.layers.base import scope_name as _scope_name
+from deeplearning4j_tpu.nn.layers.base import checkpoint_layer, scope_name as _scope_name
 from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
 from deeplearning4j_tpu.optimize.async_dispatch import (
     _fetch_scalar, deliver_score, drain_scores, get_window, leading_dim,
@@ -168,8 +168,9 @@ class MultiLayerNetwork:
                                        mask=mask, remat=self.conf.remat and train)
                 elif self.conf.remat and train:
                     # remat policy (workspace-tuning analog): save only each
-                    # layer's input; recompute its internals during backprop
-                    x, s = jax.checkpoint(
+                    # layer's input (and the attention kernel's output where it
+                    # ran); recompute its other internals during backprop
+                    x, s = checkpoint_layer(
                         lambda p, st, xx, kk, mm, _l=layer: _l.apply(
                             p, st, xx, train=True, rng=kk, mask=mm)
                     )(params[i], state[i], x, k, mask)
@@ -607,7 +608,7 @@ class MultiLayerNetwork:
             # pipeline vs device step split); None = monitoring off
             mon = monitoring.fit_monitor()
             if mon is not None:
-                mon.describe_loops(self.layers)
+                mon.describe_loops(self.layers, self.conf.remat)
             try:
                 for ds in (data if mon is None
                            else mon.wrap_batches(data, self)):

@@ -27,11 +27,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.pallas.interpret import interpret_mode
 from deeplearning4j_tpu.ops.registry import register_impl
+
+#: ``jax.ad_checkpoint.checkpoint_name``s of the forward's two results that the
+#: backward needs (``_flash_fwd``): what a layer's checkpoint has to keep for
+#: ``remat`` not to run the forward kernel again (``nn/layers/base.py``)
+SAVED_OUT, SAVED_LSE = "flash_attention_out", "flash_attention_lse"
 
 
 def _sds(shape, dtype, vma=None):
@@ -335,8 +341,15 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
     # k-blocks outer, q-blocks inner: index maps swap i<->j roles
     q_spec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0),
                            memory_space=pltpu.VMEM)
+    # k, v, dk and dv stay put along the inner (q-block) axis: one buffer each.
+    # With two, at bwd_tiles' (1024, 1024) the call needs 16.18-16.68 MB of
+    # scoped VMEM whenever XLA keeps none of its operands or results in VMEM,
+    # over the v5e's 16 MB: it compiled or not by what XLA's memory-space
+    # assignment did around it (alone at T >= 8,192 it never did). The second
+    # buffer bought overlap once per k-block only.
     k_spec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
-                           memory_space=pltpu.VMEM)
+                           memory_space=pltpu.VMEM,
+                           pipeline_mode=pl.Buffered(1))
     row_spec2 = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
                              memory_space=pltpu.VMEM)
     in_specs2 = [q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2]
@@ -398,9 +411,19 @@ def _flash(q, k, v, kmask, causal, scale, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k):
+    """The forward under differentiation: ``out`` and the residuals the
+    backward kernels read. ``out`` and ``lse`` carry names, so a
+    ``jax.checkpoint`` whose policy keeps them (``checkpoint_layer``) stores
+    T-sized results instead of running the T^2 forward again on the backward
+    pass; q, k and v are XLA's products and are recomputed from the
+    checkpoint's input as before. Under no such policy a name is the
+    identity and lowers to nothing."""
     out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
                               interpret=interpret_mode(), kmask=kmask)
+    # the named ``out`` is the primal result too: whatever reads it downstream
+    # (the output projection's weight gradient) then reads the kept value
+    out, lse = checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
     return out, (q, k, v, kmask, out, lse)
 
 

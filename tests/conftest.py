@@ -9,6 +9,7 @@ that runs on a real v5e mesh runs here on 8 virtual CPU devices.
 Must run before jax is imported anywhere, hence top of conftest.
 """
 
+import collections
 import os
 
 # Tests run on the virtual 8-device CPU mesh; the env var is set here (not
@@ -39,6 +40,30 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it (a scan's body,
+    a checkpoint's, a custom derivative's)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.fixture
+def kernel_calls():
+    """``kernel_calls(fn, *args)``: how often each Pallas kernel, by its name,
+    stands in ``fn``'s jaxpr (a ``collections.Counter``)."""
+    def count(fn, *args):
+        return collections.Counter(
+            eqn.params["name"] for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call")
+
+    return count
 
 
 @pytest.fixture

@@ -6,6 +6,7 @@ reference (``benchmarks/configs/ouro_2p6b.py``, which imports nothing of the
 program) or against the same layers applied by hand."""
 
 import hashlib
+import importlib
 import re
 import sys
 from pathlib import Path
@@ -22,11 +23,14 @@ for _path in (ROOT / "tests" / "benchmark", ROOT):
 
 from benchmarks import program_trace  # noqa: E402
 from benchmarks.configs import ouro_2p6b as reference  # noqa: E402
+from deeplearning4j_tpu.common.env import env  # noqa: E402
 from deeplearning4j_tpu.datasets.dataset import DataSet  # noqa: E402
 from deeplearning4j_tpu.nn.conf.builders import (  # noqa: E402
     MultiLayerConfiguration, NeuralNetConfiguration,
 )
 from deeplearning4j_tpu.nn.conf.inputs import InputType  # noqa: E402
+from deeplearning4j_tpu.nn.graph import ComputationGraph  # noqa: E402
+from deeplearning4j_tpu.nn.layers import base as layers_base  # noqa: E402
 from deeplearning4j_tpu.nn.layers import (  # noqa: E402
     DecoderBlock, EmbeddingSequenceLayer, LoopedStack, LoopExitOutputLayer, RMSNormLayer,
     RnnOutputLayer,
@@ -35,6 +39,9 @@ from deeplearning4j_tpu.nn.layers.attention import apply_rotary, rotary_tables  
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork  # noqa: E402
 from deeplearning4j_tpu.optimize.updaters import Sgd  # noqa: E402
 from deeplearning4j_tpu.zoo import Ouro  # noqa: E402
+
+# the module: the package's attribute of that name is the function
+flash = importlib.import_module("deeplearning4j_tpu.ops.pallas.flash_attention")
 
 VOCAB, D, HEADS, DH, FF, T, B = 96, 64, 2, 32, 80, 16, 4
 CFG = {"hidden_size": D, "num_attention_heads": HEADS, "head_dim": DH, "intermediate_size": FF,
@@ -71,11 +78,15 @@ def batch(seed=0, rows=B):
             rng.integers(0, VOCAB, (rows, T), dtype=np.int32))
 
 
-def loss_and_grad(model, x, y, train=True):
+def loss_of(model, x, y, train=True):
     def loss(p):
         return model._loss_terms(p, model.state, jnp.asarray(x), jnp.asarray(y), None, None,
                                  train=train)[0]
-    return jax.value_and_grad(loss)(model.params)
+    return loss
+
+
+def loss_and_grad(model, x, y, train=True):
+    return jax.value_and_grad(loss_of(model, x, y, train))(model.params)
 
 
 def assert_trees_close(got, want, rtol=1e-5, atol=1e-6):
@@ -173,15 +184,25 @@ def test_looped_stack_refuses_a_layer_that_keeps_state():
         LoopedStack(layers=(BatchNormalizationLayer(),), times=2).init(jax.random.key(0), ITYPE)
 
 
+@pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("train", [True, False])
-def test_remat_on_and_off_give_the_same_loss_and_gradient(train):
+def test_remat_on_and_off_give_the_same_loss_and_gradient(train, kernel, monkeypatch, kernel_calls):
+    """On the kernel path (forced, interpret mode) the checkpoints keep the
+    kernel's output and log-sum-exp: the values a second call would give."""
+    monkeypatch.setattr(env, "force_pallas", kernel)
     x, y = batch()
     with_remat, without = tiny_ouro(remat=True), tiny_ouro(remat=False)
     assert with_remat.conf.remat and not without.conf.remat
     seeded(with_remat), seeded(without)
     (la, ga), (lb, gb) = loss_and_grad(with_remat, x, y, train), loss_and_grad(without, x, y, train)
-    np.testing.assert_allclose(la, lb, rtol=1e-6)
-    assert_trees_close(ga, gb, rtol=1e-5, atol=1e-7)
+    if kernel:
+        assert float(la) == float(lb)
+        assert_trees_close(ga, gb, rtol=0, atol=0)
+        calls = kernel_calls(jax.grad(loss_of(with_remat, x, y, train)), with_remat.params)
+        assert calls["flash_attention_fwd"] == calls["flash_attention_bwd_dq"] == 2
+    else:
+        np.testing.assert_allclose(la, lb, rtol=1e-6)
+        assert_trees_close(ga, gb, rtol=1e-5, atol=1e-7)
 
 
 def test_under_remat_every_layer_application_is_its_own_checkpoint_never_the_loop():
@@ -198,6 +219,78 @@ def test_under_remat_every_layer_application_is_its_own_checkpoint_never_the_loo
     whole = str(jax.make_jaxpr(lambda p: model._loss_terms(
         p, model.state, jnp.asarray(x), jnp.asarray(y), None, None)[0])(model.params))
     assert len(remats.findall(whole)) == 1 + 2 + 1      # the embedding, the two held layers, one exit
+
+
+# ------------------------------------- what a layer's checkpoint keeps of the kernel
+def plain_net(remat):
+    b = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(lr=0.1)).gradient_checkpointing(remat)
+         .list().layer(EmbeddingSequenceLayer(n_in=VOCAB, n_out=D)).layer(BLOCK).layer(BLOCK)
+         .layer(RnnOutputLayer(n_out=VOCAB, has_bias=False, activation="softmax", loss="sparsemcxent")))
+    return MultiLayerNetwork(b.set_input_type(InputType.recurrent(VOCAB, T)).build()).init()
+
+
+def stack_gradient(remat=True):
+    stack = looped()
+    params, _ = stack.init(jax.random.key(7), ITYPE)
+    x = jax.random.normal(jax.random.key(8), (B, T, D))
+    return jax.grad(lambda p: stack.apply(p, {}, x, train=True, remat=remat)[0].sum()), params
+
+
+def multilayer_gradient(remat=True):
+    model = plain_net(remat)
+    return jax.grad(loss_of(model, *batch())), model.params
+
+
+def graph_gradient(remat=True):
+    conf = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(lr=0.1)).gradient_checkpointing(remat)
+            .graph_builder().add_inputs("in").set_input_types(**{"in": InputType.recurrent(D, T)})
+            .add_layer("a", BLOCK, "in").add_layer("b", BLOCK, "a")
+            .add_layer("out", RnnOutputLayer(n_out=VOCAB, has_bias=False, activation="softmax",
+                                             loss="sparsemcxent"), "b")
+            .set_outputs("out").build())
+    graph = ComputationGraph(conf).init()
+    x = jax.random.normal(jax.random.key(8), (B, T, D))
+    return jax.grad(lambda p: graph._forward(p, graph.state, {"in": x}, True, None)[0]["b"].sum()), graph.params
+
+
+GRADIENTS = {"looped_stack": stack_gradient, "multilayer": multilayer_gradient, "graph": graph_gradient}
+
+
+@pytest.mark.parametrize("which", sorted(GRADIENTS))
+def test_under_remat_the_kernels_forward_stands_once_for_each_backward(which, monkeypatch, kernel_calls):
+    """Two blocks: the gradient holds the forward kernel twice, as without
+    ``remat``; under a checkpoint that keeps nothing by name, four times."""
+    monkeypatch.setattr(env, "force_pallas", True)
+    kept = kernel_calls(*GRADIENTS[which]())
+    assert kept["flash_attention_bwd_dq"] == kept["flash_attention_bwd_dkv"] == 2
+    assert kept["flash_attention_fwd"] == kept["flash_attention_bwd_dq"]
+    assert kernel_calls(*GRADIENTS[which](remat=False)) == kept
+    monkeypatch.setattr(layers_base, "REMAT_POLICY", None)      # a bare jax.checkpoint
+    bare = kernel_calls(*GRADIENTS[which]())
+    assert bare["flash_attention_fwd"] == 2 * bare["flash_attention_bwd_dq"] == 4
+    assert bare["flash_attention_bwd_dkv"] == 2
+
+
+@pytest.mark.parametrize("which", sorted(GRADIENTS))
+def test_the_policy_is_inert_where_xlas_attention_runs(which, monkeypatch, kernel_calls):
+    """T = 16 is under the kernel's predicate: nothing in a layer is named, and
+    the step under ``remat`` is the bare checkpoint's, to the letter."""
+    fn, params = GRADIENTS[which]()
+    assert not kernel_calls(fn, params)
+    assert not {flash.SAVED_OUT, flash.SAVED_LSE} & set(re.findall(r"name=(\w+)", str(jax.make_jaxpr(fn)(params))))
+    kept = jax.jit(fn).lower(params).as_text()
+    assert flash.SAVED_OUT not in kept and flash.SAVED_LSE not in kept
+    monkeypatch.setattr(layers_base, "REMAT_POLICY", None)
+    fn, params = GRADIENTS[which]()
+    assert jax.jit(fn).lower(params).as_text() == kept
+
+
+def test_outside_a_checkpoint_the_kernels_names_lower_to_nothing(monkeypatch):
+    monkeypatch.setattr(env, "force_pallas", True)
+    fn, params = stack_gradient(remat=False)
+    assert {flash.SAVED_OUT, flash.SAVED_LSE} <= set(re.findall(r"name=(\w+)", str(jax.make_jaxpr(fn)(params))))
+    lowered = jax.jit(fn).lower(params).as_text()
+    assert flash.SAVED_OUT not in lowered and flash.SAVED_LSE not in lowered
 
 
 # --------------------------------------------------------------- the exits
@@ -340,6 +433,20 @@ def test_exit_shares_and_loop_gauges_are_recorded_with_monitoring_on_only(monito
     assert "dl4j_train_loop_passes 3" in text and "dl4j_train_loop_layer_applications 6" in text
     shares = [float(m.group(1)) for m in re.finditer(r'dl4j_train_exit_share\{pass="\d"\} (\S+)', text)]
     np.testing.assert_allclose(shares, model.state[-1]["exit_share"], atol=1e-6)
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_gauge_of_kernel_keeping_applications_follows_remat_and_leaves_the_step_alone(
+        remat, monitoring_off):
+    monitoring = monitoring_off
+    model = tiny_ouro(remat=remat)
+    args = (model.params, model.state, model.opt_state, jnp.asarray(0, jnp.int32),
+            *map(jnp.asarray, batch()), jax.random.key(0), None)
+    off = model._make_train_step().lower(*args).as_text()
+    monitoring.enable()
+    model.fit([DataSet(*batch())])
+    assert f"dl4j_train_loop_kernel_keeping_applications {6 if remat else 0}" in monitoring.metrics_text()
+    assert tiny_ouro(remat=remat)._make_train_step().lower(*args).as_text() == off
 
 
 # ------------------------------------------------------------------- round trips

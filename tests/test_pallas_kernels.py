@@ -6,6 +6,8 @@ and assert allclose. Kernels run in Pallas interpret mode off-TPU, so these
 tests validate kernel logic; Mosaic compilation is exercised on real TPU.
 """
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,10 @@ from deeplearning4j_tpu.ops import get_op
 from deeplearning4j_tpu.ops.attention import dot_product_attention
 from deeplearning4j_tpu.ops.pallas import flash_attention, fused_lstm_layer
 from deeplearning4j_tpu.ops.recurrent import lstm_layer
+
+# the module: the package's attribute of that name is the function
+flash_module = importlib.import_module(
+    "deeplearning4j_tpu.ops.pallas.flash_attention")
 
 
 class TestFlashAttention:
@@ -531,6 +537,88 @@ class TestFlashAttentionBackward:
                      .astype(jnp.float32).sum())(q)
         assert g.dtype == jnp.bfloat16
         assert np.isfinite(np.asarray(g, np.float32)).all()
+
+
+class TestFlashAttentionUnderCheckpoint:
+    """``_flash_fwd`` names its output and log-sum-exp: a checkpoint whose
+    policy keeps the two names holds the T-sized results and the backward
+    pass does not run the T^2 forward kernel again."""
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_a_names_policy_keeps_the_forwards_results(self, rng, causal,
+                                                       kernel_calls):
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 2, 256, 128))
+                               .astype(np.float32)) for _ in range(3))
+
+        def attend(q, k, v):
+            return flash_attention(q * 2.0, k, v, causal=causal) * 3.0
+
+        def grad_of(f):
+            return jax.grad(lambda q, k, v: f(q, k, v).sum(), argnums=(0, 1, 2))
+
+        policy = jax.checkpoint_policies.save_only_these_names(
+            flash_module.SAVED_OUT, flash_module.SAVED_LSE)
+        plain = grad_of(attend)
+        kept = grad_of(jax.checkpoint(attend, policy=policy))
+        bare = grad_of(jax.checkpoint(attend))
+        once = {"flash_attention_fwd": 1, "flash_attention_bwd_dq": 1,
+                "flash_attention_bwd_dkv": 1}
+        assert kernel_calls(plain, q, k, v) == once
+        assert kernel_calls(kept, q, k, v) == once
+        assert kernel_calls(bare, q, k, v) == {**once, "flash_attention_fwd": 2}
+        for a, b in zip(kept(q, k, v), plain(q, k, v)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_one_name_alone_does_not_spare_the_forward(self, rng, kernel_calls):
+        """Both results come from the one call: keep ``out`` without ``lse``
+        (or the reverse) and the backward pass still has to run it."""
+        q = jnp.asarray(rng.normal(size=(1, 1, 128, 128)).astype(np.float32))
+        for name in (flash_module.SAVED_OUT, flash_module.SAVED_LSE):
+            policy = jax.checkpoint_policies.save_only_these_names(name)
+            f = jax.grad(lambda q: jax.checkpoint(  # noqa: B023
+                lambda q: flash_attention(q * 2.0, q, q),
+                policy=policy)(q).sum())  # noqa: B023
+            assert kernel_calls(f, q)["flash_attention_fwd"] == 2
+
+
+class TestFlashBackwardCompilesForTheV5e:
+    """With two buffers for every block the dk/dv kernel at ``bwd_tiles``'
+    (1024, 1024) needed 16.18-16.68 MB of scoped VMEM where XLA keeps none of
+    its operands in VMEM, over the chip's 16 MB, and compiled or not by what
+    XLA placed around it. These shapes are too large for XLA to keep there,
+    so the compile sees the kernel's whole need. Compiled for a described
+    chip; nothing runs."""
+
+    @pytest.fixture(scope="class")
+    def one_chip(self):
+        from jax.experimental import topologies
+        from jax.experimental.compilation_cache import compilation_cache
+        from jax.sharding import SingleDeviceSharding
+
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - whatever keeps the compiler away
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+    @pytest.mark.parametrize("shape", [(2, 16, 8192, 128), (2, 16, 16384, 128),
+                                       (8, 12, 4096, 64)])
+    def test_backward_kernels_fit_scoped_vmem(self, one_chip, shape):
+        B, H, T, D = shape
+        wide = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        row = jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32, sharding=one_chip)
+        bq, bk = flash_module.bwd_tiles(512, 1024, D)
+        assert (bq, bk) == (1024, 1024)
+        text = jax.jit(lambda q, k, v, do, lse, delta: flash_module._flash_backward(
+            q, k, v, do, lse, delta, causal=True, scale=D ** -0.5, block_q=bq,
+            block_k=bk, interpret=False)).lower(
+                wide, wide, wide, wide, row, row).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 class TestFusedLSTMGradients:
