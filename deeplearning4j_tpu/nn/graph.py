@@ -11,27 +11,18 @@ summed. Params/state/opt-state are name-keyed dicts over vertices.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu import faults, guardrails, monitoring
-from deeplearning4j_tpu.common.dtypes import BF16, FLOAT32
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.graph import LayerVertex
-from deeplearning4j_tpu.common.env import env
 from deeplearning4j_tpu.nn.layers.base import checkpoint_layer
-from deeplearning4j_tpu.nn.multilayer import (
-    _check_carry_batch, _tree_cast, _unpack, global_norm_clip,
-)
-from deeplearning4j_tpu.optimize.async_dispatch import (
-    _fetch_scalar, deliver_score, drain_scores, get_window, leading_dim,
-    pad_tail_batch,
-)
+from deeplearning4j_tpu.nn.multilayer import _check_carry_batch, _tree_cast
+from deeplearning4j_tpu.nn.network import Network, _unpack
 from deeplearning4j_tpu.optimize.updaters import NoOp, get_updater
 
 
@@ -42,21 +33,14 @@ def _scope_name(name: str, vertex) -> str:
     return f"{name}.{type(kind).__name__}"
 
 
-class ComputationGraph:
+class ComputationGraph(Network):
     def __init__(self, conf: ComputationGraphConfiguration):
         if not conf.topological_order:
             conf.resolve()
-        self.conf = conf
+        super().__init__(conf)
         self.params: dict = {}
         self.state: dict = {}
         self.opt_state: dict = {}
-        self.step_count = 0
-        self.epoch_count = 0
-        self.score_value = float("nan")
-        self.listeners: list = []
-        self._policy = BF16 if conf.dtype in ("bf16", "bfloat16") else FLOAT32
-        self._rng_key = jax.random.key(conf.seed)
-        self._jit_cache: dict = {}
         self._updaters = {}
         for name, v in conf.vertices.items():
             if isinstance(v, LayerVertex):
@@ -95,13 +79,6 @@ class ComputationGraph:
                 t = self.conf.preprocessors[name].output_type(t)
             ins.append(t)
         return ins
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(self.params))
-
-    def _next_key(self):
-        self._rng_key, sub = jax.random.split(self._rng_key)
-        return sub
 
     @property
     def _output_vertices(self):
@@ -412,64 +389,15 @@ class ComputationGraph:
                 loss = loss + v.layer.regularization(params[name])
         return loss
 
-    def _make_train_step(self, guarded: bool = False,
-                         clip_active: bool = True):
-        updaters = self._updaters
-        max_norm = self.conf.max_grad_norm
-        conf_clipnorm = float(getattr(self.conf.updater, "clipnorm", 0.0)
-                              or 0.0)
-        if guarded:
-            from deeplearning4j_tpu.guardrails import sentinel as _sentinel
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def train_step(params, state, opt_state, step, inputs, labels, key, masks,
-                       labels_masks=None, ctrl=None):
-            def loss_fn(p):
-                cp, ci = self._cast_in(p, inputs)
-                loss, new_state = self._loss(cp, state, ci, labels, key, masks,
-                                             labels_masks=labels_masks)
-                return loss.astype(jnp.float32), new_state
-
-            (loss, new_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            if guarded:
-                # screen the RAW grads (NaN survives any clip scale, so the
-                # clips below cannot launder a non-finite gradient)
-                with jax.named_scope("guard"):
-                    grads, word = _sentinel.screen(grads, loss, ctrl,
-                                                   with_clip=clip_active)
-            with jax.named_scope("clip"):
-                if max_norm > 0:
-                    grads = global_norm_clip(grads, max_norm)
-                if conf_clipnorm > 0:
-                    grads = global_norm_clip(grads, conf_clipnorm)
-            new_params, new_opt = {}, {}
-            for name, p in params.items():
-                g = grads[name]
-                u = updaters[name]
-                # per-vertex updater override: clip only that subtree
-                ucn = float(getattr(u, "clipnorm", 0.0) or 0.0)
-                if ucn > 0 and u is not self.conf.updater:
-                    with jax.named_scope("clip"):
-                        g = global_norm_clip(g, ucn)
-                with jax.named_scope("updater"):
-                    upd, ost = u.update(g, opt_state[name], p, step)
-                    new_params[name] = jax.tree_util.tree_map(
-                        lambda a, d: a - d, p, upd)
-                new_opt[name] = ost
-            # carry forward unchanged state entries
-            for k, v in state.items():
-                new_state.setdefault(k, v)
-            if not guarded:
-                return new_params, new_state, new_opt, loss
-            # tripped step: keep the old params/opt/state ON DEVICE
-            with jax.named_scope("guard"):
-                ok = word[_sentinel.WORD_OK] > 0
-                new_params = _sentinel.tree_select(ok, new_params, params)
-                new_opt = _sentinel.tree_select(ok, new_opt, opt_state)
-                new_state = _sentinel.tree_select(ok, new_state, state)
-            return new_params, new_state, new_opt, loss, word
-
-        return train_step
+    def _step_loss(self, params, state, inputs, labels, key, masks,
+                   labels_masks):
+        cp, ci = self._cast_in(params, inputs)
+        loss, new_state = self._loss(cp, state, ci, labels, key, masks,
+                                     labels_masks=labels_masks)
+        # carry forward unchanged state entries
+        for k, v in state.items():
+            new_state.setdefault(k, v)
+        return loss.astype(jnp.float32), new_state
 
     def _as_label_dict(self, y):
         if isinstance(y, dict):
@@ -538,121 +466,25 @@ class ComputationGraph:
             self._pad_ok = ok
         return ok
 
-    def fit_batch(self, ds) -> float:
-        """One optimization step. Sync mode returns the loss as a float;
-        async mode (optimize/async_dispatch, the default) returns a lazy
-        ScoreHandle — see MultiLayerNetwork.fit_batch."""
-        if getattr(self, "_quantized", False):
-            raise RuntimeError(
-                "this network is an int8 inference view (quantize()); "
-                "train the original f32 network instead")
-        x, y, mask, label_mask = _unpack(ds)
-        plan = faults.active()
-        if plan is not None:
-            # input-path injection (nan_grad/loss_spike/data_corrupt): the
-            # batch is poisoned BEFORE the replay ring sees it, so retries
-            # replay the same poisoned bytes deterministically
-            x, y = faults.poison_batch(plan, x, y, step=self.step_count)
-        if env.pad_tail and not isinstance(y, (list, tuple, dict)):
-            # pad partial epoch tails up to a pow2 bucket (loss-exact via
-            # label-mask zeroing); multi-input x pads per entry, but a
-            # per-output labels LIST/DICT keeps its raw shape (a loss mask
-            # cannot be synthesized for it shape-safely)
-            b = leading_dim(x)
-            max_b = getattr(self, "_fit_max_batch", 0)
-            if b > max_b:
-                self._fit_max_batch = b
-            elif b < max_b and self._tail_padding_ok():
-                x, y, mask, label_mask = pad_tail_batch(
-                    x, y, mask, label_mask, max_b)
-        inputs = self._as_input_dict(x)
-        labels = self._as_label_dict(y)
-        labels_masks = self._labels_masks_for(mask, label_mask)
-        window = get_window(self)
-        mon = monitoring.fit_monitor()
-        guard = guardrails.get_guard(self)
-        if guard is not None:
-            result = guard.step(
-                self, (inputs, labels),
-                (None if mask is None else [jnp.asarray(mask)], labels_masks),
-                window, mon)
-            self.step_count += 1
-            return result
-        fn = self._jit_cache.get("train")
-        if fn is None:
-            fn = self._make_train_step()
-            self._jit_cache["train"] = fn
+    def _step_inputs(self, x, y, mask, label_mask):
+        if not isinstance(y, (list, tuple, dict)):
+            # multi-input x pads per entry, but a per-output labels LIST/DICT
+            # keeps its raw shape (a loss mask cannot be synthesized for it
+            # shape-safely)
+            x, y, mask, label_mask = self._pad_tail(x, y, mask, label_mask)
         # vertices consume masks as a LIST (one shared [B, T] sequence
         # mask threaded to every vertex; LayerVertex reads masks[0]) — a
         # bare array would hit `if masks` truthiness inside the trace
-        args = (self.params, self.state, self.opt_state,
-                jnp.asarray(self.step_count, jnp.int32), inputs, labels,
-                self._next_key(),
-                None if mask is None else [jnp.asarray(mask)], labels_masks)
-        if mon is None:
-            # hot path: monitoring off means NO registry/tracer calls here
-            self.params, self.state, self.opt_state, loss = fn(*args)
-            result = deliver_score(self, loss, window, None)
-        elif window is None:
-            with mon.phase("device_step", step=self.step_count):
-                self.params, self.state, self.opt_state, loss = fn(*args)
-                # the host fetch is the device sync: step time includes it
-                result = self._score_value = _fetch_scalar(loss)
-            with mon.phase("listeners", step=self.step_count):
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.step_count,
-                                       self.epoch_count, result)
-            mon.iteration_done(result)
-        else:
-            with mon.phase("dispatch", step=self.step_count):
-                self.params, self.state, self.opt_state, loss = fn(*args)
-            try:
-                result = window.submit(loss)  # drains oldest once over capacity
-            except BaseException:
-                # drain error for an older step: this step is queued, its id
-                # is consumed either way (see deliver_score)
-                self.step_count += 1
-                raise
-        self.step_count += 1
-        return result
+        return ((self._as_input_dict(x), self._as_label_dict(y)),
+                (None if mask is None else [jnp.asarray(mask)],
+                 self._labels_masks_for(mask, label_mask)))
 
-    def fit(self, data, labels=None, epochs: int = 1):
-        if labels is not None:
-            try:
-                for _ in range(epochs):
-                    self.fit_batch((data, labels))
-            except BaseException:
-                drain_scores(self, suppress=True)
-                raise
-            drain_scores(self)
-            for lst in self.listeners:
-                lst.on_fit_end(self)
-            return self
-        for _ in range(epochs):
-            for lst in self.listeners:
-                lst.on_epoch_start(self, self.epoch_count)
-            # data-wait spans time the iterator pull per batch (host input
-            # pipeline vs device step split); None = monitoring off
-            mon = monitoring.fit_monitor()
-            try:
-                for ds in (data if mon is None
-                           else mon.wrap_batches(data, self)):
-                    self.fit_batch(ds)
-            except BaseException:
-                # best-effort drain; the batch-loop exception wins
-                drain_scores(self, suppress=True)
-                raise
-            # in-flight scores (and any async step failure) land BEFORE the
-            # epoch-end listeners observe the epoch
-            drain_scores(self)
-            if hasattr(data, "reset"):
-                data.reset()
-            for lst in self.listeners:
-                lst.on_epoch_end(self, self.epoch_count)
-            self.epoch_count += 1
-        for lst in self.listeners:
-            lst.on_fit_end(self)
-        return self
+    def _loop_layers(self):
+        return [v.layer for v in self.conf.vertices.values()
+                if isinstance(v, LayerVertex)]
+
+    def _exit_state(self):
+        return self.state.get(self.conf.network_outputs[0])
 
     # ------------------------------------------------------------------ eval
     def evaluate(self, iterator, evaluation=None) -> Evaluation:
@@ -672,17 +504,6 @@ class ComputationGraph:
         if hasattr(iterator, "reset"):
             iterator.reset()
         return ev
-
-    @property
-    def score_value(self) -> float:
-        """Latest training score; under async dispatch reading it drains
-        the in-flight window first (see MultiLayerNetwork.score_value)."""
-        drain_scores(self)
-        return self._score_value
-
-    @score_value.setter
-    def score_value(self, value: float) -> None:
-        self._score_value = value
 
     def score(self, ds=None) -> float:
         """Loss on a batch without updating (ComputationGraph.score(DataSet));
@@ -709,26 +530,8 @@ class ComputationGraph:
                         None if mask is None else [jnp.asarray(mask)],
                         labels_masks))
 
-    # ------------------------------------------------------------- quantize
-    def quantize(self, dtype: str = "int8") -> "ComputationGraph":
-        """Weight-only int8 inference view of this graph (the original
-        stays trainable). See deeplearning4j_tpu.quantize."""
-        from deeplearning4j_tpu.quantize import quantize_network
-
-        return quantize_network(self, dtype)
-
-    # ----------------------------------------------------------------- serde
-    def save(self, path: str, save_updater: bool = True):
-        from deeplearning4j_tpu.util.serialization import write_model
-
-        write_model(self, path, save_updater=save_updater)
-
     @staticmethod
     def load(path: str, load_updater: bool = True) -> "ComputationGraph":
         from deeplearning4j_tpu.util.serialization import restore_computation_graph
 
         return restore_computation_graph(path, load_updater=load_updater)
-
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self

@@ -16,22 +16,22 @@ results host-side, exactly like the reference's listener bus.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu import faults, guardrails, monitoring
-from deeplearning4j_tpu.common.dtypes import BF16, FLOAT32
-from deeplearning4j_tpu.common.env import env
+from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.base import checkpoint_layer, scope_name as _scope_name
 from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
+# _unpack and global_norm_clip are imported from here by parallel/ and the
+# benchmark's tests
+from deeplearning4j_tpu.nn.network import Network, _unpack, global_norm_clip  # noqa: F401
 from deeplearning4j_tpu.optimize.async_dispatch import (
-    _fetch_scalar, deliver_score, drain_scores, get_window, leading_dim,
-    pad_tail_batch, supports_tail_padding,
+    deliver_score, get_window, supports_tail_padding,
 )
 from deeplearning4j_tpu.optimize.updaters import NoOp, get_updater
 
@@ -75,37 +75,22 @@ def merge_carry_rows(carries, sub, rows):
     return jax.tree_util.tree_map(lambda a, r: a.at[idx].set(r), carries, sub)
 
 
-def global_norm_clip(grads, max_norm):
-    """DL4J GradientNormalization.ClipL2PerParamType analog (global L2 form)."""
-    leaves = jax.tree_util.tree_leaves(grads)
-    norm = jnp.sqrt(sum((g.astype(jnp.float32) ** 2).sum() for g in leaves))
-    scale = jnp.minimum(1.0, max_norm / (norm + 1e-12))
-    return jax.tree_util.tree_map(lambda g: g * scale, grads)
-
-
-class MultiLayerNetwork:
+class MultiLayerNetwork(Network):
     """Sequential network over a MultiLayerConfiguration."""
 
     def __init__(self, conf: MultiLayerConfiguration):
         if not conf.layer_input_types:
             conf.resolve()
-        self.conf = conf
+        super().__init__(conf)
         self.layers = conf.layers
         self.params: list[dict] = []
         self.state: list[dict] = []
         self.opt_state: list[dict] = []
-        self.step_count = 0
-        self.epoch_count = 0
-        self.score_value = float("nan")
-        self.listeners: list = []
         # frozen wins over any per-layer updater override (TransferLearning)
         self._updaters = [NoOp() if not l.trainable
                           else (get_updater(l.updater) if l.updater is not None
                                 else conf.updater)
                           for l in self.layers]
-        self._policy = BF16 if conf.dtype in ("bf16", "bfloat16") else FLOAT32
-        self._rng_key = jax.random.key(conf.seed)
-        self._jit_cache: dict = {}
 
     # ------------------------------------------------------------------ init
     def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
@@ -121,9 +106,6 @@ class MultiLayerNetwork:
         self.opt_state = [u.init_state(p) for u, p in zip(self._updaters, self.params)]
         return self
 
-    def num_params(self) -> int:
-        return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(self.params))
-
     def params_table(self) -> dict:
         """Flat {"0_W": array, ...} naming (MultiLayerNetwork.paramTable)."""
         out = {}
@@ -135,10 +117,6 @@ class MultiLayerNetwork:
                 else:
                     out[f"{i}_{k}"] = v
         return out
-
-    def _next_key(self):
-        self._rng_key, sub = jax.random.split(self._rng_key)
-        return sub
 
     # --------------------------------------------------------------- forward
     def _forward(self, params, state, x, train, rng, mask):
@@ -276,66 +254,13 @@ class MultiLayerNetwork:
             reg = sum(l.regularization(p) for l, p in zip(self.layers, params))
         return loss + reg, new_states, new_carries
 
-    def _apply_updaters(self, grads, params, opt_state, step):
-        with jax.named_scope("clip"):
-            if self.conf.max_grad_norm > 0:
-                grads = global_norm_clip(grads, self.conf.max_grad_norm)
-            cn = float(getattr(self.conf.updater, "clipnorm", 0.0) or 0.0)
-            if cn > 0:
-                grads = global_norm_clip(grads, cn)
-        new_params, new_opt = [], []
-        for i, u in enumerate(self._updaters):
-            g = grads[i]
-            # per-layer updater override: clip only that layer's subtree
-            ucn = float(getattr(u, "clipnorm", 0.0) or 0.0)
-            if ucn > 0 and u is not self.conf.updater:
-                with jax.named_scope("clip"):
-                    g = global_norm_clip(g, ucn)
-            with jax.named_scope("updater"):
-                upd, ost = u.update(g, opt_state[i], params[i], step)
-                new_params.append(jax.tree_util.tree_map(
-                    lambda p, d: p - d, params[i], upd))
-            new_opt.append(ost)
-        return new_params, new_opt
-
-    def _make_train_step(self, guarded: bool = False,
-                         clip_active: bool = True):
-        if guarded:
-            from deeplearning4j_tpu.guardrails import sentinel as _sentinel
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def train_step(params, state, opt_state, step, x, y, key, mask,
-                       label_mask=None, ctrl=None):
-            def loss_fn(p):
-                cp = _tree_cast(p, self._policy.compute_dtype)
-                cx = x if not jnp.issubdtype(x.dtype, jnp.floating) else x.astype(
-                    self._policy.compute_dtype)
-                loss, new_states, _ = self._loss_terms(
-                    cp, state, cx, y, key, mask, label_mask=label_mask)
-                return loss.astype(jnp.float32), new_states
-
-            (loss, new_states), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            if not guarded:
-                new_params, new_opt = self._apply_updaters(grads, params,
-                                                           opt_state, step)
-                return new_params, new_states, new_opt, loss
-            # screen the RAW grads (NaN * clip_scale is still NaN, so the
-            # clip below cannot launder a non-finite gradient past the word)
-            with jax.named_scope("guard"):
-                grads, word = _sentinel.screen(grads, loss, ctrl,
-                                               with_clip=clip_active)
-            new_params, new_opt = self._apply_updaters(grads, params,
-                                                       opt_state, step)
-            # a tripped step keeps the old params/opt/state ON DEVICE: the
-            # bad update never materializes host-side or in checkpoints
-            with jax.named_scope("guard"):
-                ok = word[_sentinel.WORD_OK] > 0
-                new_params = _sentinel.tree_select(ok, new_params, params)
-                new_opt = _sentinel.tree_select(ok, new_opt, opt_state)
-                new_states = _sentinel.tree_select(ok, new_states, state)
-            return new_params, new_states, new_opt, loss, word
-
-        return train_step
+    def _step_loss(self, params, state, x, y, key, mask, label_mask):
+        cp = _tree_cast(params, self._policy.compute_dtype)
+        cx = x if not jnp.issubdtype(x.dtype, jnp.floating) else x.astype(
+            self._policy.compute_dtype)
+        loss, new_states, _ = self._loss_terms(
+            cp, state, cx, y, key, mask, label_mask=label_mask)
+        return loss.astype(jnp.float32), new_states
 
     # ------------------------------------------------------------- tBPTT
     def _forward_carry(self, params, state, x, carries, train, rng, mask):
@@ -505,129 +430,24 @@ class MultiLayerNetwork:
         self._rnn_carries = merge_carry_rows(carries, sub, rows)
         return self._rnn_carries
 
-    def fit_batch(self, ds) -> float:
-        """One optimization step on a DataSet/(features, labels) pair.
-
-        Sync mode (``DL4J_TPU_ASYNC_STEPS=0`` or an eager-score listener)
-        returns the step's loss as a float — the host blocks on the device.
-        Async mode (the default) returns a lazy ScoreHandle and keeps up to
-        ``DL4J_TPU_ASYNC_STEPS`` steps in flight; any numeric use of the
-        handle (or reading ``score()``) drains to a float."""
-        if getattr(self, "_quantized", False):
-            raise RuntimeError(
-                "this network is an int8 inference view (quantize()); "
-                "train the original f32 network instead")
-        x, y, mask, label_mask = _unpack(ds)
+    def _fit_unpacked(self, x, y, mask, label_mask):
         label_mask = _single_mask(label_mask)
-        plan = faults.active()
-        if plan is not None:
-            # input-path injection (nan_grad/loss_spike/data_corrupt): the
-            # batch is poisoned BEFORE the replay ring sees it, so retries
-            # replay the same poisoned bytes deterministically
-            x, y = faults.poison_batch(plan, x, y, step=self.step_count)
         if (self.conf.tbptt_fwd_length > 0 and np.ndim(x) == 3
                 and np.shape(x)[1] > self.conf.tbptt_fwd_length):
             return self._fit_tbptt(x, y, mask, label_mask)
-        if env.pad_tail:
-            # partial epoch tails pad up to a pow2 bucket (loss-exact via
-            # label-mask zeroing) instead of compiling one program per shape
-            b = leading_dim(x)
-            max_b = getattr(self, "_fit_max_batch", 0)
-            if b > max_b:
-                self._fit_max_batch = b
-            elif b < max_b and self._tail_padding_ok():
-                x, y, mask, label_mask = pad_tail_batch(
-                    x, y, mask, label_mask, max_b)
-        window = get_window(self)
-        mon = monitoring.fit_monitor()
-        guard = guardrails.get_guard(self)
-        if guard is not None:
-            result = guard.step(
-                self, (jnp.asarray(x), jnp.asarray(y)),
-                (None if mask is None else jnp.asarray(mask),
-                 None if label_mask is None else jnp.asarray(label_mask)),
-                window, mon)
-            self.step_count += 1
-            return result
-        step_fn = self._jit_cache.get("train")
-        if step_fn is None:
-            step_fn = self._make_train_step()
-            self._jit_cache["train"] = step_fn
-        key = self._next_key()
-        args = (self.params, self.state, self.opt_state,
-                jnp.asarray(self.step_count, jnp.int32), jnp.asarray(x),
-                jnp.asarray(y), key,
-                None if mask is None else jnp.asarray(mask),
-                None if label_mask is None else jnp.asarray(label_mask))
-        if mon is None:
-            # hot path: monitoring off means NO registry/tracer calls here
-            self.params, self.state, self.opt_state, loss = step_fn(*args)
-            result = deliver_score(self, loss, window, None)
-        elif window is None:
-            with mon.phase("device_step", step=self.step_count):
-                self.params, self.state, self.opt_state, loss = step_fn(*args)
-                mon.hold_exit_share(self.state[-1])
-                # the host fetch is the device sync: step time includes it
-                result = self._score_value = _fetch_scalar(loss)
-            with mon.phase("listeners", step=self.step_count):
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.step_count,
-                                       self.epoch_count, result)
-            mon.iteration_done(result)
-        else:
-            with mon.phase("dispatch", step=self.step_count):
-                self.params, self.state, self.opt_state, loss = step_fn(*args)
-                mon.hold_exit_share(self.state[-1])
-            try:
-                result = window.submit(loss)  # drains oldest once over capacity
-            except BaseException:
-                # drain error for an older step: this step is queued, its id
-                # is consumed either way (see deliver_score)
-                self.step_count += 1
-                raise
-        self.step_count += 1
-        return result
+        return super()._fit_unpacked(x, y, mask, label_mask)
 
-    def fit(self, data, labels=None, epochs: int = 1):
-        """fit(iterator) or fit(features, labels) (MultiLayerNetwork.fit overloads)."""
-        if labels is not None:
-            try:
-                for _ in range(epochs):
-                    self.fit_batch((data, labels))
-            except BaseException:
-                drain_scores(self, suppress=True)
-                raise
-            drain_scores(self)
-            for lst in self.listeners:
-                lst.on_fit_end(self)
-            return self
-        for _ in range(epochs):
-            for lst in self.listeners:
-                lst.on_epoch_start(self, self.epoch_count)
-            # data-wait spans time the iterator pull per batch (host input
-            # pipeline vs device step split); None = monitoring off
-            mon = monitoring.fit_monitor()
-            if mon is not None:
-                mon.describe_loops(self.layers, self.conf.remat)
-            try:
-                for ds in (data if mon is None
-                           else mon.wrap_batches(data, self)):
-                    self.fit_batch(ds)
-            except BaseException:
-                # best-effort drain; the batch-loop exception wins
-                drain_scores(self, suppress=True)
-                raise
-            # in-flight scores (and any async step failure) land BEFORE the
-            # epoch-end listeners observe the epoch
-            drain_scores(self)
-            if hasattr(data, "reset"):
-                data.reset()
-            for lst in self.listeners:
-                lst.on_epoch_end(self, self.epoch_count)
-            self.epoch_count += 1
-        for lst in self.listeners:
-            lst.on_fit_end(self)
-        return self
+    def _step_inputs(self, x, y, mask, label_mask):
+        x, y, mask, label_mask = self._pad_tail(x, y, mask, label_mask)
+        return ((jnp.asarray(x), jnp.asarray(y)),
+                (None if mask is None else jnp.asarray(mask),
+                 None if label_mask is None else jnp.asarray(label_mask)))
+
+    def _loop_layers(self):
+        return self.layers
+
+    def _exit_state(self):
+        return self.state[-1]
 
     # -------------------------------------------------------------- pretrain
     def pretrain(self, data, epochs: int = 1):
@@ -734,19 +554,6 @@ class MultiLayerNetwork:
         return loss_fn, (self.params, self.state)
 
     # ----------------------------------------------------------------- score
-    @property
-    def score_value(self) -> float:
-        """Latest training score. Under async dispatch
-        (optimize/async_dispatch) reading it drains the in-flight window
-        first — the value is always that of the newest DISPATCHED step,
-        exactly as in sync mode."""
-        drain_scores(self)
-        return self._score_value
-
-    @score_value.setter
-    def score_value(self, value: float) -> None:
-        self._score_value = value
-
     def _tail_padding_ok(self) -> bool:
         ok = getattr(self, "_pad_ok", None)
         if ok is None:
@@ -789,29 +596,11 @@ class MultiLayerNetwork:
             iterator.reset()
         return ev
 
-    # ------------------------------------------------------------- quantize
-    def quantize(self, dtype: str = "int8") -> "MultiLayerNetwork":
-        """Weight-only int8 inference view of this network (the original
-        stays trainable). See deeplearning4j_tpu.quantize."""
-        from deeplearning4j_tpu.quantize import quantize_network
-
-        return quantize_network(self, dtype)
-
-    # ----------------------------------------------------------------- serde
-    def save(self, path: str, save_updater: bool = True):
-        from deeplearning4j_tpu.util.serialization import write_model
-
-        write_model(self, path, save_updater=save_updater)
-
     @staticmethod
     def load(path: str, load_updater: bool = True) -> "MultiLayerNetwork":
         from deeplearning4j_tpu.util.serialization import restore_multi_layer_network
 
         return restore_multi_layer_network(path, load_updater=load_updater)
-
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
 
 
 def _single_mask(lm):
@@ -824,37 +613,3 @@ def _single_mask(lm):
             "MultiDataSet shape; MultiLayerNetwork takes a single labels "
             "mask array")
     return lm
-
-
-def _unpack(ds):
-    """Accept DataSet/MultiDataSet-like (has .features/.labels), tuple,
-    or dict. Returns (features, labels, mask, label_mask).
-
-    ``mask`` is the FORWARD mask (attention/RNN padding; the features
-    mask); ``label_mask`` is non-None only when the DataSet carries a
-    labels mask DISTINCT from its features mask — the masked-LM shape
-    (r4), where the model must attend to all real tokens but the loss
-    covers only the selected positions (DL4J's separate featuresMask /
-    labelsMask semantics). A single mask keeps its r1-r3 behavior: it
-    plays both roles."""
-    if hasattr(ds, "features"):
-        fm = getattr(ds, "features_mask", None)
-        lm = getattr(ds, "labels_mask", None)
-        if fm is None:
-            # a single labels-mask array keeps its r1-r3 dual role (shared
-            # forward + loss mask); a per-output list/dict (r5, MultiDataSet)
-            # can only ever be a loss mask
-            if isinstance(lm, (list, tuple, dict)):
-                return ds.features, ds.labels, None, lm
-            return ds.features, ds.labels, lm, None
-        return ds.features, ds.labels, fm, lm
-    if isinstance(ds, dict):
-        return (ds["features"], ds["labels"], ds.get("mask"),
-                ds.get("labels_mask"))
-    if len(ds) == 4:
-        return ds
-    if len(ds) == 3:
-        x, y, m = ds
-        return x, y, m, None
-    x, y = ds
-    return x, y, None, None

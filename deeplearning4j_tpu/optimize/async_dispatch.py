@@ -12,8 +12,11 @@ batch. This is the dispatch-gap problem PyGraph (arxiv 2503.19779) attacks
 with CUDA Graphs — keep the device queue full, never block the host on a
 scalar you don't need yet.
 
-Three pieces:
+Four pieces:
 
+- **run_step / deliver_score** — the one place a dispatched step's score
+  reaches the host and the listeners (sync or async, monitored or not);
+  ``nn/network.py``'s ``fit_batch`` hands it the jitted call.
 - **ScoreHandle / AsyncScoreWindow** — ``fit_batch`` keeps the loss on
   device and returns a lazy handle; a bounded window of in-flight steps
   (``DL4J_TPU_ASYNC_STEPS``, default 2, ``=0`` restores sync behavior)
@@ -263,17 +266,7 @@ class AsyncScoreWindow:
                                            sentinel=getattr(e, "word", None))
             raise handle._error
         handle._value = value
-        self.model._score_value = value
-        if mon is None:
-            for lst in listeners:
-                lst.iteration_done(self.model, handle.step, handle.epoch,
-                                   value)
-        else:
-            with mon.phase("listeners", step=handle.step):
-                for lst in listeners:
-                    lst.iteration_done(self.model, handle.step, handle.epoch,
-                                       value)
-            mon.iteration_done(value)
+        _deliver(self.model, listeners, handle.step, handle.epoch, value, mon)
 
     def drain(self) -> None:
         """Retire every in-flight step (epoch end / fit end / score read)."""
@@ -322,6 +315,22 @@ def drain_scores(model, suppress: bool = False) -> None:
         pass
 
 
+def _deliver(model, listeners, step: int, epoch: int, value: float,
+             mon) -> float:
+    """A fetched score reaches the model and ``listeners`` (timed when
+    ``mon`` is active) under the (step, epoch) it was dispatched with."""
+    model._score_value = value
+    if mon is None:
+        for lst in listeners:
+            lst.iteration_done(model, step, epoch, value)
+    else:
+        with mon.phase("listeners", step=step):
+            for lst in listeners:
+                lst.iteration_done(model, step, epoch, value)
+        mon.iteration_done(value)
+    return value
+
+
 def deliver_score(model, loss, window: Optional[AsyncScoreWindow],
                   mon) -> "float | ScoreHandle":
     """Shared sync-path score delivery + async submit. Sync: fetch, set
@@ -329,7 +338,7 @@ def deliver_score(model, loss, window: Optional[AsyncScoreWindow],
     submit to the window. Caller increments ``step_count`` afterwards."""
     if window is not None:
         try:
-            return window.submit(loss)
+            return window.submit(loss)  # drains oldest once over capacity
         except BaseException:
             # the handle is queued before the window drains, so an error
             # surfacing here belongs to an OLDER step — the current step is
@@ -337,19 +346,29 @@ def deliver_score(model, loss, window: Optional[AsyncScoreWindow],
             # next fit_batch would re-dispatch under the same step number
             model.step_count += 1
             raise
-    value = _fetch_scalar(loss)
-    model._score_value = value
+    return _deliver(model, model.listeners, model.step_count,
+                    model.epoch_count, _fetch_scalar(loss), mon)
+
+
+def run_step(model, call, window: Optional[AsyncScoreWindow],
+             mon) -> "float | ScoreHandle":
+    """One train step from dispatch to delivery. ``call()`` enqueues the
+    jitted step, keeps its new params/state on the model and returns the
+    on-device loss; the score is then delivered as ``deliver_score`` does.
+    With ``mon`` active the call is the ``dispatch`` phase (async) or, with
+    the fetch, the ``device_step`` phase (sync)."""
     if mon is None:
-        for lst in model.listeners:
-            lst.iteration_done(model, model.step_count, model.epoch_count,
-                               value)
-    else:
-        with mon.phase("listeners", step=model.step_count):
-            for lst in model.listeners:
-                lst.iteration_done(model, model.step_count,
-                                   model.epoch_count, value)
-        mon.iteration_done(value)
-    return value
+        # hot path: monitoring off means NO registry/tracer calls here
+        return deliver_score(model, call(), window, None)
+    if window is not None:
+        with mon.phase("dispatch", step=model.step_count):
+            loss = call()
+        return deliver_score(model, loss, window, mon)
+    with mon.phase("device_step", step=model.step_count):
+        # the host fetch is the device sync: step time includes it
+        value = _fetch_scalar(call())
+    return _deliver(model, model.listeners, model.step_count,
+                    model.epoch_count, value, mon)
 
 
 # ---- tail-batch padding --------------------------------------------------

@@ -21,7 +21,6 @@ from typing import Optional
 import jax
 import numpy as np
 
-from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.parallel.mesh import DeviceMesh
 
 
@@ -92,7 +91,6 @@ class ParallelWrapper:
 
     def fit(self, data, epochs: int = 1):
         from deeplearning4j_tpu.datasets.iterators import AsyncPrefetchIterator
-        from deeplearning4j_tpu.optimize.async_dispatch import drain_scores
 
         if self.prefetch_buffer and hasattr(data, "reset"):
             # single-process: the prefetch thread shards each batch onto the
@@ -104,22 +102,9 @@ class ParallelWrapper:
                        if jax.process_count() == 1 else None)
             data = AsyncPrefetchIterator(data, queue_size=self.prefetch_buffer,
                                          device_put=False, sharder=sharder)
-        for _ in range(epochs):
-            # the same phases as the networks' own fit: data-wait spans time
-            # the iterator pull per batch; None = monitoring off
-            mon = monitoring.fit_monitor()
-            try:
-                for ds in (data if mon is None
-                           else mon.wrap_batches(data, self.model)):
-                    self.fit_batch(ds)
-            except BaseException:
-                drain_scores(self.model, suppress=True)
-                raise
-            drain_scores(self.model)
-            if hasattr(data, "reset"):
-                data.reset()
-            self.model.epoch_count += 1
-        return self.model
+        # the network's own epoch loop (phases, drain, listener hooks), with
+        # the sharding fit_batch above as its step
+        return self.model._fit_epochs(data, epochs, self.fit_batch)
 
     def average_params(self):
         """No-op kept for API parity: synchronous SPMD keeps replicas identical

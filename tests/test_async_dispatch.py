@@ -70,6 +70,11 @@ def _graph(seed=3):
     return ComputationGraph(conf).init()
 
 
+#: the same test on either core (the spine of both is nn/network.py's)
+either_core = pytest.mark.parametrize("core", [_model, _graph],
+                                      ids=["multilayer", "graph"])
+
+
 def _data(n=16, rng_seed=0, n_in=4):
     rng = np.random.default_rng(rng_seed)
     x = rng.normal(size=(n, n_in)).astype(np.float32)
@@ -121,9 +126,10 @@ class TestScoreHandle:
         assert float(handles[4]) == net._score_value
         assert len(window) == 0
 
-    def test_sync_mode_returns_floats(self, monkeypatch):
+    @either_core
+    def test_sync_mode_returns_floats(self, monkeypatch, core):
         _async(monkeypatch, 0)
-        net = _model()
+        net = core()
         x, y = _data()
         out = net.fit_batch((x, y))
         assert isinstance(out, float)
@@ -250,13 +256,15 @@ class TestDrainErrors:
             h1.value()
         assert np.isfinite(float(h2))
 
-    def test_drain_error_does_not_poison_later_deliveries(self, monkeypatch):
+    @either_core
+    def test_drain_error_does_not_poison_later_deliveries(self, monkeypatch,
+                                                          core):
         """Regression: the step being SUBMITTED when an older step's drain
         error surfaces is already queued — its id must be consumed, or the
         next fit_batch re-dispatches under the same step number and
         listeners see a duplicate iteration. After one failed step, every
         other iteration fires its listener exactly once, in order."""
-        net, lst = _model(), CollectScoresListener()
+        net, lst = core(), CollectScoresListener()
         net.set_listeners(lst)
         x, y = _data()
         real = async_dispatch._fetch_scalar
@@ -281,7 +289,8 @@ class TestDrainErrors:
         assert net.step_count == 8
         assert [i for i, _ in lst.scores] == [i for i in range(8) if i != 1]
 
-    def test_fit_drains_at_epoch_end_before_epoch_listeners(self):
+    @either_core
+    def test_fit_drains_at_epoch_end_before_epoch_listeners(self, core):
         events = []
 
         class Recorder(TrainingListener):
@@ -291,7 +300,7 @@ class TestDrainErrors:
             def on_epoch_end(self, model, epoch):
                 events.append(("epoch_end", epoch))
 
-        net = _model()
+        net = core()
         net.set_listeners(Recorder())
         x, y = _data(24)
         net.fit(ArrayDataSetIterator(x, y, batch_size=8), epochs=2)
@@ -678,8 +687,9 @@ class TestCompileCache:
 
 # ------------------------------------------------------------ score reads
 class TestScoreSemantics:
-    def test_score_value_read_drains(self):
-        net = _model()
+    @either_core
+    def test_score_value_read_drains(self, core):
+        net = core()
         x, y = _data()
         net.fit_batch((x, y))
         net.fit_batch((x, y))
@@ -701,3 +711,36 @@ class TestScoreSemantics:
         _async(monkeypatch, 1)
         net.fit_batch((x, y))           # resized window drains down to 1
         assert len(net._score_window) == 1
+
+
+# ------------------------------------------------------------- one spine
+class TestOneSpine:
+    @either_core
+    def test_the_step_the_dispatch_and_the_loop_are_networks(self, core,
+                                                             monkeypatch):
+        """Neither core may grow a ``fit``, ``fit_batch``, train step or
+        ``score_value`` of its own again; and what a monitored step leaves
+        behind is the same whichever core ran it."""
+        from deeplearning4j_tpu.nn.network import Network
+
+        net = core()
+        for name in ("fit", "fit_batch", "_make_train_step", "score_value"):
+            assert getattr(type(net), name) is getattr(Network, name), name
+        x, y = _data(8)
+        float(net.fit_batch((x, y)))     # compiled and drained before the spans
+        monitoring.reset()
+        monitoring.enable()
+        try:
+            names = {}
+            for mode, steps in (("async", 2), ("sync", 0)):
+                _async(monkeypatch, steps)
+                ring = monitoring.start_tracing()
+                net.fit(ArrayDataSetIterator(x, y, batch_size=8))
+                names[mode] = sorted(s.name for s in ring.spans())
+        finally:
+            monitoring.reset()
+        # one batch: its pull and the pull that ends the epoch
+        assert names["async"] == ["fit.data_wait", "fit.data_wait",
+                                  "fit.dispatch", "fit.drain", "fit.listeners"]
+        assert names["sync"] == ["fit.data_wait", "fit.data_wait",
+                                 "fit.device_step", "fit.listeners"]

@@ -33,6 +33,31 @@ def _model(seed=9):
     return MultiLayerNetwork(conf).init()
 
 
+def _graph(seed=9):
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    conf = (
+        NeuralNetConfiguration.builder()
+        .seed(seed)
+        .updater(Sgd(lr=0.1))
+        .graph_builder()
+        .add_inputs("in")
+        .set_input_types(**{"in": InputType.feed_forward(8)})
+        .add_layer("d", DenseLayer(n_out=16, activation="relu"), "in")
+        .add_layer("o", OutputLayer(n_out=4, activation="softmax", loss="mcxent"), "d")
+        .set_outputs("o")
+        .build()
+    )
+    return ComputationGraph(conf).init()
+
+
+def _fit_through(entry, model, data, epochs):
+    """``fit`` by the entry point a user calls: the network's own, or the wrapper's."""
+    if entry == "wrapper":
+        return ParallelWrapper(model, DeviceMesh(data=8)).fit(data, epochs=epochs)
+    return model.fit(data, epochs=epochs)
+
+
 class TestDeviceMesh:
     def test_eight_devices(self):
         assert len(jax.devices()) == 8
@@ -63,6 +88,64 @@ class TestDataParallel:
             np.asarray(single.params[0]["W"]), np.asarray(dp_model.params[0]["W"]),
             rtol=2e-4, atol=1e-6,
         )
+
+    def test_wrapper_fit_calls_the_listener_hooks_the_bare_network_calls(self, rng):
+        """``ParallelWrapper.fit`` drives the network's own epoch loop: epoch
+        start and end once an epoch, fit end once, in the bare network's order."""
+        from deeplearning4j_tpu.datasets.iterators import ArrayDataSetIterator
+        from deeplearning4j_tpu.optimize.listeners import TrainingListener
+
+        class Recorder(TrainingListener):
+            def __init__(self):
+                self.events = []
+
+            def iteration_done(self, model, iteration, epoch, score):
+                self.events.append(("iter", iteration, epoch))
+
+            def on_epoch_start(self, model, epoch):
+                self.events.append(("epoch_start", epoch))
+
+            def on_epoch_end(self, model, epoch):
+                self.events.append(("epoch_end", epoch))
+
+            def on_fit_end(self, model):
+                self.events.append(("fit_end", model.step_count))
+
+        x = rng.normal(size=(32, 8)).astype(np.float32)
+        y = np.eye(4, dtype=np.float32)[rng.integers(0, 4, 32)]
+        seen = {}
+        for entry in ("bare", "wrapper"):
+            model, rec = _model(), Recorder()
+            model.set_listeners(rec)
+            out = _fit_through(entry, model, ArrayDataSetIterator(x, y, batch_size=16), 2)
+            assert out is model and model.epoch_count == 2
+            seen[entry] = rec.events
+        assert seen["wrapper"] == seen["bare"] == [
+            ("epoch_start", 0), ("iter", 0, 0), ("iter", 1, 0), ("epoch_end", 0),
+            ("epoch_start", 1), ("iter", 2, 1), ("iter", 3, 1), ("epoch_end", 1),
+            ("fit_end", 4)]
+
+    @pytest.mark.parametrize("entry,build", [("bare", _model), ("bare", _graph),
+                                             ("wrapper", _model)],
+                             ids=["multilayer", "graph", "wrapper"])
+    def test_the_final_step_is_on_disk_when_fit_returns(self, tmp_path, rng, entry, build):
+        """``AsyncCheckpointListener`` saves a run's last step in ``on_fit_end``:
+        whichever entry point ran ``fit``, that step can be restored."""
+        from deeplearning4j_tpu.datasets.iterators import ArrayDataSetIterator
+        from deeplearning4j_tpu.util.checkpoints import AsyncCheckpointListener
+
+        x = rng.normal(size=(48, 8)).astype(np.float32)
+        y = np.eye(4, dtype=np.float32)[rng.integers(0, 4, 48)]
+        model = build()
+        lst = AsyncCheckpointListener(tmp_path / "ck", save_every_n_iterations=2)
+        model.set_listeners(lst)
+        try:
+            _fit_through(entry, model, ArrayDataSetIterator(x, y, batch_size=16), 1)
+            # three steps: the cadence saved step 2, only fit's end saves step 3
+            assert model.step_count == 3
+            assert lst.checkpointer.all_steps() == [2, 3]
+        finally:
+            lst.checkpointer.close()
 
     @pytest.mark.slow  # ~110s: spawned dryrun process recompiles cold
     def test_dryrun_multichip(self):

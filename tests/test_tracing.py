@@ -716,10 +716,11 @@ class TestFitPathSpans:
         assert reg.get("dl4j_prefetch_stage_seconds").count == 3
         assert reg.get("dl4j_train_data_wait_seconds").count == 4
 
-    def test_sync_mode_records_device_step(self, monkeypatch):
+    @pytest.mark.parametrize("entry", ["mln", "cg"])
+    def test_sync_mode_records_device_step(self, monkeypatch, entry):
         monkeypatch.setattr(env, "async_steps", 0)
         monitoring.enable()
-        model = _mln()
+        model = _cg() if entry == "cg" else _mln()
         model.fit(*_xy(8))
         model.fit(*_xy(8))
         names = [(s.name, s.args.get("step")) for s in monitoring.spans()
