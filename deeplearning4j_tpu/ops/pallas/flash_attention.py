@@ -11,9 +11,20 @@ Forward grid: (B*H, Tq/bq, Tk/bk) with the k-axis innermost; m/l/acc scratch
 persists across the k iterations of one q-block (TPU grids execute the
 minor-most dimension sequentially). The forward also emits the per-row
 logsumexp, which makes the backward pass O(T*D) too: instead of
-re-materializing softmax(QK^T), the dq kernel (q-blocks outer) and the dk/dv
-kernel (k-blocks outer) recompute only one [bq, bk] probability tile at a
-time as exp(s - lse).
+re-materializing softmax(QK^T), the backward recomputes one [bq, bk]
+probability tile at a time as exp(s - lse).
+
+Backward: one kernel, ``flash_attention_bwd``, on the grid (B*H, Tk/bk,
+Tq/bq) with the q-axis innermost. Every visible tile is worked once — p, dv
++= p^T do, dp = do v^T, ds = p (dp - delta), dk += scale ds^T q, dq[rows of
+the q-block] += scale ds k: the 5 products the gradients need. dk / dv
+accumulate in scratch across the q-blocks of one k-block; a head's whole dq
+[Tq, D] float32 is an output block that stays in VMEM across the head's whole
+grid. Where that dq does not fit beside the tiles (``bwd_tiles`` decides from
+the shapes: from about T = 24,576 at head 128), two calls do the same sums
+in the same order, ``flash_attention_bwd_dq`` (q-blocks outer) and
+``flash_attention_bwd_dkv`` (k-blocks outer), each working every tile: 7
+products for the 5.
 
 Block-level primitives ``flash_block_fwd`` / ``flash_block_bwd`` are exposed
 for ring attention (parallel/sequence.py): the ring merges per-step (o, lse)
@@ -24,6 +35,7 @@ parallel long-context training inherits the same sub-quadratic memory.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -248,12 +260,15 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                       causal, scale, block_q, block_k, seq_q, seq_k,
-                      has_kmask):
-    if has_kmask:
-        km_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
-    else:
-        km_ref = None
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
+                      has_kmask, with_dq):
+    """dk and dv of one k-block, summed over its visible q-blocks; with
+    ``with_dq`` dq too, from the same tiles: ``dq_ref`` is then the head's
+    whole [Tq, D] float32, resident across every (ki, qi), and each tile adds
+    its product to the rows of its q-block."""
+    rest = list(rest)
+    km_ref = rest.pop(0) if has_kmask else None
+    dq_ref = rest.pop(0) if with_dq else None
+    dk_ref, dv_ref, dk_scr, dv_scr = rest
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -263,11 +278,20 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
+    if with_dq:
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+        # k-block 0 comes first for every q-block, and no mask hides it whole
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_ref[0, rows, :] = jnp.zeros((block_q, dq_ref.shape[-1]),
+                                           dq_ref.dtype)
+
     visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
 
     @pl.when(visible)
     def _body():
-        p, _, valid = _recompute_p(q_ref, k_ref, lse_ref, km_ref, qi=qi, ki=ki,
+        p, k, valid = _recompute_p(q_ref, k_ref, lse_ref, km_ref, qi=qi, ki=ki,
                                    causal=causal, scale=scale, block_q=block_q,
                                    block_k=block_k, seq_q=seq_q, seq_k=seq_k)
         q = q_ref[0]
@@ -287,6 +311,12 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_scr[:] += scale * jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            # dq[rows] += ds @ k: the dq kernel's product, in its order (ki
+            # ascending for a q-block's rows)
+            dq_ref[0, rows, :] += scale * jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -294,85 +324,113 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
-                    block_k, interpret, kmask=None, vma=None):
-    """O(T*D)-memory flash backward. lse/delta: [B,H,Tq,1] float32.
-
-    Returns (dq, dk, dv) in float32 (callers cast to input dtypes)."""
+def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
+                       interpret, kmask=None, vma=None):
+    """The backward at ``tiles`` (a ``BwdTiles``). Fused: one call on the
+    dk/dv kernel's grid, every visible tile worked once. Else a dq call
+    (q-blocks outer) and a dk/dv call (k-blocks outer), each working every
+    visible tile, 7 products for the 5 the gradients need: for the shapes
+    whose whole dq does not fit beside the tiles, and the reference the
+    fused call is held to, bit for bit."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    bq = min(block_q, Tq)
-    bk = min(block_k, Tk)
-    qf = q.reshape(B * H, Tq, D)
-    kf = k.reshape(B * H, Tk, D)
-    vf = v.reshape(B * H, Tk, D)
-    dof = do.reshape(B * H, Tq, D)
-    lsef = lse.reshape(B * H, Tq, 1)
-    deltaf = delta.reshape(B * H, Tq, 1)
-    has_km = kmask is not None
-    kmf = kmask.astype(jnp.float32).reshape(B, 1, Tk) if has_km else None
+    bq = min(tiles.block_q, Tq)
+    bk = min(tiles.block_k, Tk)
+    nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
+    operands = [q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
+                v.reshape(B * H, Tk, D), do.reshape(B * H, Tq, D),
+                lse.reshape(B * H, Tq, 1), delta.reshape(B * H, Tq, 1)]
+    if kmask is not None:
+        operands.append(kmask.astype(jnp.float32).reshape(B, 1, Tk))
+    kernel_kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
+                     seq_q=Tq, seq_k=Tk, has_kmask=kmask is not None)
 
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
+    def float32(rows):
+        return _sds((B * H, rows, D), jnp.float32, vma)
+
+    # k-blocks outer, q-blocks inner. k, v, dk and dv stay put along the
+    # inner axis: one buffer each. With two, at (1024, 1024) the dk/dv call
+    # needs 16.18-16.68 MB of scoped VMEM whenever XLA keeps none of its
+    # operands or results in VMEM, over the v5e's 16 MB: it compiled or not
+    # by what XLA's memory-space assignment did around it (alone at
+    # T >= 8,192 it never did). The second buffer bought overlap once per
+    # k-block only.
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0),
                           memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
-                          memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
+                          memory_space=pltpu.VMEM,
+                          pipeline_mode=pl.Buffered(1))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
                             memory_space=pltpu.VMEM)
     in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
-    operands = [qf, kf, vf, dof, lsef, deltaf]
-    if has_km:
-        in_specs.append(pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H, 0, j),
+    if kmask is not None:
+        in_specs.append(pl.BlockSpec((1, 1, bk),
+                                     lambda b, j, i: (b // H, 0, j),
                                      memory_space=pltpu.VMEM))
-        operands.append(kmf)
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, seq_q=Tq, seq_k=Tk,
-                          has_kmask=has_km),
-        name="flash_attention_bwd_dq",
-        out_shape=_sds(qf.shape, jnp.float32, vma),
-        grid=(B * H, pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)),
+    out_shape, out_specs = [float32(Tk)] * 2, [k_spec] * 2
+    if tiles.fused:
+        # a head's whole dq, in whole q-blocks of rows so that the last
+        # block's slice stays inside: a ragged tail's rows receive zeros (ds
+        # is masked there) and are cut off below
+        out_shape.insert(0, float32(nq * bq))
+        out_specs.insert(0, pl.BlockSpec((1, nq * bq, D),
+                                         lambda b, j, i: (b, 0, 0),
+                                         memory_space=pltpu.VMEM,
+                                         pipeline_mode=pl.Buffered(1)))
+    *dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_dkv_kernel, with_dq=tiles.fused, **kernel_kw),
+        name="flash_attention_bwd" if tiles.fused else "flash_attention_bwd_dkv",
+        out_shape=out_shape,
+        grid=(B * H, nk, nq),
         in_specs=in_specs,
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
-
-    # k-blocks outer, q-blocks inner: index maps swap i<->j roles
-    q_spec2 = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0),
-                           memory_space=pltpu.VMEM)
-    # k, v, dk and dv stay put along the inner (q-block) axis: one buffer each.
-    # With two, at bwd_tiles' (1024, 1024) the call needs 16.18-16.68 MB of
-    # scoped VMEM whenever XLA keeps none of its operands or results in VMEM,
-    # over the v5e's 16 MB: it compiled or not by what XLA's memory-space
-    # assignment did around it (alone at T >= 8,192 it never did). The second
-    # buffer bought overlap once per k-block only.
-    k_spec2 = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
-                           memory_space=pltpu.VMEM,
-                           pipeline_mode=pl.Buffered(1))
-    row_spec2 = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
-                             memory_space=pltpu.VMEM)
-    in_specs2 = [q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2]
-    if has_km:
-        in_specs2.append(pl.BlockSpec((1, 1, bk),
-                                      lambda b, j, i: (b // H, 0, j),
-                                      memory_space=pltpu.VMEM))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, causal=causal, scale=scale,
-                          block_q=bq, block_k=bk, seq_q=Tq, seq_k=Tk,
-                          has_kmask=has_km),
-        name="flash_attention_bwd_dkv",
-        out_shape=(_sds(kf.shape, jnp.float32, vma),
-                   _sds(vf.shape, jnp.float32, vma)),
-        grid=(B * H, pl.cdiv(Tk, bk), pl.cdiv(Tq, bq)),
-        in_specs=in_specs2,
-        out_specs=(k_spec2, k_spec2),
+        out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
     )(*operands)
+
+    if tiles.fused:
+        dq, = dq
+        if nq * bq != Tq:
+            dq = dq[:, :Tq]
+    else:
+        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
+                              memory_space=pltpu.VMEM)
+        k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
+                              memory_space=pltpu.VMEM)
+        row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
+                                memory_space=pltpu.VMEM)
+        in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
+        if kmask is not None:
+            in_specs.append(pl.BlockSpec((1, 1, bk),
+                                         lambda b, i, j: (b // H, 0, j),
+                                         memory_space=pltpu.VMEM))
+        dq = pl.pallas_call(
+            functools.partial(_flash_dq_kernel, **kernel_kw),
+            name="flash_attention_bwd_dq",
+            out_shape=float32(Tq),
+            grid=(B * H, nq, nk),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interpret,
+        )(*operands)
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
             dv.reshape(B, H, Tk, D))
+
+
+def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
+                    block_k, interpret, kmask=None, vma=None):
+    """O(T*D)-memory flash backward. lse/delta: [B,H,Tq,1] float32.
+
+    ``block_q`` / ``block_k`` are the least tiles the caller wants;
+    ``bwd_tiles`` picks the tiles and the layout from the shapes. Returns
+    (dq, dk, dv) in float32 (callers cast to input dtypes)."""
+    tiles = bwd_tiles(block_q, block_k, q.shape[-1], q.shape[2], k.shape[2],
+                      q.dtype.itemsize)
+    return _flash_backward_at(tiles, q, k, v, do, lse, delta, causal=causal,
+                              scale=scale, interpret=interpret, kmask=kmask,
+                              vma=vma)
 
 
 # --------------------------------------------------------------------------
@@ -427,29 +485,71 @@ def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k):
     return out, (q, k, v, kmask, out, lse)
 
 
-def bwd_tiles(block_q, block_k, head_dim, vmem_budget=15 << 20):
-    """VMEM-budget-aware backward tile sizes.
+#: what one kernel may hold on the v5e with no ``vmem_limit_bytes`` raised,
+#: less the half MB by which a call's need moves with what XLA places around it
+VMEM_BUDGET_BYTES = (16 << 20) - (512 << 10)
 
-    Measured on v5e: the bwd kernels want much larger tiles than the fwd
-    (1024x1024 is ~3x faster than 128x128 at T=8192 — grid overhead
-    dominates small tiles), but the [bq, bk] f32 probability/ds tiles plus
-    the [tile, D] operands must fit the ~16M scoped-VMEM limit, so large
-    head dims scale the tiles back down. The budget is calibrated against
-    the 16M scoped-VMEM limit: (1024,1024) at head_dim 128 estimates 14.7M
-    and compiles/runs on v5e; (2048,1024) estimates 25M and is rejected by
-    Mosaic (measured 18.79M actual). Tiles also clamp to the actual
-    sequence lengths inside _flash_backward."""
+#: the fused backward's tiles, the fastest first (measured on the v5e at
+#: (2, 16, 4096, 128) bfloat16, causal: 3.67, 4.00 and 4.21 ms a call, the two
+#: calls 5.12; (1024, 1024) needs ``vmem_limit_bytes`` raised and is 0.8 %
+#: faster alone but 0.5 % slower inside a training step: PERF.md, PR 33)
+FUSED_TILES = ((512, 1024), (1024, 512), (512, 512))
+
+
+class BwdTiles(NamedTuple):
+    block_q: int
+    block_k: int
+    fused: bool
+
+
+def bwd_vmem_bytes(bq, bk, head_dim, itemsize, dq_rows=0):
+    """Scoped VMEM a backward call over k-blocks needs at tiles (bq, bk): the
+    dk/dv call, or with ``dq_rows`` the fused call that keeps a head's dq.
+
+    What is there: q and do blocks, two buffers each; the ``lse`` and
+    ``delta`` row blocks, two buffers each, a ``[bq, 1]`` float32 block padded
+    to 128 lanes (0.5 MB a buffer at bq 1,024); k and v, one buffer; dk and
+    dv, an output block and an accumulator each; the resident dq; a head
+    dimension of 64 padded to 128 lanes; and the compiler's own [bq, bk]
+    temporaries, taken as two and a half float32 tiles (its figures come to
+    1.8-2.7). Against the compiler's own figures for a described v5e
+    (bfloat16, head 128): fused at T 4,096 17.07 MB at (1024, 1024), 10.21 at
+    (512, 1024), 9.83 at (1024, 512), 6.67 at (512, 512); fused at T 16,384
+    16.96 at (512, 1024), 13.42 at (512, 512); dk/dv at (1024, 1024) 13.68
+    (T 4,096) to 15.18 (T 16,384), 24.98 at (2048, 1024). This reads 17.5,
+    11.0, 11.25, 7.25; 17.0, 13.25; 15.5, 28.5 (MB of 2**20 bytes)."""
+    lanes = -(-head_dim // 128) * 128
+    moving = 2 * 2 * bq * (lanes * itemsize + 128 * 4)
+    stationary = 2 * bk * lanes * (itemsize + 2 * 4)
+    return (moving + stationary + dq_rows * lanes * 4
+            + 5 * bq * bk * 4 // 2)
+
+
+def bwd_tiles(block_q, block_k, head_dim, seq_q, seq_k, itemsize):
+    """The backward's tiles and layout, from the shapes alone.
+
+    Fused (one call, every score tile worked once) wherever a head's whole
+    dq, ``seq_q x head_dim`` float32, fits in VMEM beside the tiles at one of
+    ``FUSED_TILES``: at head 128 up to T = 20,480. Past that the two calls,
+    at the largest tiles that fit from (1024, 1024) up (``block_q`` /
+    ``block_k`` raise the start): the backward wants larger tiles than the
+    forward (measured on the v5e at T = 8,192: 1024 x 1024 is ~3x faster than
+    128 x 128), and a large head dimension scales them back down. Tiles
+    clamp to the sequence lengths."""
+    for bq, bk in FUSED_TILES:
+        bq, bk = min(bq, seq_q), min(bk, seq_k)
+        dq_rows = -(-seq_q // bq) * bq
+        if (bwd_vmem_bytes(bq, bk, head_dim, itemsize, dq_rows)
+                <= VMEM_BUDGET_BYTES):
+            return BwdTiles(bq, bk, True)
     bq, bk = max(block_q, 1024), max(block_k, 1024)
-
-    def est(bq, bk):
-        return 3 * bq * bk * 4 + 4 * max(bq, bk) * head_dim * 4
-
-    while est(bq, bk) > vmem_budget and max(bq, bk) > 128:
+    while (bwd_vmem_bytes(bq, bk, head_dim, itemsize) > VMEM_BUDGET_BYTES
+           and max(bq, bk) > 128):
         if bq >= bk:
             bq //= 2
         else:
             bk //= 2
-    return bq, bk
+    return BwdTiles(min(bq, seq_q), min(bk, seq_k), False)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, res, g):
@@ -457,12 +557,12 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
     # recomputed from the saved logsumexp — HBM stays O(T*D), which is what
     # makes long-context *training* (not just inference) sub-quadratic
     q, k, v, kmask, out, lse = res
-    bq, bk = bwd_tiles(block_q, block_k, q.shape[-1])
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
         axis=-1, keepdims=True)
     dq, dk, dv = _flash_backward(q, k, v, g, lse, delta, causal=causal,
-                                 scale=scale, block_q=bq, block_k=bk,
-                                 interpret=interpret_mode(), kmask=kmask)
+                                 scale=scale, block_q=block_q,
+                                 block_k=block_k, interpret=interpret_mode(),
+                                 kmask=kmask)
     dkm = None if kmask is None else jnp.zeros_like(kmask)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dkm
 
@@ -508,10 +608,10 @@ def flash_attention(q, k, v, *, mask=None, bias=None, scale=None,
                     causal=False, block_q: int = 512, block_k: int = 1024):
     """Public entry: same signature as the XLA dot_product_attention.
 
-    Default tiles are the v5e sweet spot measured at T=8192 (fwd 512x1024,
-    bwd 1024x1024 via _flash_bwd): small 128-tiles leave >2x on the table —
-    grid overhead dominates; 2048-tiles exceed the 16M VMEM scoped limit.
-    Tiles clamp to the actual sequence lengths for short inputs.
+    Default tiles are the v5e sweet spot measured at T=8192 (fwd 512x1024;
+    the backward's come from ``bwd_tiles``): small 128-tiles leave >2x on the
+    table — grid overhead dominates; 2048-tiles exceed the 16M VMEM scoped
+    limit. Tiles clamp to the actual sequence lengths for short inputs.
 
     ``mask`` accepts key-padding masks ([B, Tk] or the layer tier's
     [B, 1, 1, Tk]); general [Tq, Tk]-varying masks are structurally

@@ -187,12 +187,9 @@ def _ring_flash_vjp_bwd(axis, causal, scale, block_q, block_k, res, do):
     dv_carry = _pvary(jnp.zeros(v.shape, jnp.float32), (axis,))
     k_cur, v_cur = k, v
     km_cur = None if kmask is None else kmask.astype(jnp.float32)
-    # bwd kernels want large tiles, bounded by VMEM (see bwd_tiles)
-    from deeplearning4j_tpu.ops.pallas.flash_attention import bwd_tiles
-
-    bwq, bwk = bwd_tiles(block_q, block_k, q.shape[-1])
-    blk = functools.partial(flash_block_bwd, scale=scale,
-                            block_q=bwq, block_k=bwk, vma=(axis,))
+    # the backward's own tiles come from the block's shapes (bwd_tiles)
+    blk = functools.partial(flash_block_bwd, scale=scale, block_q=block_q,
+                            block_k=block_k, vma=(axis,))
     for i in range(n):
         if i == 0:
             dq_i, dk_i, dv_i = blk(q, k_cur, v_cur, do, lse, delta,
@@ -532,17 +529,15 @@ def _ring_zigzag_vjp_fwd(q, k, v, axis, scale, block_q, block_k):
 
 
 def _ring_zigzag_vjp_bwd(axis, scale, block_q, block_k, res, do):
-    from deeplearning4j_tpu.ops.pallas.flash_attention import (bwd_tiles,
-                                                               flash_block_bwd)
+    from deeplearning4j_tpu.ops.pallas.flash_attention import flash_block_bwd
 
     q, k, v, o, lse = res
     n = lax.psum(1, axis)
     my = lax.axis_index(axis)
     B, H, Tl, D = q.shape
     S = Tl // 2
-    bwq, bwk = bwd_tiles(block_q, block_k, D)
-    blk = functools.partial(flash_block_bwd, scale=scale,
-                            block_q=bwq, block_k=bwk, vma=(axis,))
+    blk = functools.partial(flash_block_bwd, scale=scale, block_q=block_q,
+                            block_k=block_k, vma=(axis,))
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1,
                                                                  keepdims=True)
     qa, qb = q[:, :, :S], q[:, :, S:]

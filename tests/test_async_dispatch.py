@@ -10,6 +10,8 @@ loss trajectory, listener callbacks, error surfacing) except when the host
 blocks.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -37,10 +39,17 @@ from deeplearning4j_tpu.optimize.listeners import (
 def _reset_env(monkeypatch):
     """Each test starts from the async default (window=2, padding on) and
     leaves the process env flags untouched."""
-    for var in ("DL4J_TPU_ASYNC_STEPS", "DL4J_TPU_PAD_TAIL"):
+    flags = ("DL4J_TPU_ASYNC_STEPS", "DL4J_TPU_PAD_TAIL")
+    for var in flags:
         monkeypatch.delenv(var, raising=False)
     env.reload()
     yield
+    # monkeypatch undoes a test's setenv only AFTER this teardown: clear the
+    # flags first, or the reload bakes the last test's into the singleton and
+    # the next file on this worker starts from them (test_monitoring in sync
+    # mode: no dispatch or drain is counted)
+    for var in flags:
+        os.environ.pop(var, None)
     env.reload()
 
 

@@ -6,7 +6,9 @@ and assert allclose. Kernels run in Pallas interpret mode off-TPU, so these
 tests validate kernel logic; Mosaic compilation is exercised on real TPU.
 """
 
+import functools
 import importlib
+import re
 
 import numpy as np
 import pytest
@@ -481,9 +483,63 @@ class TestLayerPathSelection:
 
 
 class TestFlashAttentionBackward:
-    """The flash backward kernels (dq, dk/dv) vs XLA's autodiff through the
-    plain lowering — the cuDNN-parity pattern for gradients. Exercises causal
-    block skipping, ragged tail blocks, and the saved-logsumexp recompute."""
+    """The flash backward (the fused kernel; the dq and dk/dv pair where a
+    head's dq does not fit) vs XLA's autodiff through the plain lowering —
+    the cuDNN-parity pattern for gradients. Exercises causal block skipping,
+    ragged tail blocks, and the saved-logsumexp recompute."""
+
+    @pytest.mark.parametrize("kmask", [False, True], ids=["no_mask", "kmask"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("causal,seq_q,seq_k", [
+        (False, 256, 256), (True, 256, 256),    # whole tiles of (64, 128)
+        (False, 200, 200), (True, 200, 200),    # a ragged tail on both axes
+        (False, 192, 320),                      # Tq != Tk
+    ])
+    def test_fused_call_is_the_two_calls_to_the_last_bit(self, rng, causal,
+                                                         seq_q, seq_k, dtype,
+                                                         kmask):
+        """Several tiles on both axes, so dq's rows are added to over
+        k-blocks and dk / dv over q-blocks, in the two calls' order."""
+        B, H, D = 2, 2, 128
+        q, do = (jnp.asarray(rng.normal(size=(B, H, seq_q, D)), dtype)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rng.normal(size=(B, H, seq_k, D)), dtype)
+                for _ in range(2))
+        km = None
+        if kmask:
+            m = np.ones((B, seq_k), np.float32)
+            m[0, seq_k // 2:] = 0        # whole k-blocks masked out
+            m[1, :] = 0                  # every row of this example fully masked
+            km = jnp.asarray(m)
+        kw = dict(causal=causal, scale=D ** -0.5, interpret=True, kmask=km)
+        out, lse = flash_module._flash_forward(q, k, v, block_q=64,
+                                               block_k=128, **kw)
+        delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(
+            axis=-1, keepdims=True)
+        fused, two = (flash_module._flash_backward_at(
+            flash_module.BwdTiles(64, 128, fused), q, k, v, do, lse, delta,
+            **kw) for fused in (True, False))
+        for name, a, b in zip(("dq", "dk", "dv"), fused, two):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.dtype == b.dtype == np.float32 and a.shape == b.shape
+            assert np.isfinite(a).all(), name
+            np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32),
+                                          err_msg=name)
+        assert float(jnp.abs(fused[0]).max()) > 0
+
+    @pytest.mark.parametrize("fused", [True, False])
+    def test_the_layout_is_bwd_tiles_choice(self, monkeypatch, kernel_calls,
+                                            fused):
+        """``_flash_backward`` runs what ``bwd_tiles`` says, at its tiles."""
+        monkeypatch.setattr(flash_module, "bwd_tiles",
+                            lambda *a: flash_module.BwdTiles(64, 128, fused))
+        q = jnp.ones((1, 1, 256, 128), jnp.float32)
+        calls = kernel_calls(jax.grad(lambda q: flash_attention(q, q, q).sum()), q)
+        assert calls == ({"flash_attention_fwd": 1, "flash_attention_bwd": 1}
+                         if fused else
+                         {"flash_attention_fwd": 1, "flash_attention_bwd_dq": 1,
+                          "flash_attention_bwd_dkv": 1})
 
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("shape", [(2, 2, 256, 128), (1, 2, 200, 128)])
@@ -561,8 +617,7 @@ class TestFlashAttentionUnderCheckpoint:
         plain = grad_of(attend)
         kept = grad_of(jax.checkpoint(attend, policy=policy))
         bare = grad_of(jax.checkpoint(attend))
-        once = {"flash_attention_fwd": 1, "flash_attention_bwd_dq": 1,
-                "flash_attention_bwd_dkv": 1}
+        once = {"flash_attention_fwd": 1, "flash_attention_bwd": 1}
         assert kernel_calls(plain, q, k, v) == once
         assert kernel_calls(kept, q, k, v) == once
         assert kernel_calls(bare, q, k, v) == {**once, "flash_attention_fwd": 2}
@@ -582,12 +637,12 @@ class TestFlashAttentionUnderCheckpoint:
 
 
 class TestFlashBackwardCompilesForTheV5e:
-    """With two buffers for every block the dk/dv kernel at ``bwd_tiles``'
-    (1024, 1024) needed 16.18-16.68 MB of scoped VMEM where XLA keeps none of
-    its operands in VMEM, over the chip's 16 MB, and compiled or not by what
-    XLA placed around it. These shapes are too large for XLA to keep there,
-    so the compile sees the kernel's whole need. Compiled for a described
-    chip; nothing runs."""
+    """The backward at the tiles ``bwd_tiles`` gives, compiled for a described
+    chip; nothing runs. These shapes are too large for XLA to keep an operand
+    in VMEM, so the compile sees the kernel's whole need: with two buffers
+    for every block the dk/dv kernel at (1024, 1024) needed 16.18-16.68 MB of
+    scoped VMEM, over the chip's 16, and compiled or not by what XLA placed
+    around it; the fused kernel holds a head's whole dq besides."""
 
     @pytest.fixture(scope="class")
     def one_chip(self):
@@ -606,19 +661,46 @@ class TestFlashBackwardCompilesForTheV5e:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
 
-    @pytest.mark.parametrize("shape", [(2, 16, 8192, 128), (2, 16, 16384, 128),
-                                       (8, 12, 4096, 64)])
-    def test_backward_kernels_fit_scoped_vmem(self, one_chip, shape):
+    @staticmethod
+    def compiled(one_chip, shape, tiles):
         B, H, T, D = shape
         wide = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
         row = jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32, sharding=one_chip)
-        bq, bk = flash_module.bwd_tiles(512, 1024, D)
-        assert (bq, bk) == (1024, 1024)
-        text = jax.jit(lambda q, k, v, do, lse, delta: flash_module._flash_backward(
-            q, k, v, do, lse, delta, causal=True, scale=D ** -0.5, block_q=bq,
-            block_k=bk, interpret=False)).lower(
+        return jax.jit(functools.partial(
+            flash_module._flash_backward_at, tiles, causal=True,
+            scale=D ** -0.5, interpret=False)).lower(
                 wide, wide, wide, wide, row, row).compile().as_text()
-        assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+    @pytest.mark.parametrize("shape,tiles,calls", [
+        ((2, 16, 4096, 128), (512, 1024, True), 1),     # the looped decoder's cell
+        ((2, 16, 8192, 128), (512, 1024, True), 1),
+        ((2, 16, 16384, 128), (512, 512, True), 1),     # dq alone is 8 MB
+        ((8, 12, 4096, 64), (512, 1024, True), 1),      # 64 lanes padded to 128
+        ((1, 4, 32768, 128), (1024, 1024, False), 2),   # dq would be 16 MB
+    ])
+    def test_backward_kernels_fit_scoped_vmem(self, one_chip, shape, tiles,
+                                              calls):
+        T, D = shape[2:]
+        chosen = flash_module.bwd_tiles(512, 1024, D, T, T, 2)
+        assert chosen == tiles
+        text = self.compiled(one_chip, shape, chosen)
+        assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+    def test_the_estimate_stands_just_over_the_compilers_figure(self, one_chip):
+        """At (1024, 1024) the fused call does not fit beside the cell's dq:
+        the compiler names its figure, and ``bwd_vmem_bytes`` is to count
+        what is there, a little high."""
+        shape = (2, 16, 4096, 128)
+        with pytest.raises(Exception, match="Scoped allocation") as refused:
+            self.compiled(one_chip, shape, flash_module.BwdTiles(1024, 1024, True))
+        asked, limit = re.search(r"size ([0-9.]+)M and limit ([0-9.]+)M",
+                                 str(refused.value)).groups()
+        assert float(limit) == 16.0
+        estimate = flash_module.bwd_vmem_bytes(1024, 1024, 128, 2,
+                                               dq_rows=4096) / 2 ** 20
+        assert estimate == 17.5
+        assert float(asked) <= estimate <= 1.05 * float(asked)
+        assert estimate * 2 ** 20 > flash_module.VMEM_BUDGET_BYTES
 
 
 class TestFusedLSTMGradients:

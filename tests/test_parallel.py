@@ -555,6 +555,38 @@ class TestSequenceParallelExtended:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-4, atol=5e-5)
 
+    def test_ring_backward_fused_block_kernel_equals_the_two_calls(self, rng, monkeypatch):
+        """Every ring step's block backward (``flash_block_bwd`` under
+        ``shard_map``, ``vma`` given, ``causal`` only on the diagonal) through
+        the fused kernel, against the dq and dk/dv pair forced by
+        ``bwd_tiles``: 4 devices, causal, two tiles along each block's axes."""
+        import importlib
+
+        from deeplearning4j_tpu.parallel.sequence import ring_attention
+
+        flash = importlib.import_module("deeplearning4j_tpu.ops.pallas.flash_attention")
+        mesh = DeviceMesh(data=1, seq=4, devices=jax.devices()[:4])
+        q, k, v, do = (jnp.asarray(rng.normal(size=(1, 2, 128, 128)).astype(np.float32))
+                       for _ in range(4))
+        ran = []
+
+        def gradients(fused):
+            def tiles(block_q, block_k, head_dim, seq_q, seq_k, itemsize):
+                assert (seq_q, seq_k) == (32, 32)           # the local block's
+                ran.append(fused)
+                return flash.BwdTiles(16, 16, fused)
+
+            monkeypatch.setattr(flash, "bwd_tiles", tiles)
+            return jax.grad(lambda q, k, v: (ring_attention(
+                q, k, v, mesh.mesh, causal=True, impl="flash") * do).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        fused, two = gradients(True), gradients(False)
+        assert True in ran and False in ran
+        for name, a, b in zip("qkv", fused, two):
+            assert float(jnp.abs(a).max()) > 0
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"d{name}")
+
     def test_ulysses_causal(self, rng):
         from deeplearning4j_tpu.parallel.sequence import ulysses_attention
 
