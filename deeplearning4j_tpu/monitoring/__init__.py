@@ -159,6 +159,12 @@ def span(name: str, **args):
 
 
 # ---- per-subsystem instrument bundles -----------------------------------
+@jax.jit
+def _pack_moe_stats(states):
+    """``[layers, 5]``: each expert layer's ``moe_stats`` and its loss term."""
+    return jax.numpy.stack([jax.numpy.append(s["moe_stats"], s["loss_term"]) for s in states])
+
+
 class _FitMonitor:
     """Fit-path instruments: the per-iteration wall-time split as histograms
     + spans, plus iteration counter and score gauge. Sync mode times
@@ -217,6 +223,19 @@ class _FitMonitor:
             "Mean exit probability of each pass over the latest step's positions",
             labels=("pass",))
         self._exit_shares: collections.deque = collections.deque()
+        # in the order an expert layer's ``moe_stats`` holds them
+        # (``nn/layers/experts.py`` ``MOE_STATS``, whose fourth is the raw term)
+        self.moe_load = {
+            stat: reg.gauge(f"dl4j_train_moe_{stat}", help, labels=("layer",))
+            for stat, help in (
+                ("load_max_over_mean",
+                 "Largest held expert's (token, expert) pairs over the mean, latest step"),
+                ("pairs_held", "(token, expert) pairs whose expert the layer holds, latest step"),
+                ("tokens_unserved", "Tokens none of whose experts the layer holds, latest step"))}
+        self.moe_aux_loss = reg.gauge(
+            "dl4j_train_moe_aux_loss",
+            "The routers' load-balancing terms as added to the latest step's score, summed")
+        self._moe_stats: collections.deque = collections.deque()
 
     @contextlib.contextmanager
     def phase(self, name: str, **ids):
@@ -238,6 +257,13 @@ class _FitMonitor:
             # fetched: its exit shares are ready, this fetch waits for nothing
             for t, share in enumerate(self._exit_shares.popleft().tolist()):
                 self.exit_share.labels(**{"pass": str(t + 1)}).set(share)
+        if self._moe_stats:
+            layers, packed = self._moe_stats.popleft()
+            rows = packed.tolist()          # one fetch: [layers, moe_stats + the term]
+            for layer, row in zip(layers, rows):
+                for gauge, value in zip(self.moe_load.values(), row):
+                    gauge.labels(layer=layer).set(value)
+            self.moe_aux_loss.set(sum(row[-1] for row in rows))
 
     def describe_loops(self, layers, remat: bool = False) -> None:
         """The looped stacks of a model about to be fitted, as gauges. Under
@@ -256,6 +282,16 @@ class _FitMonitor:
         share = state.get("exit_share") if isinstance(state, dict) else None
         if share is not None:
             self._exit_shares.append(jax.numpy.copy(share))
+
+    def hold_moe_stats(self, states: dict) -> None:
+        """Keep a dispatched step's expert-layer loads (``{layer: state}``:
+        ``moe_stats``, four floats, and the layer's loss term) until its score
+        is delivered: a copy, like ``hold_exit_share``'s, and one computation
+        for all the layers (a copy a layer and array, eight a step in a
+        four-layer model, filled the runtime's queue of computations in flight
+        and held every dispatch for a step's length: PERF.md, PR 34)."""
+        if states:
+            self._moe_stats.append((list(states), _pack_moe_stats(list(states.values()))))
 
     def wrap_batches(self, data, model):
         """Iterate ``data`` timing each pull as the data-wait phase of the

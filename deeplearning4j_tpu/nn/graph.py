@@ -20,7 +20,7 @@ import numpy as np
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.graph import LayerVertex
-from deeplearning4j_tpu.nn.layers.base import checkpoint_layer
+from deeplearning4j_tpu.nn.layers.base import checkpoint_layer, layer_loss_terms
 from deeplearning4j_tpu.nn.multilayer import _check_carry_batch, _tree_cast
 from deeplearning4j_tpu.nn.network import Network, _unpack
 from deeplearning4j_tpu.optimize.updaters import NoOp, get_updater
@@ -387,6 +387,8 @@ class ComputationGraph(Network):
         for name, v in self.conf.vertices.items():
             if isinstance(v, LayerVertex) and name in params:
                 loss = loss + v.layer.regularization(params[name])
+        for term in layer_loss_terms(new_state):
+            loss = loss + term.astype(jnp.float32)
         return loss
 
     def _step_loss(self, params, state, inputs, labels, key, masks,

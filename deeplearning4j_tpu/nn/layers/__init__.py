@@ -37,6 +37,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     DecoderBlock,
 )
 from deeplearning4j_tpu.nn.layers.looped import LoopedStack
+from deeplearning4j_tpu.nn.layers.experts import SparseExpertsLayer
 
 __all__ = [
     "Layer", "register_layer",
@@ -54,6 +55,6 @@ __all__ = [
     "BidirectionalLayer", "GravesBidirectionalLSTMLayer", "LastTimeStepLayer",
     "MaskZeroLayer", "TimeDistributedLayer",
     "SelfAttentionLayer", "LearnedSelfAttentionLayer", "TransformerEncoderLayer",
-    "DecoderBlock", "LoopedStack",
+    "DecoderBlock", "LoopedStack", "SparseExpertsLayer",
     "Yolo2OutputLayer", "AutoEncoderLayer", "VariationalAutoencoderLayer",
 ]

@@ -11,13 +11,16 @@ before and after each half, a gated MLP, no biases).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, resolve_activation
+from deeplearning4j_tpu.nn.layers.base import (
+    Layer, register_layer, resolve_activation, scope_name,
+)
 from deeplearning4j_tpu.ops.registry import op
 import deeplearning4j_tpu.ops.attention  # noqa: F401
 
@@ -275,15 +278,42 @@ class TransformerEncoderLayer(Layer):
         return x, state
 
 
-def rotary_tables(positions: int, head_dim: int, theta: float):
+def _inv_freq(head_dim: int, theta: float):
+    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_positions: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's ``head_dim / 2`` inverse frequencies, as ``transformers`` computes
+    them for a static ``rope_type: yarn`` section: the plain ``theta ** (-2 i /
+    head_dim)`` for the pairs that turn more than ``beta_fast`` times over
+    ``original_positions`` (extrapolated), that over ``factor`` for those that
+    turn less than ``beta_slow`` times (interpolated), a linear ramp between."""
+    def correction_dim(turns):
+        return head_dim * math.log(original_positions / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    plain = _inv_freq(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rotary_tables(positions: int, head_dim: int, theta: float, yarn=None):
     """(cos, sin), each ``[positions, head_dim]`` in float32, of rotary
     position embeddings in the rotate-half pairing: feature ``i`` is paired
     with ``i + head_dim / 2`` and both turn by ``position * theta ** (-2 i /
-    head_dim)``."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    head_dim)``. ``yarn``: ``(factor, original_positions, beta_fast, beta_slow,
+    attention_factor)`` scales the frequencies by ``yarn_inv_freq`` and
+    multiplies cos and sin by the attention factor, at every length."""
+    inv_freq = (_inv_freq(head_dim, theta) if yarn is None
+                else yarn_inv_freq(head_dim, theta, *yarn[:4]))
     angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)
-    return jnp.cos(angles), jnp.sin(angles)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos, sin) if yarn is None else (cos * yarn[4], sin * yarn[4])
 
 
 def apply_rotary(t, rope):
@@ -306,14 +336,20 @@ def rms_norm(x, gain, eps: float):
 @register_layer
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class DecoderBlock(Layer):
-    """Causal decoder block with rotary positions, sandwich RMSNorm and a
-    gated MLP, no biases — net-new (the block of looped / modern decoders).
+    """Causal decoder block with rotary positions, RMSNorm and a gated MLP, no
+    biases — net-new (the block of looped / modern decoders).
 
-    ``a = Attn(N1(h)); h = h + N2(a); m = MLP(N3(h)); h = h + N4(m)``, each
-    ``N`` an RMSNorm with its own gain; ``Attn`` projects to ``n_heads`` heads
-    of ``head_dim``, rotates q and k, and attends causally through the op
-    registry (so the flash kernel's predicate decides as for every caller);
-    ``MLP(u) = (silu(u Wg) * (u Wu)) Wd``.
+    ``norm="sandwich"``: ``a = Attn(N1(h)); h = h + N2(a); m = MLP(N3(h)); h =
+    h + N4(m)``, each ``N`` an RMSNorm with its own gain; ``norm="pre"``: the
+    same without ``N2`` and ``N4`` (gains ``n1_g``, ``n3_g`` alone). ``Attn``
+    projects to ``n_heads`` query heads and ``n_kv_heads`` key and value heads
+    (default as many) of ``head_dim``; with ``qk_norm`` an RMSNorm over each
+    head's features on q and on k, one gain each; rotates q and k (``rope_yarn``:
+    ``rotary_tables``' scaling); and attends causally, within ``window`` keys
+    where one is given, through the op registry (so the flash kernel's predicate
+    decides as for every caller). ``MLP(u) = (silu(u Wg) * (u Wu)) Wd``, or the
+    layer ``mlp`` (``SparseExpertsLayer``), whose parameters sit under ``"mlp"``
+    and whose state is the block's.
 
     ``apply`` takes ``rope``, the ``rotary_tables`` of its positions, from a
     container that applies many blocks in one step (``LoopedStack``) so that
@@ -326,44 +362,80 @@ class DecoderBlock(Layer):
     d_ff: Optional[int] = None
     rope_theta: float = 10000.0
     rms_eps: float = 1e-6
+    n_kv_heads: Optional[int] = None
+    window: Optional[int] = None
+    qk_norm: bool = False
+    norm: str = "sandwich"              # or "pre"
+    rope_yarn: Optional[tuple] = None   # (factor, original positions, beta_fast, beta_slow, attention factor)
+    mlp: Optional[Layer] = None         # None: the gated dense MLP of width d_ff
 
     @property
     def head_size(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def _gains(self) -> tuple:
+        if self.norm not in ("sandwich", "pre"):
+            raise ValueError(f"DecoderBlock norm {self.norm!r}: 'sandwich' or 'pre'")
+        return ("n1_g", "n2_g", "n3_g", "n4_g") if self.norm == "sandwich" else ("n1_g", "n3_g")
 
     def output_type(self, itype):
         return InputType.recurrent(self.d_model, itype.shape[0])
 
     def init(self, key, itype):
         D, A = self.d_model, self.n_heads * self.head_size
+        KV = self.kv_heads * self.head_size
         F = self.d_ff or 4 * D
         ks = jax.random.split(key, 7)
-        p = {"Wq": self._w(ks[0], (D, A)), "Wk": self._w(ks[1], (D, A)),
-             "Wv": self._w(ks[2], (D, A)), "Wo": self._w(ks[3], (A, D)),
-             "Wg": self._w(ks[4], (D, F)), "Wu": self._w(ks[5], (D, F)),
-             "Wd": self._w(ks[6], (F, D))}
-        for g in ("n1_g", "n2_g", "n3_g", "n4_g"):
+        p = {"Wq": self._w(ks[0], (D, A)), "Wk": self._w(ks[1], (D, KV)),
+             "Wv": self._w(ks[2], (D, KV)), "Wo": self._w(ks[3], (A, D))}
+        state = {}
+        if self.mlp is None:
+            p.update(Wg=self._w(ks[4], (D, F)), Wu=self._w(ks[5], (D, F)),
+                     Wd=self._w(ks[6], (F, D)))
+        else:
+            p["mlp"], state = self.mlp.init(ks[4], self.output_type(itype))
+        for g in self._gains:
             p[g] = jnp.ones((D,))
-        return p, {}
+        if self.qk_norm:
+            p["q_g"], p["k_g"] = jnp.ones((self.head_size,)), jnp.ones((self.head_size,))
+        return p, state
 
     def rope_tables(self, positions: int):
-        return rotary_tables(positions, self.head_size, self.rope_theta)
+        return rotary_tables(positions, self.head_size, self.rope_theta, self.rope_yarn)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None, rope=None):
         B, T, _ = x.shape
+        sandwich = self.norm == "sandwich"
         if rope is None:
             rope = self.rope_tables(T)
 
-        def heads(t):
-            return t.reshape(B, T, self.n_heads, self.head_size).transpose(0, 2, 1, 3)
+        def heads(t, n):
+            return t.reshape(B, T, n, self.head_size).transpose(0, 2, 1, 3)
 
         h = rms_norm(x, params["n1_g"], self.rms_eps)
-        q = apply_rotary(heads(h @ params["Wq"]), rope)
-        k = apply_rotary(heads(h @ params["Wk"]), rope)
-        o = op("dot_product_attention")(q, k, heads(h @ params["Wv"]),
-                                        mask=_attn_mask(mask, T, T), causal=True)
+
+        def rotated(w, n, gain):
+            t = heads(h @ params[w], n)
+            if self.qk_norm:
+                t = rms_norm(t, params[gain], self.rms_eps)
+            return apply_rotary(t, rope)
+
+        q, k = rotated("Wq", self.n_heads, "q_g"), rotated("Wk", self.kv_heads, "k_g")
+        o = op("dot_product_attention")(q, k, heads(h @ params["Wv"], self.kv_heads),
+                                        mask=_attn_mask(mask, T, T), causal=True,
+                                        window=self.window)
         a = o.transpose(0, 2, 1, 3).reshape(B, T, -1) @ params["Wo"]
-        x = x + rms_norm(a, params["n2_g"], self.rms_eps)
+        x = x + (rms_norm(a, params["n2_g"], self.rms_eps) if sandwich else a)
         h = rms_norm(x, params["n3_g"], self.rms_eps)
-        m = (jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])) @ params["Wd"]
-        return x + rms_norm(m, params["n4_g"], self.rms_eps), state
+        if self.mlp is None:
+            m = (jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])) @ params["Wd"]
+        else:
+            with jax.named_scope(scope_name("mlp", self.mlp)):
+                m, state = self.mlp.apply(params["mlp"], state, h, train=train, rng=rng,
+                                          mask=mask)
+        return x + (rms_norm(m, params["n4_g"], self.rms_eps) if sandwich else m), state

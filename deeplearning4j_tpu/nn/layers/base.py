@@ -41,6 +41,20 @@ LAYER_REGISTRY: dict[str, type] = {}
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(SAVED_OUT, SAVED_LSE)
 
 
+#: the key of a layer's new state under which its ``apply`` hands back a scalar
+#: for the score (a router's load-balancing term): computed from the step's
+#: activations, differentiated with the loss, under ``remat`` like the rest
+LOSS_TERM = "loss_term"
+
+
+def layer_loss_terms(new_states) -> list:
+    """The scalars the layers handed back for the score, from their new states
+    (a list by layer or a dict by vertex). None in a network without such a
+    layer, whose step is then the program it was."""
+    states = new_states.values() if isinstance(new_states, dict) else new_states
+    return [s[LOSS_TERM] for s in states if isinstance(s, dict) and LOSS_TERM in s]
+
+
 def checkpoint_layer(fn):
     """``jax.checkpoint`` of one layer application, for every network that
     honours ``remat`` (``MultiLayerNetwork``, ``ComputationGraph``,

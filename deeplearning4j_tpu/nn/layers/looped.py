@@ -70,7 +70,7 @@ class LoopedStack(Layer):
         ropes, extras = {}, []
         for layer in self.layers:
             if hasattr(layer, "rope_tables"):
-                key = (layer.head_size, layer.rope_theta)
+                key = (layer.head_size, layer.rope_theta, layer.rope_yarn)
                 if key not in ropes:
                     ropes[key] = layer.rope_tables(x.shape[1])
                 extras.append({"rope": ropes[key]})

@@ -25,7 +25,9 @@ import numpy as np
 from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.layers.base import checkpoint_layer, scope_name as _scope_name
+from deeplearning4j_tpu.nn.layers.base import (
+    checkpoint_layer, layer_loss_terms, scope_name as _scope_name,
+)
 from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
 # _unpack and global_norm_clip are imported from here by parallel/ and the
 # benchmark's tests
@@ -252,7 +254,10 @@ class MultiLayerNetwork(Network):
             else:
                 loss = per.mean()
             reg = sum(l.regularization(p) for l, p in zip(self.layers, params))
-        return loss + reg, new_states, new_carries
+            score = loss + reg
+            for term in layer_loss_terms(new_states):
+                score = score + term.astype(score.dtype)
+        return score, new_states, new_carries
 
     def _step_loss(self, params, state, x, y, key, mask, label_mask):
         cp = _tree_cast(params, self._policy.compute_dtype)

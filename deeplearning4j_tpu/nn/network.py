@@ -115,6 +115,12 @@ class Network:
     def score_value(self, value: float) -> None:
         self._score_value = value
 
+    def _moe_states(self) -> dict:
+        """``{entry: state}`` of the expert layers (those whose state keeps
+        ``moe_stats``), for the monitor."""
+        return {str(k): self.state[k] for k in _entry_keys(self.state)
+                if isinstance(self.state[k], dict) and "moe_stats" in self.state[k]}
+
     # ------------------------------------------------------- the jitted step
     def _apply_updaters(self, grads, params, opt_state, step):
         with jax.named_scope("clip"):
@@ -228,6 +234,7 @@ class Network:
             self.params, self.state, self.opt_state, loss = step_fn(*args)
             if mon is not None:
                 mon.hold_exit_share(self._exit_state())
+                mon.hold_moe_stats(self._moe_states())
             return loss
 
         result = run_step(self, call, window, mon)

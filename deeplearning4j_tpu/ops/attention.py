@@ -21,8 +21,12 @@ from deeplearning4j_tpu.ops.registry import op, register_op
 
 @register_op("dot_product_attention")
 def dot_product_attention(q, k, v, *, mask=None, bias=None, scale=None,
-                          causal=False):
+                          causal=False, window=None):
     """softmax(q k^T / sqrt(d)) v.
+
+    k / v may hold fewer heads than q (a divisor of its count): query head h
+    reads key-value head ``h // (N // Nkv)``. ``window`` (with ``causal``):
+    query i sees keys j with ``0 <= i - j < window``.
 
     mask: broadcastable to [B, N, Tq, Tk], 1=keep 0=drop (additive -inf applied).
     bias: broadcastable to [B, N, Tq, Tk], ADDED to the scaled logits before
@@ -31,6 +35,11 @@ def dot_product_attention(q, k, v, *, mask=None, bias=None, scale=None,
     produces. The Pallas flash kernel structurally rejects bias-carrying
     calls (registry routes them here).
     """
+    if window is not None and not causal:
+        raise ValueError("dot_product_attention's window is a causal one: pass causal=True")
+    if k.shape[1] != q.shape[1]:
+        group = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(jnp.asarray(d, q.dtype))
     logits = jnp.einsum("bntd,bnsd->bnts", q, k) * scale
@@ -40,6 +49,8 @@ def dot_product_attention(q, k, v, *, mask=None, bias=None, scale=None,
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            cm &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         logits = jnp.where(cm, logits, neg)
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, neg)

@@ -32,7 +32,7 @@ def _reduce(per_elem, mask):
 
 
 def _logp(output, from_logits):
-    """Shared stable log-probability path (mcxent / sparse_mcxent)."""
+    """Stable log-probability path of mcxent."""
     if from_logits:
         return jax.nn.log_softmax(output, axis=-1)
     return jnp.log(jnp.clip(output, _EPS, 1.0))
@@ -61,9 +61,8 @@ def sparse_mcxent(labels, output, mask=None, from_logits=False):
 
     Out-of-range indices follow take_along_axis's jit semantics (clamped
     to the last class) — size the output layer to the FULL vocabulary."""
-    logp = _logp(output, from_logits)
     labels = jnp.asarray(labels).astype(jnp.int32)
-    if labels.ndim == logp.ndim:
+    if labels.ndim == output.ndim:
         # trailing singleton index dim (the RNN score path reshapes labels
         # to [B*T, 1]); a real one-hot here means the caller wanted mcxent
         if labels.shape[-1] != 1:
@@ -72,7 +71,15 @@ def sparse_mcxent(labels, output, mask=None, from_logits=False):
                 f"absent); got labels {labels.shape} against output "
                 f"{output.shape} — one-hot labels belong to loss='mcxent'")
         labels = labels[..., 0]
-    per = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    picked = jnp.take_along_axis(output, labels[..., None], axis=-1)[..., 0]
+    if from_logits:
+        # the log-sum-exp over the classes in float32 whatever type the logits
+        # were written in (a language model's head in bfloat16), and no
+        # [.., classes] array of log-probabilities beside them
+        per = (jax.nn.logsumexp(output.astype(jnp.float32), axis=-1)
+               - picked.astype(jnp.float32))
+    else:
+        per = -jnp.log(jnp.clip(picked, _EPS, 1.0))
     per, mask = _fold_mask(per, mask)
     return _reduce(per, mask)
 

@@ -26,6 +26,16 @@ in the same order, ``flash_attention_bwd_dq`` (q-blocks outer) and
 ``flash_attention_bwd_dkv`` (k-blocks outer), each working every tile: 7
 products for the 5.
 
+A causal ``window`` (query i sees keys j with ``0 <= i - j < window``) makes
+the inner grid axis relative: it runs over the blocks a block's band can reach
+and no further (``_band_blocks``), the index maps clamp to the band so that a
+step outside it moves nothing, and the band's two edges are masked inside the
+tiles they cross. Key-value heads shared by a group of query heads (``k``, ``v``
+with fewer heads than ``q``) are read through the index map, one k/v block for
+the group's query heads; dk and dv come out a query head each and the wrapper
+sums them over the group. With ``window=None`` and equal head counts every call
+is the program it was before either existed.
+
 Block-level primitives ``flash_block_fwd`` / ``flash_block_bwd`` are exposed
 for ring attention (parallel/sequence.py): the ring merges per-step (o, lse)
 pairs online and runs the backward with the *global* lse, so sequence-
@@ -60,32 +70,68 @@ def _sds(shape, dtype, vma=None):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _band_blocks(outer, block_outer, block_inner, n_inner, back, ahead):
+    """(first, last) inner block that meets the positions ``[outer *
+    block_outer - back, outer * block_outer + block_outer - 1 + ahead]``, kept
+    inside the ``n_inner`` blocks there are. A q-block's keys under a causal
+    window: ``back = window - 1, ahead = 0``; a k-block's queries: ``back = 0,
+    ahead = window - 1``. On ints (the grid's size) and on program ids."""
+    most, least = ((max, min) if isinstance(outer, int)
+                   else (jnp.maximum, jnp.minimum))
+    first = most(outer * block_outer - back, 0) // block_inner
+    last = least((outer * block_outer + block_outer - 1 + ahead) // block_inner,
+                 n_inner - 1)
+    return first, last
+
+
+def _band_steps(n_outer, *band):
+    """The most inner blocks any outer block's band holds: the inner grid
+    axis's length under a window."""
+    return max(last - first + 1 for first, last in
+               (_band_blocks(o, *band) for o in range(n_outer)))
+
+
+def _kv_row(group):
+    """Row of the flattened ``[B * Hkv, T, D]`` k / v that the grid's row ``b``
+    of ``[B * H, ...]`` reads: query head h reads key-value head h // group."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 # --------------------------------------------------------------------------
 # forward kernel
 # --------------------------------------------------------------------------
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
-                  seq_k, has_kmask):
+                  seq_k, has_kmask, window=None):
     if has_kmask:
         km_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         km_ref = None
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal block skipping: a k-block whose first key is past this q-block's
-    # last query contributes nothing — skip its FLOPs entirely (roughly
-    # halves the causal work; the standard flash-attention optimization)
-    visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    if window is None:
+        ki = step
+        # causal block skipping: a k-block whose first key is past this
+        # q-block's last query contributes nothing — skip its FLOPs entirely
+        # (roughly halves the causal work; the standard flash-attention
+        # optimization)
+        visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    else:
+        # the inner axis runs over the band's blocks alone
+        first, last = _band_blocks(qi, block_q, block_k, pl.cdiv(seq_k, block_k),
+                                   window - 1, 0)
+        ki = first + step
+        visible = ki <= last
 
     @pl.when(visible)
     def _body():
@@ -108,6 +154,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
             qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
                                                            (block_q, block_k), 0)
             s = jnp.where(qpos >= kpos, s, -jnp.inf)
+            if window is not None:
+                s = jnp.where(qpos - kpos < window, s, -jnp.inf)
 
         m_prev = m_scr[:]                                  # [bq, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -126,7 +174,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         l = l_scr[:]
         o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -137,27 +185,40 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
 
 
 def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
-                   kmask=None, vma=None):
-    """Returns (out [B,H,Tq,D], lse [B,H,Tq,1] float32).
+                   kmask=None, vma=None, window=None):
+    """Returns (out [B,H,Tq,D], lse [B,H,Tq,1] float32). ``k`` / ``v`` are
+    ``[B, Hkv, Tk, D]`` with ``Hkv`` dividing ``H``.
 
     ``kmask``: optional key-padding mask [B, Tk] (>0 = key visible) — the
     shape DL4J's per-example feature masks reduce to; blocked per (batch,
     k-block) with the batch index derived as ``b // H`` from the flattened
     batch*head grid axis, so the mask is never materialized per-head."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk = k.shape[1], k.shape[2]
     bq = min(block_q, Tq)
     bk = min(block_k, Tk)
     qf = q.reshape(B * H, Tq, D)
-    kf = k.reshape(B * H, Tk, D)
-    vf = v.reshape(B * H, Tk, D)
-    grid = (B * H, pl.cdiv(Tq, bq), pl.cdiv(Tk, bk))
+    kf = k.reshape(B * Hkv, Tk, D)
+    vf = v.reshape(B * Hkv, Tk, D)
+    nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
+    kv_row = _kv_row(H // Hkv)
+    if window is None:
+        steps, k_block = nk, lambda i, j: j
+    else:
+        band = (bq, bk, nk, window - 1, 0)
+        steps = _band_steps(nq, *band)
+
+        def k_block(i, j):
+            first, last = _band_blocks(i, *band)
+            return jnp.minimum(first + j, last)
+
+    grid = (B * H, nq, steps)
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, bk, D), lambda b, i, j: (kv_row(b), k_block(i, j), 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
+        pl.BlockSpec((1, bk, D), lambda b, i, j: (kv_row(b), k_block(i, j), 0),
                      memory_space=pltpu.VMEM),
     ]
     operands = [qf, kf, vf]
@@ -165,13 +226,14 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
         # [B, 1, Tk] so the block's trailing dims are (1, bk) — Mosaic's
         # (8, 128)-divisibility rule applies to the last two dims and a
         # middle dim of exactly 1 satisfies the equal-to-array case
-        in_specs.append(pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H, 0, j),
+        in_specs.append(pl.BlockSpec((1, 1, bk),
+                                     lambda b, i, j: (b // H, 0, k_block(i, j)),
                                      memory_space=pltpu.VMEM))
         operands.append(kmask.astype(jnp.float32).reshape(B, 1, Tk))
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, causal=causal, scale=scale,
                           block_q=bq, block_k=bk, seq_k=Tk,
-                          has_kmask=kmask is not None),
+                          has_kmask=kmask is not None, window=window),
         name="flash_attention_fwd",
         out_shape=(_sds(qf.shape, q.dtype, vma),
                    _sds((B * H, Tq, 1), jnp.float32, vma)),
@@ -199,7 +261,7 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
 
 
 def _recompute_p(q_ref, k_ref, lse_ref, km_ref, *, qi, ki, causal, scale,
-                 block_q, block_k, seq_q, seq_k):
+                 block_q, block_k, seq_q, seq_k, window=None):
     """Recompute one [bq, bk] probability tile exp(s - lse), fully masked."""
     q = q_ref[0]
     k = k_ref[0]
@@ -217,31 +279,42 @@ def _recompute_p(q_ref, k_ref, lse_ref, km_ref, *, qi, ki, causal, scale,
         valid &= km_ref[0] > 0                            # [1, bk] broadcast
     if causal:
         valid &= qpos >= kpos
+        if window is not None:
+            valid &= qpos - kpos < window
     return jnp.where(valid, p, 0.0), k, valid
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                     causal, scale, block_q, block_k, seq_q, seq_k, has_kmask):
+                     causal, scale, block_q, block_k, seq_q, seq_k, has_kmask,
+                     window=None):
     if has_kmask:
         km_ref, dq_ref, dq_scr = rest
     else:
         km_ref = None
         dq_ref, dq_scr = rest
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    if window is None:
+        ki = step
+        visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    else:
+        first, last = _band_blocks(qi, block_q, block_k, pl.cdiv(seq_k, block_k),
+                                   window - 1, 0)
+        ki = first + step
+        visible = ki <= last
 
     @pl.when(visible)
     def _body():
         p, k, valid = _recompute_p(q_ref, k_ref, lse_ref, km_ref, qi=qi, ki=ki,
                                    causal=causal, scale=scale, block_q=block_q,
-                                   block_k=block_k, seq_q=seq_q, seq_k=seq_k)
+                                   block_k=block_k, seq_q=seq_q, seq_k=seq_k,
+                                   window=window)
         do = do_ref[0]
         v = v_ref[0]
         vrow = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
@@ -253,14 +326,14 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                       causal, scale, block_q, block_k, seq_q, seq_k,
-                      has_kmask, with_dq):
+                      has_kmask, with_dq, window=None):
     """dk and dv of one k-block, summed over its visible q-blocks; with
     ``with_dq`` dq too, from the same tiles: ``dq_ref`` is then the head's
     whole [Tq, D] float32, resident across every (ki, qi), and each tile adds
@@ -270,30 +343,47 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     dq_ref = rest.pop(0) if with_dq else None
     dk_ref, dv_ref, dk_scr, dv_scr = rest
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
+    if window is None:
+        # k-block 0 comes first for every q-block, and no mask hides it whole
+        qi, first_sight = step, ki == 0
+    else:
+        # the inner axis runs over the q-blocks the k-block's band reaches; a
+        # step past the band stays on its last block and works nothing
+        first, last = _band_blocks(ki, block_k, block_q, pl.cdiv(seq_q, block_q),
+                                   0, window - 1)
+        in_band = first + step <= last
+        qi = jnp.minimum(first + step, last)
+        # the first k-block whose band reaches this q-block
+        first_sight = in_band & (ki == _band_blocks(
+            qi, block_q, block_k, pl.cdiv(seq_k, block_k), window - 1, 0)[0])
+
     if with_dq:
         rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-        # k-block 0 comes first for every q-block, and no mask hides it whole
-        @pl.when(ki == 0)
+        @pl.when(first_sight)
         def _init_dq():
             dq_ref[0, rows, :] = jnp.zeros((block_q, dq_ref.shape[-1]),
                                            dq_ref.dtype)
 
-    visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    if window is not None:
+        visible = in_band
+    else:
+        visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
 
     @pl.when(visible)
     def _body():
         p, k, valid = _recompute_p(q_ref, k_ref, lse_ref, km_ref, qi=qi, ki=ki,
                                    causal=causal, scale=scale, block_q=block_q,
-                                   block_k=block_k, seq_q=seq_q, seq_k=seq_k)
+                                   block_k=block_k, seq_q=seq_q, seq_k=seq_k,
+                                   window=window)
         q = q_ref[0]
         qrow = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
         q = jnp.where(qrow < seq_q, q, jnp.zeros((), q.dtype))
@@ -318,14 +408,14 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
-                       interpret, kmask=None, vma=None):
+                       interpret, kmask=None, vma=None, window=None):
     """The backward at ``tiles`` (a ``BwdTiles``). Fused: one call on the
     dk/dv kernel's grid, every visible tile worked once. Else a dq call
     (q-blocks outer) and a dk/dv call (k-blocks outer), each working every
@@ -333,17 +423,33 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
     whose whole dq does not fit beside the tiles, and the reference the
     fused call is held to, bit for bit."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk = k.shape[1], k.shape[2]
     bq = min(tiles.block_q, Tq)
     bk = min(tiles.block_k, Tk)
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
-    operands = [q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
-                v.reshape(B * H, Tk, D), do.reshape(B * H, Tq, D),
+    kv_row = _kv_row(H // Hkv)
+    operands = [q.reshape(B * H, Tq, D), k.reshape(B * Hkv, Tk, D),
+                v.reshape(B * Hkv, Tk, D), do.reshape(B * H, Tq, D),
                 lse.reshape(B * H, Tq, 1), delta.reshape(B * H, Tq, 1)]
     if kmask is not None:
         operands.append(kmask.astype(jnp.float32).reshape(B, 1, Tk))
     kernel_kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
-                     seq_q=Tq, seq_k=Tk, has_kmask=kmask is not None)
+                     seq_q=Tq, seq_k=Tk, has_kmask=kmask is not None,
+                     window=window)
+    if window is None:
+        q_steps, k_steps = nq, nk
+        q_block = k_block = lambda outer, step: step
+    else:
+        q_band, k_band = (bk, bq, nq, 0, window - 1), (bq, bk, nk, window - 1, 0)
+        q_steps, k_steps = _band_steps(nk, *q_band), _band_steps(nq, *k_band)
+
+        def q_block(j, step):       # the q-blocks of k-block j's band, clamped
+            first, last = _band_blocks(j, *q_band)
+            return jnp.minimum(first + step, last)
+
+        def k_block(i, step):       # the k-blocks of q-block i's band, clamped
+            first, last = _band_blocks(i, *k_band)
+            return jnp.minimum(first + step, last)
 
     def float32(rows):
         return _sds((B * H, rows, D), jnp.float32, vma)
@@ -355,19 +461,23 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
     # by what XLA's memory-space assignment did around it (alone at
     # T >= 8,192 it never did). The second buffer bought overlap once per
     # k-block only.
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0),
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, q_block(j, i), 0),
                           memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (kv_row(b), j, 0),
                           memory_space=pltpu.VMEM,
                           pipeline_mode=pl.Buffered(1))
-    row_spec = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
+    # dk and dv of one query head: the group's are summed below
+    dk_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
+                           memory_space=pltpu.VMEM,
+                           pipeline_mode=pl.Buffered(1))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, q_block(j, i), 0),
                             memory_space=pltpu.VMEM)
     in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
     if kmask is not None:
         in_specs.append(pl.BlockSpec((1, 1, bk),
                                      lambda b, j, i: (b // H, 0, j),
                                      memory_space=pltpu.VMEM))
-    out_shape, out_specs = [float32(Tk)] * 2, [k_spec] * 2
+    out_shape, out_specs = [float32(Tk)] * 2, [dk_spec] * 2
     if tiles.fused:
         # a head's whole dq, in whole q-blocks of rows so that the last
         # block's slice stays inside: a ragged tail's rows receive zeros (ds
@@ -381,7 +491,7 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
         functools.partial(_flash_dkv_kernel, with_dq=tiles.fused, **kernel_kw),
         name="flash_attention_bwd" if tiles.fused else "flash_attention_bwd_dkv",
         out_shape=out_shape,
-        grid=(B * H, nk, nq),
+        grid=(B * H, nk, q_steps),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
@@ -396,31 +506,35 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
     else:
         q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
                               memory_space=pltpu.VMEM)
-        k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0),
+        k_spec = pl.BlockSpec((1, bk, D),
+                              lambda b, i, j: (kv_row(b), k_block(i, j), 0),
                               memory_space=pltpu.VMEM)
         row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
                                 memory_space=pltpu.VMEM)
         in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
         if kmask is not None:
             in_specs.append(pl.BlockSpec((1, 1, bk),
-                                         lambda b, i, j: (b // H, 0, j),
+                                         lambda b, i, j: (b // H, 0, k_block(i, j)),
                                          memory_space=pltpu.VMEM))
         dq = pl.pallas_call(
             functools.partial(_flash_dq_kernel, **kernel_kw),
             name="flash_attention_bwd_dq",
             out_shape=float32(Tq),
-            grid=(B * H, nq, nk),
+            grid=(B * H, nq, k_steps),
             in_specs=in_specs,
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interpret,
         )(*operands)
-    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D))
+    if Hkv != H:
+        # a key-value head's gradient is the sum over its group of query heads
+        dk, dv = (t.reshape(B, Hkv, H // Hkv, Tk, D).sum(axis=2) for t in (dk, dv))
+    return (dq.reshape(B, H, Tq, D), dk.reshape(B, Hkv, Tk, D),
+            dv.reshape(B, Hkv, Tk, D))
 
 
 def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
-                    block_k, interpret, kmask=None, vma=None):
+                    block_k, interpret, kmask=None, vma=None, window=None):
     """O(T*D)-memory flash backward. lse/delta: [B,H,Tq,1] float32.
 
     ``block_q`` / ``block_k`` are the least tiles the caller wants;
@@ -430,7 +544,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
                       q.dtype.itemsize)
     return _flash_backward_at(tiles, q, k, v, do, lse, delta, causal=causal,
                               scale=scale, interpret=interpret, kmask=kmask,
-                              vma=vma)
+                              vma=vma, window=window)
 
 
 # --------------------------------------------------------------------------
@@ -460,15 +574,16 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, causal, scale,
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, kmask, causal, scale, block_q, block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, kmask, causal, scale, block_q, block_k, window):
     out, _ = _flash_forward(q, k, v, causal=causal, scale=scale,
                             block_q=block_q, block_k=block_k,
-                            interpret=interpret_mode(), kmask=kmask)
+                            interpret=interpret_mode(), kmask=kmask,
+                            window=window)
     return out
 
 
-def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k):
+def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k, window):
     """The forward under differentiation: ``out`` and the residuals the
     backward kernels read. ``out`` and ``lse`` carry names, so a
     ``jax.checkpoint`` whose policy keeps them (``checkpoint_layer``) stores
@@ -478,7 +593,8 @@ def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k):
     identity and lowers to nothing."""
     out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
-                              interpret=interpret_mode(), kmask=kmask)
+                              interpret=interpret_mode(), kmask=kmask,
+                              window=window)
     # the named ``out`` is the primal result too: whatever reads it downstream
     # (the output projection's weight gradient) then reads the kept value
     out, lse = checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
@@ -552,7 +668,7 @@ def bwd_tiles(block_q, block_k, head_dim, seq_q, seq_k, itemsize):
     return BwdTiles(min(bq, seq_q), min(bk, seq_k), False)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
     # flash backward: only [bq, bk] probability tiles are ever materialized,
     # recomputed from the saved logsumexp — HBM stays O(T*D), which is what
     # makes long-context *training* (not just inference) sub-quadratic
@@ -562,7 +678,7 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
     dq, dk, dv = _flash_backward(q, k, v, g, lse, delta, causal=causal,
                                  scale=scale, block_q=block_q,
                                  block_k=block_k, interpret=interpret_mode(),
-                                 kmask=kmask)
+                                 kmask=kmask, window=window)
     dkm = None if kmask is None else jnp.zeros_like(kmask)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dkm
 
@@ -605,8 +721,13 @@ def _is_key_padding(mask, q, k):
 
 
 def flash_attention(q, k, v, *, mask=None, bias=None, scale=None,
-                    causal=False, block_q: int = 512, block_k: int = 1024):
+                    causal=False, window=None, block_q: int = 512,
+                    block_k: int = 1024):
     """Public entry: same signature as the XLA dot_product_attention.
+
+    ``k`` / ``v`` may hold fewer heads than ``q`` (a divisor of its count):
+    query head h reads key-value head ``h // (H // Hkv)``. ``window`` (with
+    ``causal``): query i sees keys j with ``0 <= i - j < window``.
 
     Default tiles are the v5e sweet spot measured at T=8192 (fwd 512x1024;
     the backward's come from ``bwd_tiles``): small 128-tiles leave >2x on the
@@ -624,21 +745,30 @@ def flash_attention(q, k, v, *, mask=None, bias=None, scale=None,
     km = _as_key_padding(mask, q.shape[0], k.shape[-2])
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    return _flash(q, k, v, km, causal, float(scale), block_q, block_k)
+    if window is not None and not causal:
+        raise ValueError("flash_attention's window is a causal one: pass causal=True")
+    return _flash(q, k, v, km, causal, float(scale), block_q, block_k,
+                  None if window is None else int(window))
 
 
-def _flash_requires(q, k, v, *, mask=None, scale=None, causal=False, **kw):
+def _flash_requires(q, k, v, *, mask=None, scale=None, causal=False,
+                    window=None, **kw):
     # structural: masks are supported iff they reduce to a key-padding mask
     # over Tk; the kernel's causal mask is start-aligned (query i sees keys
     # <= i) which only matches the XLA lowering's end-aligned tril when
     # Tq == Tk. Additive logit biases (the import optimizer's fused
     # exporter-mask form) are not expressible in the kernel — XLA lowering.
+    # A window is a band under the causal diagonal; k and v may hold a
+    # divisor of q's heads.
     return (kw.get("bias") is None
             and _is_key_padding(mask, q, k)
-            and (not causal or q.shape[-2] == k.shape[-2]))
+            and (not causal or q.shape[-2] == k.shape[-2])
+            and (window is None or causal)
+            and q.shape[1] % k.shape[1] == 0 and k.shape[1] == v.shape[1])
 
 
-def _flash_applicable(q, k, v, *, mask=None, scale=None, causal=False, **kw):
+def _flash_applicable(q, k, v, *, mask=None, scale=None, causal=False,
+                      window=None, **kw):
     # perf heuristic: long-sequence, lane/block-aligned shapes. head_dim 64
     # (the BERT-class geometry) runs natively: the QK^T contraction fills
     # half the MXU's K dimension but the kernel's win is HBM traffic, and

@@ -23,11 +23,12 @@ from deeplearning4j_tpu.zoo.nasnet import NASNet
 from deeplearning4j_tpu.zoo.textgen import TextGenerationLSTM, BidirectionalGravesLSTMCharRnn
 from deeplearning4j_tpu.zoo.bert import Bert, BertBase
 from deeplearning4j_tpu.zoo.ouro import Ouro
+from deeplearning4j_tpu.zoo.mellum2 import Mellum2
 
 __all__ = [
     "ZooModel", "LeNet", "AlexNet", "SimpleCNN", "VGG16", "VGG19", "ResNet50",
     "Darknet19", "TinyYOLO", "YOLO2", "SqueezeNet", "Xception", "UNet",
     "InceptionResNetV1", "NASNet",
     "TextGenerationLSTM", "BidirectionalGravesLSTMCharRnn", "Bert", "BertBase",
-    "Ouro",
+    "Ouro", "Mellum2",
 ]

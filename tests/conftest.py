@@ -93,12 +93,20 @@ SCOPED_METRIC_CASES = {
     "loop_exit_ms": "test_ouro_cell.py",
     "attention_kernel_ms": "test_ouro_cell.py",
     "attention_kernel_roofline": "test_ouro_cell.py",
+    "moe_ms": "test_mellum2_cell.py",
+    "moe_route_ms": "test_mellum2_cell.py",
+    "expert_matmul_roofline": "test_mellum2_cell.py",
+    "moe_load_max_over_mean": "test_mellum2_cell.py",
+    "banded_attention_kernel_ms": "test_mellum2_cell.py",
+    "banded_attention_kernel_roofline": "test_mellum2_cell.py",
 }
 
 
 @pytest.fixture
-def scoped_metric_cases():
-    return dict(SCOPED_METRIC_CASES)
+def scoped_metric_cases(request):
+    """The metrics whose hand-made cases the asking file holds."""
+    here = request.module.__name__.rpartition(".")[2] + ".py"
+    return {metric: where for metric, where in SCOPED_METRIC_CASES.items() if where == here}
 
 
 @pytest.fixture(autouse=True)
@@ -127,4 +135,17 @@ def _metrics_that_list_another_cell(request, monkeypatch):
         monkeypatch.setitem(module.MANIFEST, "per_layer", [
             m for m in module.MANIFEST["per_layer"]
             if harness._reports(m, module.CELL, module.MANIFEST)])
+    elif name == "test_ouro_cell":
+        # it holds its cell, its configuration and its four metrics to be the
+        # manifest's last: its copy is cut back to the manifest as that PR left
+        # it (the cells up to its own, the metrics that list one of them)
+        cells = [w["name"] for w in module.MANIFEST["workloads"]]
+        cells = cells[:cells.index(module.CELL) + 1]
+        configs = [c["name"] for c in module.MANIFEST["configs"]]
+        monkeypatch.setitem(module.MANIFEST, "workloads", module.MANIFEST["workloads"][:len(cells)])
+        monkeypatch.setitem(module.MANIFEST, "configs",
+                            module.MANIFEST["configs"][:configs.index(module.CONFIG) + 1])
+        monkeypatch.setitem(module.MANIFEST, "per_layer", [
+            m for m in module.MANIFEST["per_layer"]
+            if "workloads" not in m or set(m["workloads"]) & set(cells)])
     yield

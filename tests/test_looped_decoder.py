@@ -508,3 +508,24 @@ def test_bert_bases_lowered_train_step_is_text_identical_to_the_parents():
         jax.random.key(0), None, None).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "9d0dfcd7984fbe9587789b2f2df5156d1335bfab7fb39a9f7bbde9b6e2ca3321")
+
+
+def test_the_looped_decoders_lowered_train_step_is_text_identical_to_the_parents():
+    """PR 34 gave ``_loss_terms`` a channel for a layer's own term of the score,
+    ``DecoderBlock`` grouped heads, a window, a second norm placement and an
+    expert MLP, and the attention ops a ``window``: none may change the step of
+    a model that uses none of them. The digest is the parent commit's (e480ce2),
+    from the same lines."""
+    from benchmark_tiny import tiny_cell
+    from benchmarks.drivers import fit
+    from deeplearning4j_tpu.nn.layers.base import layer_loss_terms
+
+    cell = tiny_cell("ouro_2p6b")
+    model = fit.build_model(cell.config)
+    x = jnp.zeros((cell.traffic["batch"], cell.traffic["seq"]), jnp.int32)
+    text = model._make_train_step().lower(
+        model.params, model.state, model.opt_state, jnp.asarray(0, jnp.int32), x, x,
+        jax.random.key(0), None, None).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "615b59a498f5cb3e5e78ce39780cf3127978d9dc5912a3ee65503d778d3cc500")
+    assert layer_loss_terms(model.state) == []

@@ -595,6 +595,115 @@ class TestFlashAttentionBackward:
         assert np.isfinite(np.asarray(g, np.float32)).all()
 
 
+def _band_reference(q, k, v, window, causal=True):
+    """Dense attention under an explicit band mask; k / v heads repeated over
+    their groups of query heads."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k) / np.sqrt(q.shape[-1])
+    i, j = jnp.arange(q.shape[2])[:, None], jnp.arange(k.shape[2])[None, :]
+    keep = j <= i if causal else jnp.ones((q.shape[2], k.shape[2]), bool)
+    if window is not None:
+        keep &= i - j < window
+    return jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(jnp.where(keep, s, -1e30), -1), v)
+
+
+class TestFlashWindowAndGroupedHeads:
+    """A causal window and key-value heads shared by a group of query heads,
+    in interpret mode against the dense band mask, forward and backward."""
+
+    @staticmethod
+    def qkv(rng, heads, kv_heads, T, D=64, B=2):
+        return (jnp.asarray(rng.standard_normal((B, h, T, D)), jnp.float32)
+                for h in (heads, kv_heads, kv_heads))
+
+    @pytest.mark.parametrize("T,heads,kv_heads,window,bq,bk", [
+        (256, 4, 2, 64, 64, 128),       # the window divides the tiles
+        (256, 4, 1, 100, 64, 64),       # it does not; one key-value head for all
+        (384, 2, 2, 37, 128, 64),       # narrower than a tile, equal heads
+        (320, 8, 2, 130, 64, 128),      # a ragged last k-block
+        (256, 2, 1, 1000, 64, 128),     # wider than the sequence: plain causal
+        (256, 4, 2, None, 64, 128),     # grouped heads alone
+    ])
+    def test_forward_and_backward_match_the_dense_band(self, rng, T, heads, kv_heads,
+                                                       window, bq, bk):
+        q, k, v = self.qkv(rng, heads, kv_heads, T)
+        got, got_grads = jax.value_and_grad(lambda *a: (flash_attention(
+            *a, causal=True, window=window, block_q=bq, block_k=bk) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+        want, want_grads = jax.value_and_grad(
+            lambda *a: (_band_reference(*a, window) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        for g, w in zip(got_grads, want_grads):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(g, w, atol=5e-5)
+
+    @pytest.mark.parametrize("window", [None, 48])
+    def test_xla_lowering_has_the_same_semantics(self, rng, window):
+        from deeplearning4j_tpu.ops.attention import dot_product_attention
+
+        q, k, v = self.qkv(rng, 4, 2, 96, D=16)
+        got, got_grads = jax.value_and_grad(lambda *a: (dot_product_attention(
+            *a, causal=True, window=window) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        want, want_grads = jax.value_and_grad(
+            lambda *a: (_band_reference(*a, window) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        for g, w in zip(got_grads, want_grads):
+            np.testing.assert_allclose(g, w, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [None, 100])
+    def test_fused_backward_equals_the_two_calls_bit_for_bit(self, rng, window):
+        q, k, v = self.qkv(rng, 4, 2, 256)
+        out, lse = flash_module._flash_forward(
+            q, k, v, causal=True, scale=0.125, block_q=64, block_k=128, interpret=True,
+            window=window)
+        do = jnp.asarray(rng.standard_normal(out.shape), jnp.float32)
+        delta = (do * out).sum(-1, keepdims=True)
+        fused, two = (flash_module._flash_backward_at(
+            flash_module.BwdTiles(64, 128, layout), q, k, v, do, lse, delta, causal=True,
+            scale=0.125, interpret=True, window=window) for layout in (True, False))
+        for a, b in zip(fused, two):
+            assert bool((a == b).all())
+
+    @pytest.mark.parametrize("causal,digest", [
+        (True, "15b2ccad5d0e862bc072d133dabb3732b2203770dddaf5aa90b82e9b73f2020c"),
+        (False, "ed228247cb400a1eac3f24cdf329312ef103350d4ed94b7a0147ba20067062db"),
+    ])
+    def test_no_window_and_equal_heads_give_the_parents_bits(self, causal, digest):
+        """``window=None`` with equal head counts is the kernel it was: output
+        and the three gradients of a seeded call, by the digest the parent
+        commit (e480ce2) gives for the same lines."""
+        import hashlib
+
+        rng = np.random.default_rng(7)
+        q, k, v = (jnp.asarray(rng.standard_normal((1, 2, 256, 64)), jnp.float32)
+                   for _ in range(3))
+        out, grads = jax.value_and_grad(lambda *a: (flash_attention(
+            *a, causal=causal, block_q=64, block_k=128) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        seen = hashlib.sha256()
+        for a in (out, *grads):
+            seen.update(np.asarray(a).tobytes())
+        assert seen.hexdigest() == digest
+
+    def test_band_steps_count_the_blocks_a_band_reaches(self):
+        """At the expert cell's shape (512 x 1024 tiles, window 1,024) a q-block
+        reaches 2 of the 8 k-blocks and a k-block 4 of the 16 q-blocks."""
+        assert flash_module._band_steps(16, 512, 1024, 8, 1023, 0) == 2
+        assert flash_module._band_steps(8, 1024, 512, 16, 0, 1023) == 4
+        assert flash_module._band_blocks(5, 512, 1024, 8, 1023, 0) == (1, 2)
+        assert flash_module._band_blocks(7, 1024, 512, 16, 0, 1023) == (14, 15)
+
+    def test_registry_predicates_take_a_window_and_groups(self, rng):
+        q, k, v = self.qkv(rng, 4, 2, 2048, D=64, B=1)
+        assert flash_module._flash_applicable(q, k, v, causal=True, window=1024)
+        assert flash_module._flash_requires(q, k, v, causal=True, window=1024)
+        assert not flash_module._flash_requires(q, k, v, causal=False, window=1024)
+        assert not flash_module._flash_requires(
+            jnp.concatenate([q, q[:, :1]], axis=1), k, v, causal=True)
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention(q, k, v, window=8)
+
+
 class TestFlashAttentionUnderCheckpoint:
     """``_flash_fwd`` names its output and log-sum-exp: a checkpoint whose
     policy keeps the two names holds the T-sized results and the backward
@@ -685,6 +794,31 @@ class TestFlashBackwardCompilesForTheV5e:
         assert chosen == tiles
         text = self.compiled(one_chip, shape, chosen)
         assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+    @pytest.mark.parametrize("window", [None, 1024])
+    def test_grouped_heads_and_a_window_compile_at_the_expert_cells_shape(
+            self, one_chip, window):
+        """(1, 32 query heads over 4 key-value heads, 8,192, 128): forward and
+        the fused backward, with the band and without; dk and dv leave the call
+        a query head each and are summed over the group by XLA."""
+        B, H, Hkv, T, D = 1, 32, 4, 8192, 128
+        tiles = flash_module.bwd_tiles(512, 1024, D, T, T, 2)
+        assert tiles == (512, 1024, True)
+
+        def sds(heads, last, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct((B, heads, T, last), dtype, sharding=one_chip)
+
+        q, kv, row = sds(H, D), sds(Hkv, D), sds(H, 1, jnp.float32)
+        backward = jax.jit(functools.partial(
+            flash_module._flash_backward_at, tiles, causal=True, scale=D ** -0.5,
+            interpret=False, window=window)).lower(q, kv, kv, q, row, row).compile()
+        assert backward.as_text().count('custom_call_target="tpu_custom_call"') == 1
+        assert [o.shape for o in jax.tree.leaves(backward.out_info)] == [
+            (B, H, T, D), (B, Hkv, T, D), (B, Hkv, T, D)]
+        forward = jax.jit(functools.partial(
+            flash_module._flash_forward, causal=True, scale=D ** -0.5, block_q=512,
+            block_k=1024, interpret=False, window=window)).lower(q, kv, kv).compile()
+        assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
     def test_the_estimate_stands_just_over_the_compilers_figure(self, one_chip):
         """At (1024, 1024) the fused call does not fit beside the cell's dq:
