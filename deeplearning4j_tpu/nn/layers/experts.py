@@ -17,8 +17,15 @@ is one grouped matrix product over the sorted rows (``op("grouped_matmul")``),
 whose groups are the experts' pair counts of this very step. The buffer of
 sorted rows holds all ``tokens x top_k`` pairs, the one static bound there is
 when no pair may be dropped; rows past the held pairs are worked by no product
-and masked out of every sum. Dispatch and combine are permutations and are
-differentiated as such: gathers both ways, never a scatter-add.
+and reach no sum. Dispatch and combine are permutations and are differentiated
+as such: gathers both ways, never a scatter-add, each a row-gather op of the
+registry (``ops/rows.py``: ``gather_rows``, ``gather_sum_rows``,
+``gather_rows_dot``) that takes the count of held pairs, a value of the step,
+beside its indices. Where the shapes tile, the Pallas kernels of
+``ops/pallas/row_gather.py`` move the held pairs' rows alone, with the select,
+the weight and the sum over a token's slots inside the move; elsewhere (a CPU,
+a ragged shape) XLA gathers all ``tokens x top_k`` rows and runs those as
+passes of their own.
 
 The layer hands back the load-balancing term of its router for the score
 (``base.LOSS_TERM`` in its new state): ``aux_coef * n_experts * sum_e f_e P_e``,
@@ -41,6 +48,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.layers.base import LOSS_TERM, Layer, register_layer
 from deeplearning4j_tpu.ops.registry import op
 import deeplearning4j_tpu.ops.grouped  # noqa: F401
+import deeplearning4j_tpu.ops.rows  # noqa: F401
 
 #: the layer's load gauges, in the order ``moe_stats`` holds them
 MOE_STATS = ("load_max_over_mean", "pairs_held", "tokens_unserved", "aux")
@@ -53,81 +61,73 @@ def route(probs, top_k: int, first: int, held: int):
     renormalised over the chosen and 0 where the expert is not held, ``order
     [pairs]``: the pair each sorted row takes, held experts' pairs first by
     expert, ``place [pairs]``: the sorted row of each pair, ``group_sizes
-    [held]``)."""
+    [held]``, ``sorted_weights [pairs]``: each sorted row's pair's weight, a
+    constant: the sort carries the weights along, where a gather of 65,536
+    scalars by ``order`` afterwards took XLA 0.6 ms on the v5e)."""
     top_p, chosen = jax.lax.top_k(probs, top_k)
     weights = top_p / top_p.sum(axis=-1, keepdims=True)
     local = chosen - first
     is_held = (local >= 0) & (local < held)
+    weights = jnp.where(is_held, weights, 0.0)
     key = jnp.where(is_held, local, held).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    place = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=jnp.int32), unique_indices=True)
+    pairs = jnp.arange(key.shape[0], dtype=jnp.int32)
+    _, order, sorted_weights = jax.lax.sort(
+        (key, pairs, jax.lax.stop_gradient(weights).reshape(-1)), num_keys=1, is_stable=True)
+    place = jnp.zeros_like(order).at[order].set(pairs, unique_indices=True)
     group_sizes = (key[:, None] == jnp.arange(held, dtype=key.dtype)).sum(
         axis=0, dtype=jnp.int32)
-    return chosen, jnp.where(is_held, weights, 0.0), order, place, group_sizes
-
-
-def _gather_rows(table, index):
-    """``table[index]`` as an operation of its own: fused with the select before
-    it and the product after it, XLA's gather of 65,536 rows of 2,304 bfloat16
-    took 5.4 ms on the v5e where the bare one takes 2.4-3.5 (PERF.md, PR 34);
-    the barriers keep producers and consumers out of it."""
-    table, index = jax.lax.optimization_barrier((table, index))
-    return jax.lax.optimization_barrier(table[index])
-
-
-def _pairs_rows(rows, place, n_held, top_k):
-    """``[tokens, top_k, features]``: each pair's sorted row, zeros for a pair
-    whose expert is not held (a select on the gathered rows, so that whatever
-    a product left in the rows past the held pairs, NaN included, goes)."""
-    picked = _gather_rows(rows, place).reshape(-1, top_k, rows.shape[-1])
-    held = (place < n_held).reshape(-1, top_k, 1)
-    return jnp.where(held, picked, jnp.zeros((), rows.dtype))
+    return chosen, weights, order, place, group_sizes, sorted_weights
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def dispatch(xt, order, place, n_held, top_k):
     """The tokens' rows in sorted pair order: ``rows[r] = xt[order[r] //
-    top_k]``. Backward: each token sums the gradients of its ``top_k`` rows
+    top_k]`` for the ``n_held`` rows that are a held pair's (the others:
+    unspecified), handed out twice, once to each of the two products that read
+    them: their two gradients then come back apart and are added where they are
+    gathered, over the held rows, and not by a pass over all ``tokens x
+    top_k``. Backward: each token sums the gradients of its ``top_k`` rows
     (those of held pairs), found by ``place``: a gather."""
     return _dispatch_fwd(xt, order, place, n_held, top_k)[0]
 
 
 def _dispatch_fwd(xt, order, place, n_held, top_k):
-    return _gather_rows(xt, order // top_k), (place, n_held)
+    rows = op("gather_rows")(xt, order // top_k, n_held)
+    return (rows, rows), (place, n_held)
 
 
-def _dispatch_bwd(top_k, res, g):
+def _dispatch_bwd(top_k, res, gs):
     place, n_held = res
-    picked = _pairs_rows(g, place, n_held, top_k)
-    return picked.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None, None
+    d_xt = op("gather_sum_rows")(gs[0], place.reshape(-1, top_k), n_held, None, gs[1])
+    return d_xt, None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def combine(rows, weights, order, place, n_held):
+def combine(rows, weights, sorted_weights, order, place, n_held):
     """``y[token] = sum_slot weights[token, slot] * rows[place[token, slot]]``
-    over the held pairs, summed in float32. Backward: a row's gradient is its
-    pair's weight times its token's, found by ``order``: a gather."""
-    return _combine_fwd(rows, weights, order, place, n_held)[0]
+    over the held pairs, summed in float32. Backward, in sorted order: a
+    row's gradient is its pair's weight (``sorted_weights``, ``route``'s) times
+    its token's, found by ``order``, and from the same gathered rows a pair's
+    weight gets ``rows[r] . g[token]``: one gather for both, and none of
+    ``rows``."""
+    return _combine_fwd(rows, weights, sorted_weights, order, place, n_held)[0]
 
 
-def _combine_fwd(rows, weights, order, place, n_held):
-    y = (_pairs_rows(rows, place, n_held, weights.shape[1]).astype(jnp.float32)
-         * weights[..., None]).sum(axis=1).astype(rows.dtype)
-    return y, (rows, weights, order, place, n_held)
+def _combine_fwd(rows, weights, sorted_weights, order, place, n_held):
+    y = op("gather_sum_rows")(rows, place.reshape(weights.shape), n_held, weights)
+    return y, (rows, weights, sorted_weights, order, place, n_held)
 
 
 def _combine_bwd(res, g):
-    rows, weights, order, place, n_held = res
-    top_k = weights.shape[1]
-    d_rows = (_gather_rows(g, order // top_k).astype(jnp.float32)
-              * weights.reshape(-1)[order][:, None]).astype(rows.dtype)
-    d_weights = (_pairs_rows(rows, place, n_held, top_k).astype(jnp.float32)
-                 * g[:, None, :].astype(jnp.float32)).sum(axis=-1).astype(weights.dtype)
-    return d_rows, d_weights, None, None, None
+    rows, weights, sorted_weights, order, place, n_held = res
+    d_rows, d_sorted = op("gather_rows_dot")(g, order // weights.shape[1], n_held,
+                                             sorted_weights, rows)
+    d_weights = jnp.where(place < n_held, d_sorted[place], 0.0)
+    return (d_rows, d_weights.reshape(weights.shape).astype(weights.dtype),
+            None, None, None, None)
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -170,20 +170,23 @@ class SparseExpertsLayer(Layer):
                              precision=jax.lax.Precision.HIGHEST)
             probs = jax.nn.softmax(logits, axis=-1)
         with jax.named_scope("route"):
-            chosen, weights, order, place, group_sizes = route(probs, self.top_k, first, held)
+            chosen, weights, order, place, group_sizes, sorted_weights = route(
+                probs, self.top_k, first, held)
             n_held = group_sizes.sum()
-            share = jnp.zeros((self.n_experts,), jnp.float32).at[chosen.reshape(-1)].add(
-                1.0 / tokens)
+            # a count by comparison: as a scatter-add of 65,536 scalars it took 0.57 ms
+            share = (chosen.reshape(-1, 1) == jnp.arange(self.n_experts)).sum(
+                axis=0, dtype=jnp.float32) / tokens
             aux = self.n_experts * (share * probs.mean(axis=0)).sum()
         with jax.named_scope("dispatch"):
-            rows = dispatch(xt, order, place, n_held, self.top_k)
+            gate_rows, up_rows = dispatch(xt, order, place, n_held, self.top_k)
         with jax.named_scope("expert_matmul"):
             grouped = op("grouped_matmul")
-            hidden = (jax.nn.silu(grouped(rows, params["Wg"], group_sizes))
-                      * grouped(rows, params["Wu"], group_sizes))
+            hidden = (jax.nn.silu(grouped(gate_rows, params["Wg"], group_sizes))
+                      * grouped(up_rows, params["Wu"], group_sizes))
             out_rows = grouped(hidden, params["Wd"], group_sizes)
         with jax.named_scope("combine"):
-            y = combine(out_rows, weights.astype(jnp.float32), order, place, n_held)
+            y = combine(out_rows, weights.astype(jnp.float32), sorted_weights, order, place,
+                        n_held)
         # a row's score is the sum over its positions: the term counts once a position
         positions = x.shape[1] if x.ndim == 3 else 1
         largest, pairs = group_sizes.max().astype(jnp.float32), n_held.astype(jnp.float32)

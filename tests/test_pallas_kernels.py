@@ -820,6 +820,74 @@ class TestFlashBackwardCompilesForTheV5e:
             block_k=1024, interpret=False, window=window)).lower(q, kv, kv).compile()
         assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
+    @pytest.mark.parametrize("kernel,calls", [
+        ("pack_rows", 1), ("gather_rows", 2), ("gather_rows_dot", 2), ("gather_sum_rows", 2)])
+    def test_row_gather_kernels_compile_at_the_expert_cells_shape(self, one_chip, kernel, calls):
+        """8,192 tokens of 2,304 bfloat16, top-8: 65,536 sorted rows. Each gather
+        is its kernel and the packing of its table; the indices (256 KB, with
+        the weights 512) lie in SMEM beside a tile's slabs in VMEM."""
+        from deeplearning4j_tpu.ops.pallas import row_gather
+
+        T, K, D = 8192, 8, 2304
+
+        def sds(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        table, rows, n = sds((T, D)), sds((T * K, D)), sds((), jnp.int32)
+        index, place = sds((T * K,), jnp.int32), sds((T, K), jnp.int32)
+        scale, weights = sds((T * K,), jnp.float32), sds((T, K), jnp.float32)
+        args = {"pack_rows": (rows, n), "gather_rows": (table, index, n),
+                "gather_rows_dot": (table, index, n, scale, rows),
+                "gather_sum_rows": (rows, place, n, weights)}[kernel]
+        text = jax.jit(functools.partial(getattr(row_gather, kernel), interpret=False)).lower(
+            *args).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == calls
+
+    def test_the_expert_layers_step_moves_no_pair_rows_but_by_its_kernels(self, one_chip,
+                                                                         monkeypatch):
+        """The layer's forward and gradient at the cell's shape, compiled for the
+        chip: nine kernel calls move rows (four packings, the gather, the gather
+        with the weights' products, two sums), XLA gathers scalars only, and
+        neither combine's forward nor dispatch's backward has an array of
+        ``tokens x top_k`` rows of features."""
+        from deeplearning4j_tpu.nn.conf.inputs import InputType
+        from deeplearning4j_tpu.nn.layers import SparseExpertsLayer
+        from deeplearning4j_tpu.ops.pallas import grouped_matmul, row_gather
+
+        for module in (row_gather, grouped_matmul):     # the CPU is the default backend here
+            monkeypatch.setattr(module, "interpret_mode", lambda: False)
+        T, D = 8192, 2304
+        layer = SparseExpertsLayer(n_experts=64, top_k=8, d_expert=896, experts_held=(0, 16))
+        params, state = jax.eval_shape(
+            lambda: layer.init(jax.random.key(0), InputType.recurrent(D, T)))
+
+        def on_chip(leaf, dtype=None):
+            return jax.ShapeDtypeStruct(leaf.shape, dtype or leaf.dtype, sharding=one_chip)
+
+        params = {k: on_chip(v, jnp.float32 if k == "Wr" else jnp.bfloat16)
+                  for k, v in params.items()}
+        x = jax.ShapeDtypeStruct((1, T, D), jnp.bfloat16, sharding=one_chip)
+        text = jax.jit(jax.grad(
+            lambda p, x: (layer.apply(p, jax.tree.map(jnp.zeros_like, state), x)[0]
+                          .astype(jnp.float32) ** 2).sum(), argnums=(0, 1))).lower(
+                params, x).compile().as_text()
+        lines = text.splitlines()
+        calls = [name for line in lines if 'custom_call_target="tpu_custom_call"' in line
+                 for name in re.findall(r'/(\w+)/pallas_call"', line)]     # not the products' jit(gmm)
+        assert sorted(calls) == sorted(["pack_rows"] * 4 + ["gather_rows", "gather_rows_dot"]
+                                       + ["gather_sum_rows"] * 2)
+        pair_rows = re.compile(r"= \(?(bf16|f32)\[(65536,2304|8192,8,2304)\]")
+        for line in lines:
+            name = re.search(r'op_name="([^"]*)"', line)
+            if name is None:
+                continue
+            if re.search(r" gather\(", line):
+                assert "2304" not in line.split("metadata")[0], line      # scalars only
+            forward = "transpose(" not in name.group(1)
+            if (re.search(r"[(/]combine[)/]", name.group(1)) and forward) or (
+                    re.search(r"[(/]dispatch[)/]", name.group(1)) and not forward):
+                assert not pair_rows.search(line), line
+
     def test_the_estimate_stands_just_over_the_compilers_figure(self, one_chip):
         """At (1024, 1024) the fused call does not fit beside the cell's dq:
         the compiler names its figure, and ``bwd_vmem_bytes`` is to count

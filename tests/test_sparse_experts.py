@@ -65,11 +65,33 @@ def _highest():
 
 
 # ------------------------------------------------------------------- the layer
-@pytest.mark.parametrize("held", [None, (2, 2), (0, 1), (6, 2)], ids=str)
-def test_the_layer_matches_the_reference_forward_and_gradient(held):
+#: a size at which the row-gather kernels' shape contracts hold (256 tokens of
+#: 128 float32, 512 pairs), so the registry takes them (interpreted here)
+TILED = (2, 128, 128)
+
+
+def platforms(layer, x):
+    """The implementation the registry takes for each of the layer's row ops."""
+    tokens, d = x.shape[0] * x.shape[1], x.shape[-1]
+    pairs = tokens * layer.top_k
+    xt, rows = jnp.zeros((tokens, d), x.dtype), jnp.zeros((pairs, d), x.dtype)
+    index, place = jnp.zeros((pairs,), jnp.int32), jnp.zeros((tokens, layer.top_k), jnp.int32)
+    n, scale = jnp.int32(0), jnp.zeros((pairs,))
+    return {experts_module.op("gather_rows").select(xt, index, n).platform,
+            experts_module.op("gather_sum_rows").select(rows, place, n).platform,
+            experts_module.op("gather_rows_dot").select(xt, index, n, scale, rows).platform}
+
+
+@pytest.mark.parametrize("held,size", [
+    (None, None), ((2, 2), None), ((0, 1), None), ((6, 2), None), (None, TILED), ((2, 2), TILED)],
+    ids=str)
+def test_the_layer_matches_the_reference_forward_and_gradient(held, size):
+    B, T, D = size or (3, 10, 32)
+    ITYPE = InputType.recurrent(D, T)
     layer = SparseExpertsLayer(n_experts=E, top_k=K, d_expert=F, experts_held=held, aux_coef=0.01)
     params, _ = layer.init(jax.random.key(1), ITYPE)
     x = jax.random.normal(jax.random.key(2), (B, T, D))
+    assert platforms(layer, x) == ({"pallas"} if size else {"xla"})
 
     def program(params, x):
         y, state = layer.apply(params, {}, x)
@@ -83,10 +105,12 @@ def test_the_layer_matches_the_reference_forward_and_gradient(held):
     want, want_grads = jax.value_and_grad(plain, argnums=(0, 1))(params, x)
     np.testing.assert_allclose(got, want, rtol=1e-6)
     for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
-        np.testing.assert_allclose(g, w, atol=2e-5)
-    (_, term, stats), (_, want_term, want_stats) = layer_and_reference(layer, params, x)
-    np.testing.assert_allclose(term, want_term, rtol=1e-6)
-    np.testing.assert_allclose(stats, want_stats, rtol=1e-6)
+        # of the leaf's largest where that passes 1: at D 128 the router's gradient reaches 300
+        np.testing.assert_allclose(g, w, atol=2e-5 * max(1.0, float(jnp.abs(w).max())))
+    y, state = layer.apply(params, layer.init(jax.random.key(0), ITYPE)[1], x)
+    _, aux, load = reference.experts(x.reshape(-1, D), params, cfg_for(layer), lambda f: f)
+    np.testing.assert_allclose(state[LOSS_TERM], layer.aux_coef * T * aux, rtol=1e-6)
+    np.testing.assert_allclose(state["moe_stats"], load, rtol=1e-6)
 
 
 @pytest.mark.parametrize("top_k", [1, 4])
@@ -103,19 +127,23 @@ def test_every_token_on_one_expert_drops_nothing_and_pads_nothing(top_k):
     np.testing.assert_allclose(y, want_y, atol=2e-6)
     assert stats[1] == B * T * top_k and stats[2] == 0          # pairs held, tokens unserved
     assert stats[0] == pytest.approx(E / top_k)                  # the largest group over the mean
-    chosen, _, order, place, sizes = experts_module.route(
+    chosen, weights, order, place, sizes, sorted_weights = experts_module.route(
         jax.nn.softmax(x.reshape(-1, D) @ params["Wr"]), top_k, 0, E)
     assert sizes.tolist() == [B * T] * top_k + [0] * (E - top_k)
     assert sorted(order.tolist()) == list(range(B * T * top_k))
     assert (order[place] == jnp.arange(B * T * top_k)).all()
+    assert (sorted_weights == weights.reshape(-1)[order]).all()
 
 
-def test_rows_past_the_held_pairs_never_reach_a_sum():
+@pytest.mark.parametrize("size", [None, TILED], ids=["plain", "kernels"])
+def test_rows_past_the_held_pairs_never_reach_a_sum(size):
     """What a grouped product leaves in the rows no group holds is unspecified:
     with NaN there, the result and every gradient stay finite and unchanged."""
+    B, T, D = size or (3, 10, 32)
     layer = SparseExpertsLayer(n_experts=E, top_k=K, d_expert=F, experts_held=(2, 2))
-    params, _ = layer.init(jax.random.key(5), ITYPE)
+    params, _ = layer.init(jax.random.key(5), InputType.recurrent(D, T))
     x = jax.random.normal(jax.random.key(6), (B, T, D))
+    assert platforms(layer, x) == ({"pallas"} if size else {"xla"})
 
     def poisoned(lhs, rhs, sizes):
         rows = jnp.arange(lhs.shape[0])[:, None]
@@ -199,9 +227,11 @@ def test_the_layer_round_trips_and_says_which_experts_it_holds():
     assert sorted(state) == [LOSS_TERM, "moe_stats"]
 
 
-def test_the_scopes_a_trace_reader_splits_the_layer_by():
+@pytest.mark.parametrize("size", [None, TILED], ids=["plain", "kernels"])
+def test_the_scopes_a_trace_reader_splits_the_layer_by(size):
+    B, T, D = size or (3, 10, 32)
     layer = SparseExpertsLayer(n_experts=E, top_k=K, d_expert=F)
-    params, state = layer.init(jax.random.key(0), ITYPE)
+    params, state = layer.init(jax.random.key(0), InputType.recurrent(D, T))
     x = jax.random.normal(jax.random.key(1), (B, T, D))
     text = jax.jit(jax.grad(lambda p, x: (layer.apply(p, state, x)[0] ** 2).sum(),
                             argnums=(0, 1))).lower(params, x).compile().as_text()
@@ -209,6 +239,106 @@ def test_the_scopes_a_trace_reader_splits_the_layer_by():
     for scope in ("router", "route", "dispatch", "expert_matmul", "combine"):
         inside = [n for n in names if re.search(rf"[(/]{scope}[)/]", n)]
         assert inside and any("transpose(" in n for n in inside), scope     # forward and backward
+    if size:        # the kernels' calls keep the scope they were written under
+        for scope, kernel in (("dispatch", "gather_rows"), ("dispatch", "gather_sum_rows"),
+                              ("combine", "gather_sum_rows"), ("combine", "gather_rows_dot")):
+            assert any(re.search(rf"[(/]{scope}\)*/{kernel}/", n) for n in names), (scope, kernel)
+
+
+# ----------------------------------------------------------------- the row ops
+ROW_T, ROW_K = 256, 4           # 1,024 pairs; the gather's tile is 256 rows, the sum's 32 tokens
+HELD = {"none": 0, "one_row": 1, "ragged": 300, "a_quarter": 256, "all": 1024}
+
+
+def row_case(dtype, d, n):
+    """A permutation of the pairs with, past the ``n`` counted rows, NaN in the
+    sorted rows and indices far out of range."""
+    pairs = ROW_T * ROW_K
+    keys = jax.random.split(jax.random.key(n + d), 6)
+    order = jax.random.permutation(keys[0], pairs).astype(jnp.int32)
+    place = jnp.zeros_like(order).at[order].set(jnp.arange(pairs, dtype=jnp.int32))
+    counted = jnp.arange(pairs) < n
+    return dict(
+        table=jax.random.normal(keys[1], (ROW_T, d)).astype(dtype),
+        rows=jnp.where(counted[:, None], jax.random.normal(keys[2], (pairs, d)), jnp.nan).astype(dtype),
+        index=jnp.where(counted, order // ROW_K, 2 ** 30), order=order,
+        place=place.reshape(ROW_T, ROW_K), scale=jax.random.normal(keys[3], (pairs,)),
+        weights=jax.random.normal(keys[4], (ROW_T, ROW_K)), n=jnp.int32(n), counted=counted)
+
+
+def both(name, *args):
+    """(the Pallas kernel's, the plain lowering's) result of op ``name``."""
+    chosen = experts_module.op(name)
+    assert chosen.select(*args).platform == "pallas"
+    return chosen(*args), chosen.xla.fn(*args)
+
+
+def counted_rows(c, *arrays):
+    return [np.asarray(jnp.where(c["counted"].reshape((-1,) + (1,) * (a.ndim - 1)), a, 0),
+                       np.float32) for a in arrays]
+
+
+@pytest.mark.parametrize("dtype,d", [(jnp.float32, 128), (jnp.bfloat16, 256)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("held", list(HELD))
+def test_gather_rows_moves_the_counted_rows_and_reads_no_index_past_them(held, dtype, d):
+    c = row_case(dtype, d, HELD[held])
+    for scale in (None, c["scale"]):
+        got, want = counted_rows(c, *both("gather_rows", c["table"], c["index"], c["n"], scale))
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,d", [(jnp.float32, 128), (jnp.bfloat16, 256)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("held", list(HELD))
+def test_gather_rows_dot_gives_both_results_from_one_gather(held, dtype, d):
+    c = row_case(dtype, d, HELD[held])
+    (got, got_dots), (want, want_dots) = both("gather_rows_dot", c["table"], c["index"], c["n"],
+                                             c["scale"], c["rows"])
+    np.testing.assert_allclose(*counted_rows(c, got, want), rtol=1e-6)
+    np.testing.assert_allclose(*counted_rows(c, got_dots, want_dots), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,d", [(jnp.float32, 128), (jnp.bfloat16, 256)], ids=["f32", "bf16"])
+@pytest.mark.parametrize("held", list(HELD))
+def test_gather_sum_rows_sums_the_held_slots_and_no_nan_past_them(held, dtype, d):
+    c = row_case(dtype, d, HELD[held])
+    tight = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)
+    more = jnp.where(c["counted"][:, None], 1 - 0.5 * jnp.nan_to_num(c["rows"]), jnp.nan).astype(dtype)
+    for weights, more in ((None, None), (c["weights"], None), (None, more)):
+        got, want = both("gather_sum_rows", c["rows"], c["place"], c["n"], weights, more)
+        assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **tight)
+    if HELD[held] == 0:
+        assert float(jnp.abs(got.astype(jnp.float32)).max()) == 0.0
+
+
+@pytest.mark.parametrize("held", list(HELD))
+def test_dispatch_and_combine_are_differentiated_by_the_kernels_as_by_the_plain_ops(
+        held, monkeypatch):
+    """Value and every gradient of the two permutations, kernels against plain
+    lowerings, on the rows that count (``d_rows`` past them is unspecified)."""
+    from deeplearning4j_tpu.common.env import env
+
+    c = row_case(jnp.float32, 128, HELD[held])
+    order, place = c["order"], c["place"].reshape(-1)
+    rows = jnp.nan_to_num(c["rows"])
+    g_rows = jax.random.normal(jax.random.key(7), rows.shape)
+    g_y = jax.random.normal(jax.random.key(8), c["table"].shape)
+
+    def run():
+        (sent, again), pull = jax.vjp(
+            lambda xt: experts_module.dispatch(xt, order, place, c["n"], ROW_K), c["table"])
+        assert again is sent
+        y, back = jax.vjp(lambda r, w: experts_module.combine(
+            r, w, c["weights"].reshape(-1)[order], order, place, c["n"]), rows, c["weights"])
+        d_rows, d_weights = back(g_y)
+        poisoned = jnp.where(c["counted"][:, None], g_rows, jnp.nan)
+        return counted_rows(c, sent, d_rows) + [pull((poisoned, poisoned[:, ::-1]))[0], y, d_weights]
+
+    got = run()
+    monkeypatch.setattr(env, "disable_pallas", True)
+    for g, w in zip(got, run()):
+        assert bool(np.isfinite(g).all())
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
 
 
 # ----------------------------------------------------- a loss term from a layer
