@@ -27,6 +27,15 @@ path records its spans and its histograms together; ``spans()`` reads them
 out (after ``disable()`` too). ``start_tracing()`` / ``stop_tracing(path)``
 bracket a stretch to save as a Chrome trace: a fresh ring in, the ring out
 and optionally written.
+
+One record is kept whatever the switch says: the **start-up record**
+(``startup()``), a ``SpanTracer`` of its own with its own cap. Building a
+model (``setup.init``) and building a program (``compile.trace``,
+``compile.lower``, ``compile``: monitoring/compile.py) land there. Each
+happens once per model or per program, never in a step that reuses a built
+program, and both happen before a job usually switches monitoring on — the
+only record of why a start was slow. ``spans()`` returns its spans with the
+ring's.
 """
 
 from __future__ import annotations
@@ -50,7 +59,14 @@ from deeplearning4j_tpu.monitoring.tracing import (
     Span, SpanTracer, validate_nesting,
 )
 
+#: events the start-up record holds; past it the oldest go, counted in its
+#: ``dropped``. The benchmark's largest cell (``kimi_linear_48b``) records 7,182
+#: in a whole run on the chip: 5,192 spans of set-up, the eager programs inside
+#: ``init()`` among them, and the reference's programs after the window
+STARTUP_MAX_EVENTS = 50_000
+
 _REGISTRY = MetricsRegistry()
+_startup = SpanTracer("deeplearning4j_tpu start-up", STARTUP_MAX_EVENTS)
 _enabled: bool = env.monitoring
 _tracer: Optional[SpanTracer] = SpanTracer() if _enabled else None
 _fit_mon = None
@@ -94,11 +110,12 @@ def reset() -> None:
     """Fresh registry + tracer detached + enablement back to the env flag.
     Test isolation hook; instrument bundles are re-created lazily against
     the new registry."""
-    global _REGISTRY, _tracer, _enabled
+    global _REGISTRY, _tracer, _enabled, _startup
     global _fit_mon, _serving_mon, _localsgd_mon, _ckpt_mon, _import_mon
     global _recovery_mon, _compile_mon, _generate_mon, _quantize_mon
     global _tenant_mon, _slo_mon, _guardrail_mon
     _REGISTRY = MetricsRegistry()
+    _startup = SpanTracer("deeplearning4j_tpu start-up", STARTUP_MAX_EVENTS)
     _enabled = env.monitoring
     _tracer = SpanTracer() if _enabled else None
     _fit_mon = _serving_mon = _localsgd_mon = _ckpt_mon = None
@@ -137,12 +154,20 @@ def tracer() -> Optional[SpanTracer]:
     return _tracer
 
 
+def startup() -> SpanTracer:
+    """The start-up record: model and program builds, kept with monitoring
+    off too."""
+    return _startup
+
+
 def spans() -> list:
-    """The spans recorded so far, as plain ``Span`` tuples (name, start_ns,
-    end_ns, tid, thread, id, parent, args) on ``time.time_ns()``; empty with
-    no ring installed."""
+    """The spans recorded so far, the start-up record's and the ring's, as
+    plain ``Span`` tuples (name, start_ns, end_ns, tid, thread, id, parent,
+    args) on ``time.time_ns()``, by start."""
     t = _tracer
-    return [] if t is None else t.spans()
+    out = _startup.spans() + ([] if t is None else t.spans())
+    out.sort(key=lambda s: s.start_ns)
+    return out
 
 
 @contextlib.contextmanager
@@ -213,16 +238,6 @@ class _FitMonitor:
         self.queue_depth = reg.gauge(
             "dl4j_prefetch_queue_depth",
             "Staged batches waiting in the prefetch queue at hand-over")
-        self.loop_passes = reg.gauge(
-            "dl4j_train_loop_passes",
-            "Passes the fitted model's looped stacks make over their layers")
-        self.loop_layer_applications = reg.gauge(
-            "dl4j_train_loop_layer_applications",
-            "Layer applications a step of the fitted model's looped stacks (passes x layers)")
-        self.loop_kernel_keeping_applications = reg.gauge(
-            "dl4j_train_loop_kernel_keeping_applications",
-            "Layer applications a step of the looped stacks under a checkpoint that keeps the "
-            "attention kernel's named results (0 with remat off)")
         self.exit_share = reg.gauge(
             "dl4j_train_exit_share",
             "Mean exit probability of each pass over the latest step's positions",
@@ -277,16 +292,6 @@ class _FitMonitor:
             if delta_layers:
                 for layer, value in zip(delta_layers, decays.tolist()):
                     self.delta_chunk_log_decay_min.labels(layer=layer).set(value)
-
-    def describe_loops(self, layers, remat: bool = False) -> None:
-        """The looped stacks of a model about to be fitted, as gauges. Under
-        ``remat`` every application is its own ``checkpoint_layer``."""
-        loops = [l for l in layers if hasattr(l, "layer_applications")]
-        if loops:
-            applications = sum(l.layer_applications for l in loops)
-            self.loop_passes.set(sum(l.times for l in loops))
-            self.loop_layer_applications.set(applications)
-            self.loop_kernel_keeping_applications.set(applications if remat else 0)
 
     def hold_exit_share(self, state) -> None:
         """Keep a dispatched step's exit shares (``LoopExitOutputLayer``'s
@@ -701,7 +706,7 @@ __all__ = [
     "Span", "SpanTracer", "MetricsListener", "DEFAULT_BUCKETS", "SIZE_BUCKETS",
     "FlightRecorder", "RequestTrace", "RequestTracer", "flight",
     "registry", "enabled", "enable", "disable", "reset", "metrics_text",
-    "start_tracing", "stop_tracing", "tracer", "span", "spans",
+    "start_tracing", "stop_tracing", "tracer", "span", "spans", "startup",
     "validate_nesting",
     "fit_monitor", "serving_monitor", "localsgd_monitor",
     "checkpoint_monitor", "import_monitor", "recovery_monitor",

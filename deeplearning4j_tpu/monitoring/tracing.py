@@ -15,6 +15,10 @@ enclosing span on its thread, or an explicit ``parent=`` — and enters a
 ``jax.profiler.TraceAnnotation`` of the same name and arguments, so a
 profiler trace taken with its host tracer on shows the same spans beside
 the device's operations. ``spans()`` reads the ring out as plain tuples.
+Span ids are unique in the process and the spans open on a thread are one
+stack whatever tracer holds them, so a span in one tracer may name its
+cause in another (the start-up record's ``compile`` under the ring's
+``fit.dispatch``).
 
 The saved file is the Chrome trace-event format — begin/end ("B"/"E") event
 pairs, "X" complete events, and "M" metadata under ``{"traceEvents": [...]}``
@@ -46,6 +50,9 @@ from typing import Deque, Dict, List, NamedTuple, Optional
 from jax.profiler import TraceAnnotation
 
 from deeplearning4j_tpu.common.env import env
+
+_ids = itertools.count(1)       # span ids, unique in the process
+_open = threading.local()       # per thread: ids of the spans open on it, any tracer's
 
 
 class Span(NamedTuple):
@@ -87,8 +94,6 @@ class SpanTracer:
                                else env.trace_max_events))
         self._events: Deque[Dict] = collections.deque()
         self.start_ns = time.time_ns()
-        self._ids = itertools.count(1)
-        self._open = threading.local()      # per thread: ids of open spans
         self._pid = os.getpid()
         self._named_tids: set = set()
         self._meta: List[Dict] = [{
@@ -99,10 +104,18 @@ class SpanTracer:
     def _us(self, t_ns: int) -> float:
         return (t_ns - self.start_ns) * 1e-3
 
-    def current(self) -> Optional[int]:
-        """The id of the innermost span open on this thread."""
-        stack = getattr(self._open, "stack", None)
+    @staticmethod
+    def current() -> Optional[int]:
+        """The id of the innermost span open on this thread, in any tracer."""
+        stack = getattr(_open, "stack", None)
         return stack[-1] if stack else None
+
+    @staticmethod
+    def _stack() -> List[int]:
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        return stack
 
     def _append(self, ev: Dict) -> None:
         """Ring append: names the emitting thread on first sight, evicts
@@ -137,10 +150,8 @@ class SpanTracer:
         the span that caused this one where that is not the enclosing span
         on this thread."""
         tid = threading.get_ident()
-        stack = getattr(self._open, "stack", None)
-        if stack is None:
-            stack = self._open.stack = []
-        sid = next(self._ids)
+        stack = self._stack()
+        sid = next(_ids)
         if parent is None and stack:
             parent = stack[-1]
         args = {k: _json_safe(v) for k, v in args.items()}
@@ -174,18 +185,40 @@ class SpanTracer:
     def complete(self, name: str, dur_s: float, **args) -> None:
         """Record an already-measured span (ended ~now, ``dur_s`` long) as
         an "X" complete event, caused by the span open on this thread — how
-        request-trace spans (monitoring/context.py) and compiles
-        (monitoring/compile.py) mirror into the process timeline without
-        holding the tracer lock for their whole duration."""
+        request-trace spans (monitoring/context.py) mirror into the process
+        timeline without holding the tracer lock for their whole duration."""
         dur_ns = int(max(0.0, float(dur_s)) * 1e9)
         t = max(self.start_ns, time.time_ns() - dur_ns)
-        ev: Dict = {"name": name, "ph": "X", "ts": self._us(t),
-                    "dur": dur_ns * 1e-3, "pid": self._pid,
-                    "tid": threading.get_ident(), "t_ns": t,
-                    "sid": next(self._ids), "parent": self.current()}
+        self.record(name, t, t + dur_ns, self.current(), **args)
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               parent: Optional[int] = None, sid: Optional[int] = None,
+               **args) -> int:
+        """Record a finished span from ``start_ns`` to ``end_ns`` as one "X"
+        event, whole: a ring that drops it drops all of it. Returns its id."""
+        sid = next(_ids) if sid is None else sid
+        ev: Dict = {"name": name, "ph": "X", "ts": self._us(start_ns),
+                    "dur": (end_ns - start_ns) * 1e-3, "pid": self._pid,
+                    "tid": threading.get_ident(), "t_ns": start_ns,
+                    "sid": sid, "parent": parent}
         if args:
             ev["args"] = {k: _json_safe(v) for k, v in args.items()}
         self._append(ev)
+        return sid
+
+    @contextlib.contextmanager
+    def measured(self, name: str, **args):
+        """Time a section as one "X" event recorded when it ends, open on
+        this thread meanwhile (the spans it causes name it). Yields its
+        ``args``, which the section may add to before it ends."""
+        stack = self._stack()
+        parent, sid, t = self.current(), next(_ids), time.time_ns()
+        stack.append(sid)
+        try:
+            yield args
+        finally:
+            stack.pop()
+            self.record(name, t, time.time_ns(), parent, sid, **args)
 
     def spans(self) -> List[Span]:
         """Every finished span still in the ring, by start: begin/end pairs
@@ -208,7 +241,7 @@ class SpanTracer:
                                     b["parent"], b.get("args", {})))
             elif ph == "X":
                 out.append(Span(ev["name"], ev["t_ns"],
-                                ev["t_ns"] + int(ev["dur"] * 1e3), ev["tid"],
+                                ev["t_ns"] + round(ev["dur"] * 1e3), ev["tid"],
                                 threads.get(ev["tid"], ""), ev["sid"],
                                 ev["parent"], ev.get("args", {})))
         out.sort(key=lambda s: s.start_ns)

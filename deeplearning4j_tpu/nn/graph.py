@@ -22,7 +22,7 @@ from deeplearning4j_tpu.nn.conf.builders import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.conf.graph import LayerVertex
 from deeplearning4j_tpu.nn.layers.base import checkpoint_layer, layer_loss_terms
 from deeplearning4j_tpu.nn.multilayer import _check_carry_batch, _tree_cast
-from deeplearning4j_tpu.nn.network import Network, _unpack
+from deeplearning4j_tpu.nn.network import Network, _unpack, recorded_init
 from deeplearning4j_tpu.optimize.updaters import NoOp, get_updater
 
 
@@ -54,6 +54,7 @@ class ComputationGraph(Network):
                 self._updaters[name] = conf.updater
 
     # ------------------------------------------------------------------ init
+    @recorded_init
     def init(self, seed: Optional[int] = None) -> "ComputationGraph":
         seed = self.conf.seed if seed is None else seed
         key = jax.random.key(seed)
@@ -480,10 +481,6 @@ class ComputationGraph(Network):
         return ((self._as_input_dict(x), self._as_label_dict(y)),
                 (None if mask is None else [jnp.asarray(mask)],
                  self._labels_masks_for(mask, label_mask)))
-
-    def _loop_layers(self):
-        return [v.layer for v in self.conf.vertices.values()
-                if isinstance(v, LayerVertex)]
 
     def _exit_state(self):
         return self.state.get(self.conf.network_outputs[0])

@@ -31,7 +31,9 @@ from deeplearning4j_tpu.nn.layers.base import (
 from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
 # _unpack and global_norm_clip are imported from here by parallel/ and the
 # benchmark's tests
-from deeplearning4j_tpu.nn.network import Network, _unpack, global_norm_clip  # noqa: F401
+from deeplearning4j_tpu.nn.network import (  # noqa: F401
+    Network, _unpack, global_norm_clip, recorded_init,
+)
 from deeplearning4j_tpu.optimize.async_dispatch import (
     deliver_score, get_window, supports_tail_padding,
 )
@@ -95,6 +97,7 @@ class MultiLayerNetwork(Network):
                           for l in self.layers]
 
     # ------------------------------------------------------------------ init
+    @recorded_init
     def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
         seed = self.conf.seed if seed is None else seed
         key = jax.random.key(seed)
@@ -447,9 +450,6 @@ class MultiLayerNetwork(Network):
         return ((jnp.asarray(x), jnp.asarray(y)),
                 (None if mask is None else jnp.asarray(mask),
                  None if label_mask is None else jnp.asarray(label_mask)))
-
-    def _loop_layers(self):
-        return self.layers
 
     def _exit_state(self):
         return self.state[-1]

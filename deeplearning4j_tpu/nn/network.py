@@ -73,12 +73,25 @@ def _unpack(ds):
     return x, y, None, None
 
 
+def recorded_init(init):
+    """A core's ``init()`` as the span ``setup.init`` of the start-up record
+    (with monitoring off too: a model is built before a job switches it on),
+    with the leaves and parameters it drew; what it builds names it."""
+    @functools.wraps(init)
+    def recorded(self, *args, **kwargs):
+        with monitoring.startup().measured("setup.init") as span:
+            out = init(self, *args, **kwargs)
+            span.update(leaves=len(jax.tree.leaves(self.params)), params=self.num_params())
+        return out
+    return recorded
+
+
 class Network:
     """A model over a resolved configuration: counters, listeners, the jitted
     train step and the fit loop. A subclass keeps ``params``, ``state``,
     ``opt_state`` and ``_updaters`` as containers of one shape (lists by
     layer, dicts by vertex) and supplies ``_step_loss``, ``_step_inputs``,
-    ``_tail_padding_ok``, ``_loop_layers`` and ``_exit_state``."""
+    ``_tail_padding_ok`` and ``_exit_state``."""
 
     def __init__(self, conf):
         self.conf = conf
@@ -275,8 +288,6 @@ class Network:
             # data-wait spans time the iterator pull per batch (host input
             # pipeline vs device step split); None = monitoring off
             mon = monitoring.fit_monitor()
-            if mon is not None:
-                mon.describe_loops(self._loop_layers(), self.conf.remat)
             self._fit_batches(
                 data if mon is None else mon.wrap_batches(data, self),
                 fit_batch)

@@ -422,6 +422,11 @@ class TestZeroHostSyncs:
         x, y = _data(24)
         net.fit(ArrayDataSetIterator(x, y, batch_size=8), epochs=2)
         assert calls == []
+        # the start-up record, on with monitoring off, took the first fit's
+        # builds; a fit that builds no program appends to no tracer at all
+        monkeypatch.setattr(SpanTracer, "_append", spy("SpanTracer._append"))
+        net.fit(ArrayDataSetIterator(x, y, batch_size=8), epochs=2)
+        assert calls == []
 
 
 # --------------------------------------------------------- tail padding
