@@ -17,9 +17,11 @@ keys, on and below it) by sub-chunks of ``SUB`` with ``_decayed_scores``'
 reference points (a diagonal block around its sub-chunk's middle token, a
 block below it around the first token of its rows' sub-chunk, a select above a
 block's diagonal); the inverse ``T`` of the unit lower-triangular system
-``I + Diag(beta) A`` in float32 by doubling its diagonal blocks (1, 2, ..., 64:
-``T <- T - T N T``, ``N`` the system's part between the two halves of each
-new block; no power series, whose terms would cancel); ``W = T Diag(beta) (K
+``I + Diag(beta) A`` in float32, doing only its non-zero work: its diagonal
+blocks of ``BLOCK`` tokens by substitution on the vector unit, then merged to
+32 and 64 by ``T <- T - T N T`` (``N`` the system's part between the two
+halves of each new block) over the second halves' rows alone, the only ones
+it fills (no power series, whose terms would cancel); ``W = T Diag(beta) (K
 * exp(G))``, ``U0 = T Diag(beta) V``; then ``U = U0 - W S``, ``O = (Q *
 exp(G)) S + B U`` and the next state. The forward writes ``o``, the final state
 and each chunk's starting state in ``k``'s type (what the plain lowering keeps;
@@ -34,7 +36,8 @@ HBM; the residuals are the operands and the chunks' starting states.
 
 **Precision**, the plain lowering's: the state, the running sums, the
 exponentials, the inverse and every product's sum in float32 (the inverse's
-products at ``HIGHEST``), the other products' operands in ``k``'s type.
+substitution exact float32 arithmetic, its merges' products at ``HIGHEST``),
+the other products' operands in ``k``'s type.
 """
 
 from __future__ import annotations
@@ -60,6 +63,12 @@ CHUNKS = 4
 #: backward reads: a layer's checkpoint that keeps them (``REMAT_POLICY``,
 #: ``nn/layers/base.py``) does not run the forward kernel again under ``remat``
 SAVED_OUT, SAVED_STATES = "delta_rule_out", "delta_rule_states"
+
+#: the diagonal blocks of a chunk's inverse solved by substitution before the
+#: merges at ``HIGHEST`` (``_unit_lower_inverse``); measured on the v5e in the
+#: hybrid decoder's training step (PERF.md): blocks of 8, with a third merge,
+#: ran it 1.3-3.9 % slower
+BLOCK = 16
 
 _F32 = jnp.float32
 _EXACT = jax.lax.Precision.HIGHEST
@@ -98,10 +107,11 @@ def _running_sum(x, reverse=False):
     return _dot(jnp.where(i <= j if not reverse else i >= j, 1.0, 0.0), x, exact=True)
 
 
-def _at_rows(G, offset):
-    """``[CHUNK, K]``: each row the row of ``G`` at ``offset`` in its sub-chunk."""
-    return jnp.concatenate([jnp.broadcast_to(G[a * SUB + offset:a * SUB + offset + 1], (SUB, G.shape[1]))
-                            for a in range(CHUNK // SUB)], axis=0)
+def _at_rows(G, offset, size=SUB):
+    """``[CHUNK, K]``: each row the row of ``G`` at ``offset`` in its block of
+    ``size`` rows (a sub-chunk)."""
+    return jnp.concatenate([jnp.broadcast_to(G[a * size + offset:a * size + offset + 1], (size, G.shape[1]))
+                            for a in range(CHUNK // size)], axis=0)
 
 
 def _stacked(t):
@@ -186,17 +196,26 @@ class _Scores:
 
 def _unit_lower_inverse(N):
     """``(I + N)^-1`` for ``N`` ``[CHUNK, CHUNK]`` float32 strictly lower
-    triangular: the inverse of its diagonal blocks of 1, 2, 4, ... tokens,
-    each doubling ``T <- T - T N' T`` with ``N'`` the part of ``N`` that links
-    the two halves of a new block (exact for a block triangular matrix), at
-    ``HIGHEST``."""
+    triangular, the exact block inverse doing only its non-zero work (the
+    layout of ``fla``'s ``solve_tril``). Its diagonal blocks of ``BLOCK``
+    tokens by substitution in float32 on the vector unit, all blocks at once:
+    step ``c`` takes row ``c`` of each block, final by then, off the rows below
+    it (``T -= N[:, pivot] T[pivot]``). Then blocks of ``s`` merged into ``2 s``
+    by ``T <- T - T N' T``, ``N'`` the part of ``N`` that links the two halves
+    of a new block: ``T N' T`` is zero but on the second halves' rows, so both
+    products take those rows alone, at ``HIGHEST``."""
     j, i = _iota(N.shape, 0), _iota(N.shape, 1)
-    # blocks of 2 from blocks of 1, whose inverse is I: I - N'
-    T = jnp.where(j == i, 1.0, 0.0) - jnp.where((j // 2 == i // 2) & (j != i), N, 0.0)
-    size = 2
+    T = jnp.where(j == i, 1.0, 0.0)
+    for c in range(BLOCK - 1):
+        below = jnp.where(i == j // BLOCK * BLOCK + c, N, 0.0).sum(axis=1, keepdims=True)
+        T = T - below * _at_rows(T, c, BLOCK)
+    size = BLOCK
     while size < CHUNK:
-        links = (j // (2 * size) == i // (2 * size)) & (j // size != i // size)
-        T = T - _dot(_dot(T, jnp.where(links, N, 0.0), exact=True), T, exact=True)
+        links = jnp.where((j // (2 * size) == i // (2 * size)) & (j // size != i // size), N, 0.0)
+        blocks = [T[a:a + size] for a in range(0, CHUNK, size)]
+        update = _dot(_dot(jnp.concatenate(blocks[1::2]), links, exact=True), T, exact=True)
+        blocks[1::2] = [b - update[n * size:(n + 1) * size] for n, b in enumerate(blocks[1::2])]
+        T = jnp.concatenate(blocks)
         size *= 2
     return T
 

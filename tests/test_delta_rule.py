@@ -5,6 +5,8 @@ past it, in float32 and with bfloat16 operands; and the exponents the op forms.
 Each by both lowerings: the plain one (what the CPU selects) and the Pallas
 kernel pair (``ops/pallas/delta_rule.py``) in interpret mode."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -207,6 +209,68 @@ def test_the_kernels_carry_the_state_across_grid_steps(monkeypatch, chunks, T, g
     for got, want, name in zip(grads, grads_ref, ("q", "k", "v", "g", "beta", "state")):
         np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()) + 1e-6,
                                    err_msg=name)
+
+
+def kernel_system(decay):
+    """A chunk's ``N = Diag(beta) A`` as the kernels build it: ``beta`` in (0, 1),
+    ``A`` the decayed key scores of ``_Scores`` over the running sums of ``g``."""
+    q, k, _, g, beta, _ = operands(64, decay, seed=12)
+    A = kernels._Scores(q[0, 0], k[0, 0], kernels._running_sum(g[0, 0]), jnp.float32).A
+    return beta[0, 0][:, None] * A
+
+
+def cancelling_system():
+    """Entries of order 10 whose inverse is of order 1: ``(I + M)^-1 - I`` for
+    ``M`` of order 0.3, where a power series' terms would reach 10^k and
+    cancel."""
+    M = np.tril(np.random.default_rng(0).normal(size=(64, 64)) * 0.3, -1)
+    N = jnp.asarray(np.linalg.inv(np.eye(64) + M) - np.eye(64), jnp.float32)
+    assert 5 < float(jnp.abs(N).max()) < 50
+    return N
+
+
+def doubling_inverse(N):
+    """The inverse as the kernels took it before: the diagonal blocks of 1, 2,
+    4, ... doubled, ``T <- T - T N' T`` over every row, float32 at ``HIGHEST``."""
+    j, i = np.indices(N.shape)
+    T = jnp.where(j == i, 1.0, 0.0) - jnp.where((j // 2 == i // 2) & (j != i), N, 0.0)
+    for size in (2, 4, 8, 16, 32):
+        links = jnp.where((j // (2 * size) == i // (2 * size)) & (j // size != i // size), N, 0.0)
+        T = T - jnp.dot(jnp.dot(T, links, precision="highest"), T, precision="highest")
+    return T
+
+
+SYSTEMS = {**{name: functools.partial(kernel_system, decay) for name, decay in
+              (("weak", 0.05), ("start", STRONGEST_START), ("past", 3.0), ("ten", 10.0))},
+           "order10": cancelling_system}
+
+
+@pytest.mark.parametrize("check", ["float64", "doubling", "jaxpr"])
+@pytest.mark.parametrize("system", list(SYSTEMS))
+def test_the_chunk_inverse_is_exact_in_float32_with_only_highest_products(system, check):
+    """``_unit_lower_inverse``, its diagonal blocks by substitution and their
+    merges over the rows they fill: against a float64 inverse and against the
+    doubling it replaced, to float32's round-off (ten times it where ``N``'s
+    entries are of order 10); and in its jaxpr nothing but float32, with two
+    products a merge, every one at ``HIGHEST``."""
+    N = SYSTEMS[system]()
+    T = kernels._unit_lower_inverse(N)
+    room = 1e-5 if system == "order10" else 1e-6
+    if check == "float64":
+        want = np.linalg.inv(np.eye(64) + np.asarray(N, np.float64))
+        np.testing.assert_allclose(np.asarray(T, np.float64), want, atol=room * np.abs(want).max(), rtol=0)
+    elif check == "doubling":
+        want = doubling_inverse(N)
+        np.testing.assert_allclose(T, want, atol=room * float(jnp.abs(want).max()), rtol=0)
+    else:
+        jaxpr = jax.make_jaxpr(kernels._unit_lower_inverse)(N).jaxpr
+        dots = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+        merges = int(np.log2(delta_rule.CHUNK // kernels.BLOCK))
+        assert len(dots) == 2 * merges <= 4
+        assert all(e.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2 for e in dots)
+        floats = {v.aval.dtype for e in jaxpr.eqns for v in e.outvars
+                  if jnp.issubdtype(v.aval.dtype, jnp.floating)}
+        assert floats == {jnp.dtype(jnp.float32)}
 
 
 class TestTheKernelIsChosen:
