@@ -185,14 +185,17 @@ def span(name: str, **args):
 
 # ---- per-subsystem instrument bundles -----------------------------------
 @jax.jit
-def _pack_layer_stats(moe_states, delta_states):
+def _pack_layer_stats(moe_states, delta_states, sparse_states):
     """(``[layers, 5]``: each expert layer's ``moe_stats`` and its loss term,
-    ``[layers]``: each gated delta-rule mixer's ``delta_log_decay_min``), in
-    one computation."""
+    ``[layers]``: each gated delta-rule mixer's ``delta_log_decay_min``,
+    ``[layers, 2]``: each sparse-attention mixer's share of tiles visited and
+    its indexer's loss), in one computation."""
     stack = jax.numpy.stack
     return (stack([jax.numpy.append(s["moe_stats"], s["loss_term"]) for s in moe_states])
             if moe_states else None,
-            stack([s["delta_log_decay_min"] for s in delta_states]) if delta_states else None)
+            stack([s["delta_log_decay_min"] for s in delta_states]) if delta_states else None,
+            stack([stack([s["sparse_tiles_visited"], s["loss_term_indexer"]])
+                   for s in sparse_states]) if sparse_states else None)
 
 
 class _FitMonitor:
@@ -259,6 +262,13 @@ class _FitMonitor:
             "dl4j_train_delta_chunk_log_decay_min",
             "Most negative log-decay summed over one chunk of the gated delta rule, latest "
             "step: how far down the chunk algebra's factors reach", labels=("layer",))
+        self.sparse_tiles_visited = reg.gauge(
+            "dl4j_train_sparse_attn_tiles_visited",
+            "Share of the causal key tiles that hold a key the indexer chose, latest step: "
+            "the tiles the sparse-attention kernels work", labels=("layer",))
+        self.indexer_kl = reg.gauge(
+            "dl4j_train_indexer_kl",
+            "The indexer's loss as added to the latest step's score", labels=("layer",))
         self._layer_stats: collections.deque = collections.deque()
 
     @contextlib.contextmanager
@@ -282,7 +292,8 @@ class _FitMonitor:
             for t, share in enumerate(self._exit_shares.popleft().tolist()):
                 self.exit_share.labels(**{"pass": str(t + 1)}).set(share)
         if self._layer_stats:
-            moe_layers, delta_layers, (packed, decays) = self._layer_stats.popleft()
+            moe_layers, delta_layers, sparse_layers, (packed, decays, sparse) = (
+                self._layer_stats.popleft())
             if moe_layers:
                 rows = packed.tolist()      # one fetch: [layers, moe_stats + the term]
                 for layer, row in zip(moe_layers, rows):
@@ -292,6 +303,10 @@ class _FitMonitor:
             if delta_layers:
                 for layer, value in zip(delta_layers, decays.tolist()):
                     self.delta_chunk_log_decay_min.labels(layer=layer).set(value)
+            if sparse_layers:
+                for layer, (tiles, kl) in zip(sparse_layers, sparse.tolist()):
+                    self.sparse_tiles_visited.labels(layer=layer).set(tiles)
+                    self.indexer_kl.labels(layer=layer).set(kl)
 
     def hold_exit_share(self, state) -> None:
         """Keep a dispatched step's exit shares (``LoopExitOutputLayer``'s
@@ -304,7 +319,8 @@ class _FitMonitor:
     def hold_layer_stats(self, states: dict) -> None:
         """Keep a dispatched step's layer gauges (``{layer: state}``: an expert
         layer's ``moe_stats``, four floats, and its loss term; a gated
-        delta-rule mixer's ``delta_log_decay_min``) until its score is
+        delta-rule mixer's ``delta_log_decay_min``; a sparse-attention mixer's
+        share of tiles visited and its indexer's loss) until its score is
         delivered: a copy, like ``hold_exit_share``'s, and one computation for
         all the layers and both kinds (a copy a layer and array, eight a step
         in a four-layer model, filled the runtime's queue of computations in
@@ -312,8 +328,9 @@ class _FitMonitor:
         if states:
             moe = {k: s for k, s in states.items() if "moe_stats" in s}
             delta = {k: s for k, s in states.items() if "delta_log_decay_min" in s}
-            self._layer_stats.append((list(moe), list(delta), _pack_layer_stats(
-                list(moe.values()), list(delta.values()))))
+            sparse = {k: s for k, s in states.items() if "sparse_tiles_visited" in s}
+            self._layer_stats.append((list(moe), list(delta), list(sparse), _pack_layer_stats(
+                list(moe.values()), list(delta.values()), list(sparse.values()))))
 
     def wrap_batches(self, data, model):
         """Iterate ``data`` timing each pull as the data-wait phase of the

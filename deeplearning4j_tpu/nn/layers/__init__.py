@@ -38,7 +38,9 @@ from deeplearning4j_tpu.nn.layers.attention import (
 )
 from deeplearning4j_tpu.nn.layers.looped import LoopedStack
 from deeplearning4j_tpu.nn.layers.experts import SparseExpertsLayer
-from deeplearning4j_tpu.nn.layers.mixers import GatedDeltaRuleLayer, LatentAttentionLayer
+from deeplearning4j_tpu.nn.layers.mixers import (
+    GatedDeltaRuleLayer, LatentAttentionLayer, SparseAttentionLayer,
+)
 
 __all__ = [
     "Layer", "register_layer",
@@ -57,6 +59,6 @@ __all__ = [
     "MaskZeroLayer", "TimeDistributedLayer",
     "SelfAttentionLayer", "LearnedSelfAttentionLayer", "TransformerEncoderLayer",
     "DecoderBlock", "LoopedStack", "SparseExpertsLayer", "GatedDeltaRuleLayer",
-    "LatentAttentionLayer",
+    "LatentAttentionLayer", "SparseAttentionLayer",
     "Yolo2OutputLayer", "AutoEncoderLayer", "VariationalAutoencoderLayer",
 ]

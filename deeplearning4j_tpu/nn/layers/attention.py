@@ -16,6 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import (
@@ -301,16 +302,27 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_positions
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def rotary_tables(positions: int, head_dim: int, theta: float, yarn=None):
-    """(cos, sin), each ``[positions, head_dim]`` in float32, of rotary
-    position embeddings in the rotate-half pairing: feature ``i`` is paired
-    with ``i + head_dim / 2`` and both turn by ``position * theta ** (-2 i /
-    head_dim)``. ``yarn``: ``(factor, original_positions, beta_fast, beta_slow,
-    attention_factor)`` scales the frequencies by ``yarn_inv_freq`` and
-    multiplies cos and sin by the attention factor, at every length."""
+def rotary_tables(positions, head_dim: int, theta: float, yarn=None, sections=None):
+    """(cos, sin), each ``[T, head_dim]`` in float32, of rotary position
+    embeddings in the rotate-half pairing: feature ``i`` is paired with ``i +
+    head_dim / 2`` and both turn by ``position * theta ** (-2 i / head_dim)``,
+    at the positions ``0 .. positions - 1``. ``yarn``: ``(factor,
+    original_positions, beta_fast, beta_slow, attention_factor)`` scales the
+    frequencies by ``yarn_inv_freq`` and multiplies cos and sin by the
+    attention factor, at every length. ``sections`` (M-RoPE): ``positions`` is
+    then ``[len(sections), T]``, a row of positions a section, and the
+    ``head_dim / 2`` frequencies are cut in runs of ``sections`` (summing to
+    ``head_dim / 2``), the n-th run turning by the n-th row; with equal rows
+    the tables are the plain ones."""
     inv_freq = (_inv_freq(head_dim, theta) if yarn is None
                 else yarn_inv_freq(head_dim, theta, *yarn[:4]))
-    angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    if sections is None:
+        angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    else:
+        if sum(sections) != head_dim // 2:
+            raise ValueError(f"M-RoPE sections {sections} do not sum to head_dim / 2 = {head_dim // 2}")
+        row = np.repeat(np.arange(len(sections)), sections)        # the row each frequency turns by
+        angles = jnp.asarray(positions, jnp.float32)[row].T * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     return (cos, sin) if yarn is None else (cos * yarn[4], sin * yarn[4])

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops.activations import get_activation
-from deeplearning4j_tpu.ops.pallas import delta_rule
+from deeplearning4j_tpu.ops.pallas import delta_rule, sparse_attention
 from deeplearning4j_tpu.ops.pallas.flash_attention import SAVED_LSE, SAVED_OUT
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.weights import init_weight
@@ -38,15 +38,24 @@ LAYER_REGISTRY: dict[str, type] = {}
 #: configuration's ``remat``: the flash kernel's output and log-sum-exp, T-sized
 #: results of T^2 work, and the delta-rule kernels' output and chunk states
 #: (a state of K x V a chunk, in k's type), each only where the registry chose
-#: the kernel; a layer that names nothing keeps its input alone. A wider
+#: the kernel; the sparse-attention kernels' output and log-sum-exp, the
+#: indexer loss's log-sum-exp of a query's chosen scores, and the indexer's
+#: choice of keys (``SELECTED``, ``nn/layers/mixers.py``'s, whichever
+#: lowering made it: the forward, the recomputation and the backward then see
+#: one choice); a layer that names nothing keeps its input alone. A wider
 #: keep-list (XLA's own products, by name) would go here.
+SELECTED = "sparse_attention_selected"
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    SAVED_OUT, SAVED_LSE, delta_rule.SAVED_OUT, delta_rule.SAVED_STATES)
+    SAVED_OUT, SAVED_LSE, delta_rule.SAVED_OUT, delta_rule.SAVED_STATES,
+    sparse_attention.SAVED_OUT, sparse_attention.SAVED_LSE, sparse_attention.SAVED_INDEX_LSE,
+    SELECTED)
 
 
 #: the key of a layer's new state under which its ``apply`` hands back a scalar
 #: for the score (a router's load-balancing term): computed from the step's
-#: activations, differentiated with the loss, under ``remat`` like the rest
+#: activations, differentiated with the loss, under ``remat`` like the rest. A
+#: state that holds two layers' terms (a block's mixer beside its experts)
+#: keys the other ``LOSS_TERM + "_<what>"``
 LOSS_TERM = "loss_term"
 
 
@@ -55,7 +64,8 @@ def layer_loss_terms(new_states) -> list:
     (a list by layer or a dict by vertex). None in a network without such a
     layer, whose step is then the program it was."""
     states = new_states.values() if isinstance(new_states, dict) else new_states
-    return [s[LOSS_TERM] for s in states if isinstance(s, dict) and LOSS_TERM in s]
+    return [term for s in states if isinstance(s, dict) for key, term in s.items()
+            if key == LOSS_TERM or key.startswith(LOSS_TERM + "_")]
 
 
 def checkpoint_layer(fn):

@@ -29,6 +29,25 @@ shared_dim``; ``c = h Wkva`` in ``R^{kv_rank + shared_dim}``; ``[k_nope | v] =
 RMSNorm(c[:kv_rank]) Wkvb`` a head; every head's key ends in the same
 ``c[kv_rank:]``; causal softmax attention through the op registry (the flash
 kernel takes a value narrower than the key), then ``Wo``.
+
+``SparseAttentionLayer``: grouped-head attention over the keys a learned
+indexer chooses (DeepSeek Sparse Attention, ``ops/sparse_attention.py``).
+``q = Nq(h Wq)``, ``k = Nk(h Wk)`` (RMSNorm over a head's features), ``v = h
+Wv``, ``n_heads`` query heads over ``n_kv_heads`` key-value heads, q and k
+turned by M-RoPE (``rotary_tables`` with ``mrope_sections``, from the position
+rows given, or on text the plain positions three times). Under the scope
+``indexer``, from ``sg(h)`` (the indexer learns from its own loss alone):
+``q_i = h Wq_i`` as ``index_heads`` heads of ``index_head_dim``, ``k_i =
+LN(h Wk_i)`` (a LayerNorm with gain and bias, eps ``norm_eps``), the first
+``index_rope_dim`` features of both turned by plain rotary positions, ``w = h
+Ww_i / sqrt(index_heads x index_head_dim)``; the choice
+``op("indexer_topk")`` of ``topk`` keys a query, kept under ``remat`` by name
+(``SELECTED``). Then ``op("sparse_attention")`` over the choice (the op's own
+scope), ``Wo``, and under ``indexer`` again the indexer's loss
+``op("indexer_loss")`` against the attention's weights, handed to the score
+as ``LOSS_TERM + "_indexer"`` (over the rows, as the score sums a row's
+positions), and the share of the causal ``TILE x TILE`` tiles the choice
+reaches (``SPARSE_TILES``), a gauge.
 """
 
 from __future__ import annotations
@@ -41,17 +60,22 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.layers.attention import _attn_mask, rms_norm
-from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.nn.layers.attention import _attn_mask, apply_rotary, rms_norm, rotary_tables
+from deeplearning4j_tpu.nn.layers.base import LOSS_TERM, SELECTED, Layer, register_layer
 from deeplearning4j_tpu.ops.registry import op
 import deeplearning4j_tpu.ops.attention  # noqa: F401
 from deeplearning4j_tpu.ops.delta_rule import chunk_log_decay_min      # registers the op too
+from deeplearning4j_tpu.ops.sparse_attention import tiles_visited_share   # registers the ops too
 
 #: the KDA layer's gauge, the key of its state
 DELTA_STAT = "delta_log_decay_min"
 #: ``checkpoint_name`` of the q, k and v projections' results, which the layer's
 #: own checkpoint keeps: XLA's products, not worth running a third time
 PROJECTED = "delta_mixer_projected"
+#: the sparse-attention mixer's state: its term of the score (the indexer's
+#: loss) and its gauge (the share of the causal tiles its choice reaches)
+INDEXER_LOSS = LOSS_TERM + "_indexer"
+SPARSE_TILES = "sparse_tiles_visited"
 
 
 def causal_depthwise_conv(u, w):
@@ -185,3 +209,86 @@ class LatentAttentionLayer(Layer):
         o = op("dot_product_attention")(
             q, k, kv[..., self.nope_dim:], mask=_attn_mask(mask, T, T), causal=True)
         return o.transpose(0, 2, 1, 3).reshape(B, T, H * self.v_dim) @ params["Wo"], state
+
+
+def layer_norm(x, gain, bias, eps: float):
+    """``(x - mean) / sqrt(var + eps) * gain + bias`` over the last axis, in float32."""
+    xf = x.astype(jnp.float32)
+    mean = xf.mean(-1, keepdims=True)
+    var = ((xf - mean) ** 2).mean(-1, keepdims=True)
+    return ((xf - mean) * jax.lax.rsqrt(var + eps) * gain + bias).astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class SparseAttentionLayer(Layer):
+    d_model: int
+    n_heads: int = 32
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1e7
+    mrope_sections: tuple = (16, 24, 24)
+    norm_eps: float = 1e-6
+    index_heads: int = 16
+    index_head_dim: int = 64
+    index_rope_dim: int = 32        # the features of q_i and k_i that rotary positions turn
+    topk: int = 2048
+
+    def output_type(self, itype):
+        return InputType.recurrent(self.d_model, itype.shape[0])
+
+    def init(self, key, itype):
+        D, dh, HI, dI = self.d_model, self.head_dim, self.index_heads, self.index_head_dim
+        ks = jax.random.split(key, 7)
+        p = {"Wq": self._w(ks[0], (D, self.n_heads * dh)),
+             "Wk": self._w(ks[1], (D, self.n_kv_heads * dh)),
+             "Wv": self._w(ks[2], (D, self.n_kv_heads * dh)),
+             "Wo": self._w(ks[3], (self.n_heads * dh, D)),
+             "q_g": jnp.ones((dh,)), "k_g": jnp.ones((dh,)),
+             "indexer": {"Wq": self._w(ks[4], (D, HI * dI)), "Wk": self._w(ks[5], (D, dI)),
+                         "Ww": self._w(ks[6], (D, HI)),
+                         "k_norm_g": jnp.ones((dI,)), "k_norm_b": jnp.zeros((dI,))}}
+        return p, {INDEXER_LOSS: jnp.zeros((), jnp.float32),
+                   SPARSE_TILES: jnp.zeros((), jnp.float32)}
+
+    def text_positions(self, seq: int):
+        """``[sections, seq]``: text's one position a token, on every row."""
+        return jnp.broadcast_to(jnp.arange(seq), (len(self.mrope_sections), seq))
+
+    def _indexer(self, p, h, positions):
+        """``(q_i [B, H_I, T, d_I], k_i [B, T, d_I], w [B, T, H_I] float32)``."""
+        B, T, _ = h.shape
+        HI, dI, r = self.index_heads, self.index_head_dim, self.index_rope_dim
+        rope = rotary_tables(positions[:1], r, self.rope_theta, sections=(r // 2,))
+
+        def turned(t):
+            return jnp.concatenate([apply_rotary(t[..., :r], rope), t[..., r:]], axis=-1)
+
+        q_i = turned((h @ p["Wq"]).reshape(B, T, HI, dI).transpose(0, 2, 1, 3))
+        k_i = turned(layer_norm(h @ p["Wk"], p["k_norm_g"], p["k_norm_b"], self.norm_eps))
+        w = (h @ p["Ww"]).astype(jnp.float32) * (HI * dI) ** -0.5
+        return q_i, k_i, w
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None, positions=None):
+        if mask is not None:
+            raise ValueError("SparseAttentionLayer takes no padding mask: its choice is of all keys")
+        B, T, _ = x.shape
+        H, KV, dh = self.n_heads, self.n_kv_heads, self.head_dim
+        positions = self.text_positions(T) if positions is None else positions
+        rope = rotary_tables(positions, dh, self.rope_theta, sections=tuple(self.mrope_sections))
+
+        def heads(t, n):
+            return t.reshape(B, T, n, dh).transpose(0, 2, 1, 3)
+
+        q = apply_rotary(rms_norm(heads(x @ params["Wq"], H), params["q_g"], self.norm_eps), rope)
+        k = apply_rotary(rms_norm(heads(x @ params["Wk"], KV), params["k_g"], self.norm_eps), rope)
+        v = heads(x @ params["Wv"], KV)
+        with jax.named_scope("indexer"):
+            q_i, k_i, w = self._indexer(params["indexer"], jax.lax.stop_gradient(x), positions)
+            chosen = checkpoint_name(op("indexer_topk")(q_i, k_i, w, topk=self.topk), SELECTED)
+        o, lse = op("sparse_attention")(q, k, v, chosen)
+        with jax.named_scope("indexer"):
+            loss = op("indexer_loss")(q, k, lse, chosen, q_i, k_i, w)
+            tiles = jax.lax.stop_gradient(tiles_visited_share(chosen))
+        y = o.transpose(0, 2, 1, 3).reshape(B, T, H * dh) @ params["Wo"]
+        return y, {**state, INDEXER_LOSS: loss / B, SPARSE_TILES: tiles}

@@ -131,10 +131,11 @@ class Network:
     def _layer_stats(self) -> dict:
         """``{entry: state}`` of the layers whose state keeps a gauge's source
         (an expert layer's ``moe_stats``, a gated delta-rule mixer's
-        ``delta_log_decay_min``), for the monitor."""
+        ``delta_log_decay_min``, a sparse-attention mixer's
+        ``sparse_tiles_visited``), for the monitor."""
+        gauges = ("moe_stats", "delta_log_decay_min", "sparse_tiles_visited")
         return {str(k): self.state[k] for k in _entry_keys(self.state)
-                if isinstance(self.state[k], dict)
-                and ("moe_stats" in self.state[k] or "delta_log_decay_min" in self.state[k])}
+                if isinstance(self.state[k], dict) and any(g in self.state[k] for g in gauges)}
 
     # ------------------------------------------------------- the jitted step
     def _apply_updaters(self, grads, params, opt_state, step):

@@ -113,6 +113,11 @@ SCOPED_METRIC_CASES = {
     "setup_step_lower_s": "test_setup_metrics.py",
     "setup_step_load_s": "test_setup_metrics.py",
     "setup_cache_misses": "test_setup_metrics.py",
+    "sparse_attention_ms": "test_keye_vl2_cell.py",
+    "indexer_ms": "test_keye_vl2_cell.py",
+    "sparse_attention_kernel_roofline": "test_keye_vl2_cell.py",
+    "sparse_tiles_visited_pct": "test_keye_vl2_cell.py",
+    "indexer_kernel_roofline": "test_keye_vl2_cell.py",
 }
 
 #: the set-up metrics every cell reports, added after each of these modules
@@ -170,9 +175,9 @@ def _metrics_that_list_another_cell(request, monkeypatch):
         monkeypatch.setitem(module.MANIFEST, "per_layer", [
             m for m in module.MANIFEST["per_layer"]
             if harness._reports(m, module.CELL, module.MANIFEST)])
-    elif name == "test_ouro_cell":
-        # it holds its cell, its configuration and its four metrics to be the
-        # manifest's last: its copy is cut back to the manifest as that PR left
+    elif name in ("test_ouro_cell", "test_kimi_linear_cell"):
+        # each holds its cell, its configuration and its metrics to be the
+        # manifest's last: its copy is cut back to the manifest as its PR left
         # it (the cells up to its own, the metrics that list one of them)
         cells = [w["name"] for w in module.MANIFEST["workloads"]]
         cells = cells[:cells.index(module.CELL) + 1]
