@@ -39,7 +39,7 @@ LAYER_REGISTRY: dict[str, type] = {}
 #: results of T^2 work, and the delta-rule kernels' output and chunk states
 #: (a state of K x V a chunk, in k's type), each only where the registry chose
 #: the kernel; the sparse-attention kernels' output and log-sum-exp, the
-#: indexer loss's log-sum-exp of a query's chosen scores, and the indexer's
+#: indexer loss's gradients (its forward makes them), and the indexer's
 #: choice of keys (``SELECTED``, ``nn/layers/mixers.py``'s, whichever
 #: lowering made it: the forward, the recomputation and the backward then see
 #: one choice); a layer that names nothing keeps its input alone. A wider
@@ -47,7 +47,7 @@ LAYER_REGISTRY: dict[str, type] = {}
 SELECTED = "sparse_attention_selected"
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
     SAVED_OUT, SAVED_LSE, delta_rule.SAVED_OUT, delta_rule.SAVED_STATES,
-    sparse_attention.SAVED_OUT, sparse_attention.SAVED_LSE, sparse_attention.SAVED_INDEX_LSE,
+    sparse_attention.SAVED_OUT, sparse_attention.SAVED_LSE, sparse_attention.SAVED_INDEX_GRADS,
     SELECTED)
 
 

@@ -1,5 +1,6 @@
 """Learned sparse attention on the chip: the indexer's top-k choice as one
-kernel, and attention over the chosen keys as a forward and backward pair.
+kernel, attention over the chosen keys as a forward and backward pair, and the
+indexer's loss as two passes whose forward makes the gradient.
 
 ``indexer_topk`` (``ops/sparse_attention.py`` has the equations): a grid step
 takes ``SELECT_ROWS`` queries. It scores them against every key their causal
@@ -19,9 +20,17 @@ in SMEM ahead of the grid) lets a grid step skip a tile whose keys no query of
 it chose, as it skips one past the diagonal; the index maps hold such a step
 on the block before it, so that it moves nothing. The backward is one call on
 the k-blocks' grid that keeps a head's whole ``dq`` in VMEM, as the flash
-kernel's fused backward does. The forward's ``out`` and ``lse``, and the
-indexer loss's per-query log-sum-exp, carry names for ``remat`` to keep
-(``nn/layers/base.py``).
+kernel's fused backward does.
+
+``indexer_loss``: ``indexer_loss_lse`` forms the indexer's scores alone and
+keeps a query's log-sum-exp over its chosen keys; ``indexer_loss_kl`` then
+forms the attention's mean weights over the query heads once a tile, sums the
+loss's rows and, differentiated, the gradient: the loss's cotangent is a
+scalar, so the backward only scales what the forward made. The same table
+skips tiles in both.
+
+The attention forward's ``out`` and ``lse``, and the indexer loss's gradients,
+carry names for ``remat`` to keep (``nn/layers/base.py``).
 """
 
 from __future__ import annotations
@@ -41,10 +50,9 @@ from deeplearning4j_tpu.ops.sparse_attention import (
 )
 
 #: ``checkpoint_name``s of the attention forward's results that its backward reads,
-#: and of the log-sum-exp of a query's chosen index scores the indexer loss's
-#: backward reads
+#: and of the indexer loss's gradients, which its forward makes
 SAVED_OUT, SAVED_LSE = "sparse_attention_out", "sparse_attention_lse"
-SAVED_INDEX_LSE = "indexer_loss_lse"
+SAVED_INDEX_GRADS = "indexer_loss_grads"
 #: queries a grid step of the choice works
 SELECT_ROWS = 128
 #: scoped VMEM a call may take: the choice keeps a block's scores (8 MB at 16,384
@@ -362,37 +370,27 @@ def _index_t(qi_ref, ki_ref, j):
                                preferred_element_type=_F32)
 
 
-def _kl_tile(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_ref, *, scale, group):
-    """(keep, p^T, I^T) of a tile, keys down: the choice, the attention's mean
-    weights and the indexer's scores."""
+def _index_tile(qi_ref, ki_ref, w_ref, m_ref):
+    """(keep, I^T) of a tile, keys down: the choice and the indexer's scores."""
     keep = m_ref[0].astype(_F32).T > 0
-    p = jnp.where(keep, _mean_weights_t(q_ref, k_ref, lse_ref, scale=scale, group=group), 0.0)
 
     def head(j, total):
         return total + w_ref[0, pl.ds(j, 1), :] * jnp.maximum(_index_t(qi_ref, ki_ref, j), 0.0)
 
-    return keep, p, jax.lax.fori_loop(0, qi_ref.shape[1], head, jnp.zeros(keep.shape, _F32))
+    return keep, jax.lax.fori_loop(0, qi_ref.shape[1], head, jnp.zeros(keep.shape, _F32))
 
 
-def _kl_fwd_kernel(table_ref, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_ref,
-                   kl_ref, lse_i_ref, m_scr, l_scr, cross_scr, ent_scr, mass_scr,
-                   *, scale, group, tiles):
+def _lse_kernel(table_ref, qi_ref, ki_ref, w_ref, m_ref, lse_i_ref, m_scr, l_scr, *, tiles):
     b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        for scr in (l_scr, cross_scr, ent_scr, mass_scr):
-            scr[:] = jnp.zeros_like(scr)
+        l_scr[:] = jnp.zeros_like(l_scr)
 
     @pl.when(_tile_seen(table_ref, b, qi, kj, **tiles))
     def _body():
-        keep, p, index = _kl_tile(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_ref,
-                                  scale=scale, group=group)
-        cross_scr[:] += jnp.sum(jnp.where(keep, p * index, 0.0), axis=0, keepdims=True)
-        ent_scr[:] += jnp.sum(jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0),
-                              axis=0, keepdims=True)
-        mass_scr[:] += jnp.sum(p, axis=0, keepdims=True)
+        keep, index = _index_tile(qi_ref, ki_ref, w_ref, m_ref)
         masked = jnp.where(keep, index, -jnp.inf)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, masked.max(axis=0, keepdims=True))
@@ -404,49 +402,59 @@ def _kl_fwd_kernel(table_ref, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_re
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
-        lse_i = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
-        lse_i_ref[0] = lse_i
-        # sum_s p (log p - (I - lse_I)) over the chosen keys
-        kl_ref[0] = ent_scr[:] - cross_scr[:] + lse_i * mass_scr[:]
+        lse_i_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
 
 
-def _kl_bwd_kernel(table_ref, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_ref, lse_i_ref,
-                   dqi_ref, dw_ref, dki_ref, dqi_scr, dw_scr, *, scale, group, tiles):
+def _kl_kernel(table_ref, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_ref, lse_i_ref,
+               kl_ref, *refs, scale, group, tiles, grads):
+    # with ``grads`` the outputs dq_i, dw (both held across the key tiles) and
+    # dk_i's part; then the three sums
+    cross_scr, ent_scr, mass_scr = sums = refs[-3:]
     b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     seen = _tile_seen(table_ref, b, qi, kj, **tiles)
 
     @pl.when(kj == 0)
     def _init():
-        dqi_scr[:] = jnp.zeros_like(dqi_scr)
-        dw_scr[:] = jnp.zeros_like(dw_scr)
+        for ref in (refs[:2] if grads else ()) + sums:
+            ref[...] = jnp.zeros_like(ref)
 
     @pl.when(seen)
     def _body():
-        keep, p, index = _kl_tile(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, m_ref,
-                                  scale=scale, group=group)
+        keep, index = _index_tile(qi_ref, ki_ref, w_ref, m_ref)
+        p = jnp.where(keep, _mean_weights_t(q_ref, k_ref, lse_ref, scale=scale, group=group), 0.0)
+        cross_scr[:] += jnp.sum(jnp.where(keep, p * index, 0.0), axis=0, keepdims=True)
+        ent_scr[:] += jnp.sum(jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0),
+                              axis=0, keepdims=True)
+        mass_scr[:] += jnp.sum(p, axis=0, keepdims=True)
+        if not grads:
+            return
+        dqi_ref, dw_ref, dki_ref = refs[:3]
         # d L_I / d I = softmax over the chosen keys of I less the target
         d_index = jnp.where(keep, jnp.exp(index - lse_i_ref[0]) - p, 0.0)
+
         def head(j, dki):
             z = _index_t(qi_ref, ki_ref, j)
-            dw_scr[pl.ds(j, 1), :] += jnp.sum(d_index * jnp.maximum(z, 0.0), axis=0, keepdims=True)
+            dw_ref[0, pl.ds(j, 1), :] += jnp.sum(d_index * jnp.maximum(z, 0.0), axis=0,
+                                                 keepdims=True)
             dz = jnp.where(z > 0, d_index * w_ref[0, pl.ds(j, 1), :], 0.0)
             ki, qj = ki_ref[0], qi_ref[0, j]
-            dqi_scr[j] += jax.lax.dot_general(dz.astype(ki.dtype), ki, (((0,), (0,)), ((), ())),
-                                              preferred_element_type=_F32)
+            dqi_ref[0, j] += jax.lax.dot_general(dz.astype(ki.dtype), ki, (((0,), (0,)), ((), ())),
+                                                 preferred_element_type=_F32)
             return dki + jax.lax.dot_general(dz.astype(qj.dtype), qj, (((1,), (0,)), ((), ())),
                                              preferred_element_type=_F32)
 
         dki_ref[0, 0] = jax.lax.fori_loop(0, qi_ref.shape[1], head,
                                           jnp.zeros(dki_ref.shape[2:], _F32))
 
-    @pl.when(jnp.logical_not(seen))
-    def _nothing():
-        dki_ref[0, 0] = jnp.zeros(dki_ref.shape[2:], _F32)
+    if grads:
+        @pl.when(jnp.logical_not(seen))
+        def _nothing():
+            refs[2][0, 0] = jnp.zeros(refs[2].shape[2:], _F32)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finalize():
-        dqi_ref[0] = dqi_scr[:]
-        dw_ref[0] = dw_scr[:]
+        # sum_s p (log p - (I - lse_I)) over the chosen keys
+        kl_ref[0] = ent_scr[:] - cross_scr[:] + lse_i_ref[0] * mass_scr[:]
 
 
 def _kl_specs(q, k, q_i):
@@ -463,80 +471,88 @@ def _kl_specs(q, k, q_i):
         pl.BlockSpec((1, TILE, TILE), lambda b, i, j, tab: (b, i, key(j, i)))])
 
 
-def _kl_forward(q, k, lse, selected, table, q_i, k_i, w_t, scale):
-    B, T, group, tiles, in_specs = _kl_specs(q, k, q_i)
-    row = pl.BlockSpec((1, 1, TILE), lambda b, i, j, tab: (b, 0, i))
-    return pl.pallas_call(
-        functools.partial(_kl_fwd_kernel, scale=scale, group=group, tiles=tiles),
-        name="indexer_loss_fwd",
-        out_shape=(jax.ShapeDtypeStruct((B, 1, T), _F32), jax.ShapeDtypeStruct((B, 1, T), _F32)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, T // TILE, T // TILE), in_specs=in_specs,
-            out_specs=(row, row), scratch_shapes=[pltpu.VMEM((1, TILE), _F32)] * 5),
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret_mode(),
-    )(table.reshape(-1), q, k, lse, q_i, k_i, w_t, selected)
-
-
-def _kl_backward(q, k, lse, selected, table, q_i, k_i, w_t, lse_i, scale):
+def _kl_passes(q, k, lse, selected, table, q_i, k_i, w_t, scale, grads):
+    """The loss's rows ``[B, 1, T]`` and, with ``grads``, its unscaled
+    gradients in ``q_i``, ``k_i`` and ``w_t``, float32: the log-sum-exp pass,
+    then the pass that forms the mean weights."""
     B, T, group, tiles, in_specs = _kl_specs(q, k, q_i)
     HI, DI = q_i.shape[1], q_i.shape[-1]
     nq = T // TILE
-    dqi, dw, dki = pl.pallas_call(
-        functools.partial(_kl_bwd_kernel, scale=scale, group=group, tiles=tiles),
-        name="indexer_loss_bwd",
-        out_shape=(jax.ShapeDtypeStruct((B, HI, T, DI), _F32),
-                   jax.ShapeDtypeStruct((B, HI, T), _F32),
-                   jax.ShapeDtypeStruct((B, nq, T, DI), _F32)),
+    row = pl.BlockSpec((1, 1, TILE), lambda b, i, j, tab: (b, 0, i))
+    lse_i = pl.pallas_call(
+        functools.partial(_lse_kernel, tiles=tiles),
+        name="indexer_loss_lse",
+        out_shape=jax.ShapeDtypeStruct((B, 1, T), _F32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, nq, T // TILE),
-            in_specs=in_specs + [pl.BlockSpec((1, 1, TILE), lambda b, i, j, tab: (b, 0, i))],
-            out_specs=(pl.BlockSpec((1, HI, TILE, DI), lambda b, i, j, tab: (b, 0, i, 0)),
-                       pl.BlockSpec((1, HI, TILE), lambda b, i, j, tab: (b, 0, i)),
-                       pl.BlockSpec((1, 1, TILE, DI), lambda b, i, j, tab: (b, i, j, 0))),
-            scratch_shapes=[pltpu.VMEM((HI, TILE, DI), _F32), pltpu.VMEM((HI, TILE), _F32)]),
+            num_scalar_prefetch=1, grid=(B, nq, T // TILE), in_specs=in_specs[3:],
+            out_specs=row, scratch_shapes=[pltpu.VMEM((1, TILE), _F32)] * 2),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        interpret=interpret_mode(),
+    )(table.reshape(-1), q_i, k_i, w_t, selected)
+    out_shape, out_specs = [jax.ShapeDtypeStruct((B, 1, T), _F32)], [row]
+    if grads:
+        out_shape += [jax.ShapeDtypeStruct((B, HI, T, DI), _F32),
+                      jax.ShapeDtypeStruct((B, HI, T), _F32),
+                      jax.ShapeDtypeStruct((B, nq, T, DI), _F32)]
+        out_specs += [pl.BlockSpec((1, HI, TILE, DI), lambda b, i, j, tab: (b, 0, i, 0)),
+                      pl.BlockSpec((1, HI, TILE), lambda b, i, j, tab: (b, 0, i)),
+                      pl.BlockSpec((1, 1, TILE, DI), lambda b, i, j, tab: (b, i, j, 0))]
+    out = pl.pallas_call(
+        functools.partial(_kl_kernel, scale=scale, group=group, tiles=tiles, grads=grads),
+        name="indexer_loss_kl",
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, nq, T // TILE), in_specs=in_specs + [row],
+            out_specs=out_specs, scratch_shapes=[pltpu.VMEM((1, TILE), _F32)] * 3),
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=interpret_mode(),
     )(table.reshape(-1), q, k, lse, q_i, k_i, w_t, selected, lse_i)
+    if not grads:
+        return out[0]
+    rows, dqi, dw, dki = out
     # a key's gradient is the sum of its parts from every block of queries
-    return dqi, dki.sum(axis=1), dw
+    return rows, (dqi, dki.sum(axis=1), dw)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _kl(q, k, lse, selected, table, q_i, k_i, w_t, scale):
-    return _kl_forward(q, k, lse, selected, table, q_i, k_i, w_t, scale)[0].sum()
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _kl(q, k, lse, selected, table, q_i, k_i, w_t, scale, dtype):
+    return _kl_passes(q, k, lse, selected, table, q_i, k_i, w_t, scale, grads=False).sum()
 
 
-def _kl_fwd(q, k, lse, selected, table, q_i, k_i, w_t, scale):
-    rows, lse_i = _kl_forward(q, k, lse, selected, table, q_i, k_i, w_t, scale)
-    # kept under ``remat``: the forward call is then not made again for the backward
-    lse_i = checkpoint_name(lse_i, SAVED_INDEX_LSE)
-    return rows.sum(), (q, k, lse, selected, table, q_i, k_i, w_t, lse_i)
+def _kl_fwd(q, k, lse, selected, table, q_i, k_i, w_t, scale, dtype):
+    rows, grads = _kl_passes(q, k, lse, selected, table, q_i, k_i, w_t, scale, grads=True)
+    # kept under ``remat``: neither call is made again for the backward
+    return rows.sum(), checkpoint_name(grads, SAVED_INDEX_GRADS)
 
 
-def _kl_bwd(scale, residuals, g):
-    q, k, lse, selected, table, q_i, k_i, w_t, lse_i = residuals
-    dqi, dki, dw = _kl_backward(q, k, lse, selected, table, q_i, k_i, w_t, lse_i, scale)
-    return (None, None, None, None, None, (g * dqi).astype(q_i.dtype),
-            (g * dki).astype(k_i.dtype), (g * dw).astype(w_t.dtype))
+def _kl_bwd(scale, dtype, grads, g):
+    dqi, dki, dw = grads
+    return (None, None, None, None, None, (g * dqi).astype(dtype), (g * dki).astype(dtype),
+            g * dw)
 
 
 _kl.defvjp(_kl_fwd, _kl_bwd)
 
 
 def indexer_loss(q, k, lse, selected, q_i, k_i, w):
-    """``op("indexer_loss")`` by the kernel pair: a grid step works a tile of
-    ``TILE`` queries by ``TILE`` keys with keys down (so that a head's
-    log-sum-exp and weight are rows), the attention's mean weights from the
-    query heads' products, the indexer's scores from its heads', and sums a
-    query's cross term, entropy, mass and the online log-sum-exp of its chosen
-    scores over the key tiles. The backward works the same tiles again: the
-    indexer's gradient on a tile is ``softmax(I) - p`` over the chosen keys,
-    through the ReLU to ``q_i``, ``w`` (kept in VMEM across the key tiles) and
-    ``k_i`` (a part a block of queries, summed after)."""
+    """``op("indexer_loss")`` by two kernels over the tiles of ``TILE``
+    queries by ``TILE`` keys, worked keys down (so that a head's log-sum-exp
+    and weight are rows). ``indexer_loss_lse`` forms the indexer's scores
+    alone and keeps a query's online log-sum-exp over its chosen keys,
+    ``lse_I``. ``indexer_loss_kl`` then forms the attention's mean weights
+    ``p`` from the query heads' products, the costly part, and the scores
+    again, once a tile: it sums a query's cross term, entropy and mass, and,
+    differentiated, the gradient ``softmax(I) - p`` over the chosen keys
+    through the ReLU to ``q_i``, ``w`` (held in VMEM across the key tiles)
+    and ``k_i`` (a part a block of queries, summed after). ``lse_I`` comes
+    first because the softmax needs it on every tile. The loss's cotangent is
+    a scalar and ``p`` a fixed target, so the gradient is made in the forward
+    and the backward only scales it: the float32 gradients are the residuals,
+    kept by name under ``remat`` (``d_I`` padded to 128 lanes: at 16 heads of
+    64 and 16,384 tokens ``dq_i`` 134 MB a layer, ``dk_i`` 8, ``dw`` 1)."""
     q, k, lse, selected = (jax.lax.stop_gradient(a) for a in (q, k, lse, selected))
     return _kl(q, k, lse.astype(_F32), selected, visited_tiles(selected), q_i, k_i,
-               w.astype(_F32).transpose(0, 2, 1), q.shape[-1] ** -0.5)
+               w.astype(_F32).transpose(0, 2, 1), q.shape[-1] ** -0.5, q_i.dtype)
 
 
 def _kl_requires(q, k, lse, selected, q_i, k_i, w):

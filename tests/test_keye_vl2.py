@@ -227,12 +227,12 @@ def test_the_choice_is_kept_under_remat_by_its_name(capsys):
     assert not [line for line in saved if f"[{B},{T},{T}]" in line and SELECTED not in line]
 
 
-def test_with_the_kernels_remat_keeps_what_their_backwards_read(capsys, monkeypatch):
+def test_with_the_kernels_remat_keeps_what_their_backwards_read(capsys, monkeypatch, kernel_calls):
     """The chip's path (the kernels forced onto the CPU, interpreted): the
     block's checkpoint keeps the choice, the attention's output and
-    log-sum-exp and the indexer loss's log-sum-exp by name, so that neither
-    forward kernel runs again for the backward, and nothing T x T but the
-    choice."""
+    log-sum-exp and the indexer loss's gradients by name, so that no forward
+    kernel runs again for the backward, and nothing T x T but the choice; the
+    loss's two calls run once, and no call of it is left for the backward."""
     from deeplearning4j_tpu.common.env import env
 
     monkeypatch.setattr(env, "force_pallas", True)
@@ -251,13 +251,20 @@ def test_with_the_kernels_remat_keeps_what_their_backwards_read(capsys, monkeypa
     jax.ad_checkpoint.print_saved_residuals(loss, params)
     saved = capsys.readouterr().out.splitlines()
     named = {line.split("named '")[1].split("'")[0] for line in saved if "named '" in line}
-    assert {SELECTED, kernels.SAVED_INDEX_LSE} <= named
+    assert {SELECTED, kernels.SAVED_INDEX_GRADS} <= named
+    assert "indexer_loss_lse" not in named
+    # dq_i, dk_i and dw, float32
+    grads = sorted(line.split(" ")[0] for line in saved if kernels.SAVED_INDEX_GRADS in line)
+    assert grads == sorted([f"f32[1,2,{seq},64]", f"f32[1,{seq},64]", f"f32[1,2,{seq}]"]), saved
     # the attention's ``out`` and ``lse``, kept behind the ``reduce_precision``
     # JAX puts on a kept value
     kept = [line.split(" ")[0] for line in saved if line.endswith("(sparse_attention)")]
     assert sorted(kept) == [f"f32[1,{H},{seq},64]", f"f32[1,{H},{seq}]"]
     assert [line for line in saved if f",{seq},{seq}]" in line] == [
         line for line in saved if SELECTED in line]
+    calls = kernel_calls(jax.grad(loss), params)
+    assert {name: n for name, n in calls.items() if name.startswith("indexer_loss")} == {
+        "indexer_loss_lse": 1, "indexer_loss_kl": 1}, calls
 
 
 # ------------------------------------------------------------ the kernels, interpreted
@@ -303,6 +310,42 @@ def test_the_attention_kernels_interpreted_match_the_plain_lowering_and_skip_emp
     np.testing.assert_allclose(lse, lse_ref, atol=2e-5)
     for g, w in zip(grads, grads_ref):
         np.testing.assert_allclose(g, w, atol=5e-5 * float(jnp.abs(w).max()))
+
+
+def _loss_operands(seq=1024):
+    """Operands of the indexer's loss, the choice with one causal tile empty,
+    and the log-sum-exp of the attention over the choice (so that a query's
+    weights sum to one over its chosen keys, as the loss's gradient has it)."""
+    keys = jax.random.split(jax.random.key(6), 7)
+    q = jax.random.normal(keys[0], (1, 4, seq, 64))
+    k = jax.random.normal(keys[1], (1, 2, seq, 64))
+    t = jnp.arange(seq)
+    sel = (jax.random.uniform(keys[2], (1, seq, seq)) < 0.2) | (t[None, :] == t[:, None])
+    sel = (sel & (t[None, :] <= t[:, None])).at[:, 512:, :512].set(False).astype(jnp.int8)
+    _, lse = plain.sparse_attention(q, k, jax.random.normal(keys[3], k.shape), sel)
+    q_i = jax.random.normal(keys[4], (1, 2, seq, 64))
+    k_i = jax.random.normal(keys[5], (1, seq, 64))
+    w = jax.random.normal(keys[6], (1, seq, 2))
+    return q, k, lse, sel, q_i, k_i, w
+
+
+def test_the_loss_kernels_interpreted_match_the_plain_lowering_and_skip_empty_tiles(kernel_calls):
+    q, k, lse, sel, q_i, k_i, w = operands = _loss_operands()
+    assert int(plain.visited_tiles(sel).sum()) == 2          # one causal tile of three is skipped
+    want, want_grads = jax.value_and_grad(plain.indexer_loss, argnums=(4, 5, 6))(*operands)
+    got, got_grads = jax.value_and_grad(kernels.indexer_loss, argnums=(4, 5, 6))(*operands)
+    assert float(got) == pytest.approx(float(want), rel=2e-5) and float(want) > 0
+    for g, ref in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, ref, atol=5e-5 * float(jnp.abs(ref).max()))
+    # the value alone: no gradient is made
+    assert float(kernels.indexer_loss(*operands)) == pytest.approx(float(want), rel=2e-5)
+    assert kernel_calls(kernels.indexer_loss, *operands) == {"indexer_loss_lse": 1,
+                                                             "indexer_loss_kl": 1}
+    # the backward scales the gradient the forward made by the loss's cotangent
+    value, backward = jax.vjp(lambda *a: kernels.indexer_loss(q, k, lse, sel, *a), q_i, k_i, w)
+    assert float(value) == float(got)
+    for g, one in zip(backward(jnp.float32(2.5)), got_grads):
+        np.testing.assert_array_equal(g, 2.5 * one)
 
 
 def test_the_registry_picks_the_kernels_on_the_chip_alone():

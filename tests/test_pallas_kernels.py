@@ -967,6 +967,34 @@ class TestFlashBackwardCompilesForTheV5e:
         assert "delta_rule_fwd" in text and "delta_rule_bwd" in text
         assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
+    @pytest.mark.parametrize("differentiated", [False, True], ids=["value", "gradient"])
+    def test_the_indexer_loss_kernels_compile_at_the_sparse_cells_shape(self, one_chip, monkeypatch,
+                                                                        differentiated):
+        """The keye cell's shape (32 query heads over 4 of 128, 16 index heads of
+        64, 16,384 tokens): the log-sum-exp pass and the pass that forms the
+        mean weights, and no third call for the gradient, which the second
+        makes; the float32 gradients leave it, ``k_i``'s as parts a block of
+        queries (268 MB with its 64 lanes padded, the bulk of the temporaries)."""
+        from deeplearning4j_tpu.ops.pallas import sparse_attention as kernels
+
+        monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+        B, H, KV, T, D, HI, DI = 1, 32, 4, 16384, 128, 16, 64
+
+        def sds(shape, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        args = (sds((B, H, T, D)), sds((B, KV, T, D)), sds((B, H, T), jnp.float32),
+                sds((B, T, T), jnp.int8), sds((B, HI, T, DI)), sds((B, T, DI)),
+                sds((B, T, HI), jnp.float32))
+        fn = kernels.indexer_loss
+        if differentiated:
+            fn = jax.value_and_grad(fn, argnums=(4, 5, 6))
+        compiled = jax.jit(fn).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert "indexer_loss_lse" in text and "indexer_loss_kl" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 450e6
+
     @pytest.mark.parametrize("kernel,calls", [
         ("pack_rows", 1), ("gather_rows", 2), ("gather_rows_dot", 2), ("gather_sum_rows", 2)])
     def test_row_gather_kernels_compile_at_the_expert_cells_shape(self, one_chip, kernel, calls):
