@@ -7,11 +7,11 @@ org.deeplearning4j.nn.layers.** — see each module's docstring.
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.nn.layers.core import (
     DenseLayer, ActivationLayer, DropoutLayer, EmbeddingLayer,
-    EmbeddingSequenceLayer, ElementWiseMultiplicationLayer,
+    EmbeddingSequenceLayer, ElementWiseMultiplicationLayer, BlockDiffusionNoiseLayer,
 )
 from deeplearning4j_tpu.nn.layers.output import (
     OutputLayer, RnnOutputLayer, LossLayer, CenterLossOutputLayer, CnnLossLayer,
-    LoopExitOutputLayer,
+    LoopExitOutputLayer, BlockDiffusionOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.conv import (
     ConvolutionLayer, Convolution1DLayer, Convolution3DLayer,
@@ -45,9 +45,9 @@ from deeplearning4j_tpu.nn.layers.mixers import (
 __all__ = [
     "Layer", "register_layer",
     "DenseLayer", "ActivationLayer", "DropoutLayer", "EmbeddingLayer",
-    "EmbeddingSequenceLayer", "ElementWiseMultiplicationLayer",
+    "EmbeddingSequenceLayer", "ElementWiseMultiplicationLayer", "BlockDiffusionNoiseLayer",
     "OutputLayer", "RnnOutputLayer", "LossLayer", "CenterLossOutputLayer",
-    "CnnLossLayer", "LoopExitOutputLayer",
+    "CnnLossLayer", "LoopExitOutputLayer", "BlockDiffusionOutputLayer",
     "ConvolutionLayer", "Convolution1DLayer", "Convolution3DLayer",
     "Deconvolution2DLayer", "SeparableConvolution2DLayer",
     "DepthwiseConvolution2DLayer", "SubsamplingLayer", "Subsampling1DLayer",

@@ -373,6 +373,12 @@ class DecoderBlock(Layer):
     ``apply`` takes ``rope``, the ``rotary_tables`` of its positions, from a
     container that applies many blocks in one step (``LoopedStack``) so that
     they are computed once; alone it makes its own.
+
+    ``block_diffusion`` (a block length): the input is block diffusion's
+    ``x_t || x_0`` (``BlockDiffusionNoiseLayer``), 2L positions whose halves
+    share the positions 0 .. L - 1 in the rotary tables, and ``Attn`` attends
+    under the block-diffusion mask (``ops/attention.py::block_diffusion_mask``)
+    in place of the causal one.
     """
 
     d_model: int
@@ -388,8 +394,11 @@ class DecoderBlock(Layer):
     rope_yarn: Optional[tuple] = None   # (factor, original positions, beta_fast, beta_slow, attention factor)
     mlp: Optional[Layer] = None         # None: the gated dense MLP of width d_ff
     mixer: Optional[Layer] = None       # None: the rotary softmax attention above
+    block_diffusion: Optional[int] = None   # None: causal
 
     def __post_init__(self):
+        if self.block_diffusion is not None and (self.window is not None or self.mixer is not None):
+            raise ValueError("DecoderBlock's block-diffusion mask takes no window and no mixer")
         if self.mixer is None:
             return
         mine = {f.name: f.default for f in dataclasses.fields(DecoderBlock)}
@@ -441,6 +450,10 @@ class DecoderBlock(Layer):
         return p, state
 
     def rope_tables(self, positions: int):
+        if self.block_diffusion is not None:
+            # x_t || x_0: the two halves hold the same positions
+            half = rotary_tables(positions // 2, self.head_size, self.rope_theta, self.rope_yarn)
+            return tuple(jnp.concatenate([t, t], axis=0) for t in half)
         return rotary_tables(positions, self.head_size, self.rope_theta, self.rope_yarn)
 
     @property
@@ -470,9 +483,13 @@ class DecoderBlock(Layer):
                 return apply_rotary(t, rope)
 
             q, k = rotated("Wq", self.n_heads, "q_g"), rotated("Wk", self.kv_heads, "k_g")
-            o = op("dot_product_attention")(q, k, heads(h @ params["Wv"], self.kv_heads),
-                                            mask=_attn_mask(mask, T, T), causal=True,
-                                            window=self.window)
+            v = heads(h @ params["Wv"], self.kv_heads)
+            if self.block_diffusion is None:
+                o = op("dot_product_attention")(q, k, v, mask=_attn_mask(mask, T, T),
+                                                causal=True, window=self.window)
+            else:
+                o = op("dot_product_attention")(q, k, v, mask=_attn_mask(mask, T, T),
+                                                block_diffusion=self.block_diffusion)
             return o.transpose(0, 2, 1, 3).reshape(B, T, -1) @ params["Wo"]
 
         def first_half(params, state, x):

@@ -68,6 +68,28 @@ def layer_loss_terms(new_states) -> list:
             if key == LOSS_TERM or key.startswith(LOSS_TERM + "_")]
 
 
+#: the key of a layer's new state under which its ``apply`` hands the output
+#: layer per-position weights for its score (block diffusion's noising layer:
+#: 1/t at the positions it masked, 0 elsewhere). They live for one step: the
+#: network takes them out of the state it keeps (``take_loss_weights``)
+LOSS_WEIGHTS = "loss_weights"
+
+
+def take_loss_weights(new_states: list):
+    """The per-position weights a layer handed on in its new state, taken out
+    of it (the list of states is changed in place), or None where no layer
+    handed any."""
+    found = [i for i, s in enumerate(new_states) if isinstance(s, dict) and LOSS_WEIGHTS in s]
+    if not found:
+        return None
+    if len(found) > 1:
+        raise ValueError(f"layers {found} each hand the score weights: one may")
+    kept = dict(new_states[found[0]])
+    weights = kept.pop(LOSS_WEIGHTS)
+    new_states[found[0]] = kept
+    return weights
+
+
 def checkpoint_layer(fn):
     """``jax.checkpoint`` of one layer application, for every network that
     honours ``remat`` (``MultiLayerNetwork``, ``ComputationGraph``,

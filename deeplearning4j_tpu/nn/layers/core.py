@@ -2,7 +2,9 @@
 
 Reference analog: org.deeplearning4j.nn.conf.layers.{DenseLayer, ActivationLayer,
 DropoutLayer, EmbeddingLayer, EmbeddingSequenceLayer, ElementWiseMultiplicationLayer}
-and their impls in org.deeplearning4j.nn.layers.feedforward.**.
+and their impls in org.deeplearning4j.nn.layers.feedforward.**. Net-new beside
+them: ``BlockDiffusionNoiseLayer``, block diffusion's noising of a token
+sequence before its embedding.
 """
 
 from __future__ import annotations
@@ -10,11 +12,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import jax.random
 
 from deeplearning4j_tpu.nn.conf.inputs import InputType
-from deeplearning4j_tpu.nn.layers.base import Layer, register_layer, resolve_activation
+from deeplearning4j_tpu.nn.layers.base import (
+    LOSS_WEIGHTS, Layer, register_layer, resolve_activation,
+)
 
 
 @register_layer
@@ -153,6 +158,74 @@ class EmbeddingSequenceLayer(Layer):
         if self.has_bias:
             y = y + params["b"]
         return resolve_activation(self.activation)(y), state
+
+
+def block_diffusion_noise(key, rows: int, seq: int, block: int, t_min: float):
+    """(masked ``[rows, seq]`` bool, weights ``[rows, seq]`` float32) of one
+    step: a rate ``t ~ U(t_min, 1)`` drawn for each block of ``block``
+    positions of each row, each position masked on its own with probability
+    ``t`` of its block, and weighted ``1 / t`` where it is masked, 0 elsewhere.
+    ``key`` splits into the rates' key and the positions'."""
+    k_rate, k_mask = jax.random.split(key)
+    rate = jnp.repeat(jax.random.uniform(k_rate, (rows, seq // block), jnp.float32,
+                                         t_min, 1.0), block, axis=1)
+    masked = jax.random.uniform(k_mask, (rows, seq), jnp.float32) < rate
+    return masked, jnp.where(masked, 1.0 / rate, 0.0)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class BlockDiffusionNoiseLayer(EmbeddingSequenceLayer):
+    """Block diffusion's noising and embedding of a clean token sequence —
+    net-new (masked diffusion over blocks, BD3-LM; no reference analog).
+
+    Takes ids ``x_0`` ``[batch, L]``; draws the step's noise
+    (``block_diffusion_noise``: a rate a block of ``block`` positions, at
+    least ``t_min``, a mask a position), writes ``mask_id`` (None: the table's
+    last row) at the masked positions of a copy ``x_t``, and embeds ``x_t ||
+    x_0``: ``[batch, 2L, n_out]``, the noised copy first. The draw's key is
+    ``fold_in(key(noise_seed), noise_step)``, both int32 leaves of the layer's
+    state: the seed drawn once at ``init`` from the model's seed, the counter
+    0 there and one more after every step. So every step draws afresh, two
+    seeds draw apart, and a reader that holds the state draws the same
+    (replicas that hold one state draw one mask). The masked positions'
+    weights ``1 / t`` (0 elsewhere) go to the output layer's score through the
+    new state's ``LOSS_WEIGHTS`` (``BlockDiffusionOutputLayer``), which the
+    network takes out again. Runs under the scope ``block_diffusion_noise``.
+    """
+
+    block: int = 4
+    t_min: float = 1e-3             # MDLM's and BD3-LM's clipped schedule
+    mask_id: Optional[int] = None
+
+    def output_type(self, itype):
+        t = itype.shape[0] if itype.kind == "rnn" else None
+        return InputType.recurrent(self.n_out, None if t is None else 2 * t)
+
+    def init(self, key, itype):
+        params, _ = super().init(key, itype)
+        seed = jax.random.randint(jax.random.fold_in(key, 1), (), 0,
+                                  jnp.iinfo(jnp.int32).max, jnp.int32)
+        return params, {"noise_seed": seed, "noise_step": jnp.zeros((), jnp.int32)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        with jax.named_scope("block_diffusion_noise"):
+            clean = x.astype(jnp.int32)
+            if clean.ndim == 3 and clean.shape[-1] == 1:
+                clean = clean[..., 0]
+            rows, seq = clean.shape
+            if mask is not None:
+                raise ValueError("BlockDiffusionNoiseLayer takes whole rows: no padding mask")
+            if seq % self.block:
+                raise ValueError(f"{seq} positions are not whole blocks of {self.block}")
+            seed, step = state["noise_seed"], state["noise_step"]
+            masked, weights = block_diffusion_noise(
+                jax.random.fold_in(jax.random.key(seed), step), rows, seq, self.block,
+                self.t_min)
+            mask_id = params["W"].shape[0] - 1 if self.mask_id is None else self.mask_id
+            noised = jnp.where(masked, mask_id, clean)
+            y, _ = super().apply(params, state, jnp.concatenate([noised, clean], axis=1))
+        return y, {"noise_seed": seed, "noise_step": step + 1, LOSS_WEIGHTS: weights}
 
 
 @register_layer

@@ -98,6 +98,34 @@ class RnnOutputLayer(OutputLayer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True, kw_only=True)
+class BlockDiffusionOutputLayer(RnnOutputLayer):
+    """Block diffusion's head and masked-token loss — net-new (BD3-LM, MDLM;
+    no DL4J analog). Input: the stack's ``[batch, 2L, features]`` over
+    ``x_t || x_0`` (``BlockDiffusionNoiseLayer``); labels: an int32 class
+    index for each of the L positions. Only the noised half is scored: the
+    logit at noisy position i predicts label i, the clean half serves the
+    attention alone, and the head's products are L positions', not 2L. A row's
+    score is ``sum_i w_i CE(logits_i, label_i)`` with ``w`` the noising layer's
+    weights of the step (``1 / t`` at the masked positions, 0 elsewhere),
+    which the network hands ``score_from_features``; ``output()`` gives the
+    noised half's logits. Runs under the scope ``block_diffusion_loss``."""
+
+    def preout(self, params, x):
+        return super().preout(params, x[:, :x.shape[1] // 2])
+
+    def score_from_features(self, params, state, labels, features, mask=None, weights=None):
+        """(per-row score ``[batch]``, state) from the stack's output."""
+        if weights is None:
+            raise ValueError("BlockDiffusionOutputLayer scores with the noising layer's weights: "
+                             "put a BlockDiffusionNoiseLayer first")
+        with jax.named_scope("block_diffusion_loss"):
+            if mask is not None:
+                weights = weights * mask.reshape(weights.shape).astype(weights.dtype)
+            return self.score_from_preout(labels, self.preout(params, features), weights), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class LoopExitOutputLayer(Layer):
     """The exits of a looped stack — net-new (looped decoders with a learned
     exit; no DL4J analog). Input: every pass's state, stacked ``[times, batch,

@@ -26,7 +26,7 @@ from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.eval.evaluation import Evaluation
 from deeplearning4j_tpu.nn.conf.builders import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.base import (
-    checkpoint_layer, layer_loss_terms, scope_name as _scope_name,
+    checkpoint_layer, layer_loss_terms, scope_name as _scope_name, take_loss_weights,
 )
 from deeplearning4j_tpu.nn.layers.output import CenterLossOutputLayer
 # _unpack and global_norm_clip are imported from here by parallel/ and the
@@ -228,12 +228,19 @@ class MultiLayerNetwork(Network):
         else:
             preout, new_states, out_mask, features, new_carries = (
                 self._forward_carry(params, state, x, carries, True, rng, mask))
+        # per-position weights of the score a layer drew in this step (block
+        # diffusion's noising layer): for an output layer that scores its input
+        weights = take_loss_weights(new_states)
+        if weights is not None and not from_features:
+            raise ValueError(f"a layer hands the score per-position weights, which "
+                             f"{type(out_layer).__name__} does not take")
         with jax.named_scope("loss"):
             if label_mask is not None:
                 out_mask = label_mask
             if from_features:
                 per, new_states[-1] = out_layer.score_from_features(
-                    params[-1], state[-1], y, features, out_mask)
+                    params[-1], state[-1], y, features, out_mask,
+                    **({} if weights is None else {"weights": weights}))
             else:
                 per = out_layer.score_from_preout(y, preout, out_mask)
             if isinstance(out_layer, CenterLossOutputLayer):

@@ -19,14 +19,32 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.ops.registry import op, register_op
 
 
+def block_diffusion_mask(seq: int, block: int):
+    """Boolean ``[seq, seq]`` (query, key): block diffusion's mask over a noised
+    copy and the clean sequence side by side, ``x_t || x_0``, ``seq / 2``
+    positions each, in blocks of ``block``. A noisy query of block b sees the
+    noisy keys of block b (both ways) and the clean keys of blocks before b; a
+    clean query of block b the clean keys of blocks up to b; no clean query
+    sees a noisy key."""
+    half = seq // 2
+    pos = jnp.arange(seq)
+    clean = pos >= half
+    blk = (pos - jnp.where(clean, half, 0)) // block
+    q_clean, qb = clean[:, None], blk[:, None]
+    k_clean, kb = clean[None, :], blk[None, :]
+    return jnp.where(q_clean, k_clean & (kb <= qb), jnp.where(k_clean, kb < qb, kb == qb))
+
+
 @register_op("dot_product_attention")
 def dot_product_attention(q, k, v, *, mask=None, bias=None, scale=None,
-                          causal=False, window=None):
+                          causal=False, window=None, block_diffusion=None):
     """softmax(q k^T / sqrt(d)) v.
 
     k / v may hold fewer heads than q (a divisor of its count): query head h
     reads key-value head ``h // (N // Nkv)``. ``window`` (with ``causal``):
-    query i sees keys j with ``0 <= i - j < window``.
+    query i sees keys j with ``0 <= i - j < window``. ``block_diffusion`` (a
+    block length, not with ``causal``): ``block_diffusion_mask`` over the T
+    positions.
 
     mask: broadcastable to [B, N, Tq, Tk], 1=keep 0=drop (additive -inf applied).
     bias: broadcastable to [B, N, Tq, Tk], ADDED to the scaled logits before
@@ -46,6 +64,10 @@ def dot_product_attention(q, k, v, *, mask=None, bias=None, scale=None,
     if bias is not None:
         logits = logits + bias
     neg = jnp.finfo(logits.dtype).min
+    if block_diffusion is not None:
+        if causal or window is not None or logits.shape[-2] != logits.shape[-1]:
+            raise ValueError("the block-diffusion mask is square and neither causal nor banded")
+        logits = jnp.where(block_diffusion_mask(logits.shape[-1], block_diffusion), logits, neg)
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)

@@ -40,6 +40,20 @@ is the program it was before either existed.
 of 192, values of 128): v, do, o, dv and their accumulators then take v's
 width, q, k, dq and dk the keys'; no product is padded to the wider of the two.
 
+A third mask kind, ``block_diffusion`` (a block length), is block diffusion's
+over a noised copy and the clean sequence side by side, ``x_t || x_0``, T = 2L
+positions (``ops/attention.py::block_diffusion_mask``): a noisy query sees
+the noisy keys of its own block and the clean keys of the blocks before it, a
+clean query the clean keys of its block and of those before. Its tiles are
+static and few (at T = 16,384 in 512 x 512 tiles, 288 of 1,024 hold a visible
+pair), so the grid is flattened: ``(B*H, pairs)``, one step a visible tile, in
+the order of a table (``diffusion_pairs``) that scalar prefetch hands the
+index maps: q-blocks outer for the forward and dq, k-blocks outer for dk / dv,
+with the steps that open and close an outer block flagged. No step visits a
+tile without a visible pair. The calls carry the kind in their names
+(``flash_attention_block_diffusion_fwd`` ...) and run under the scope
+``block_diffusion``.
+
 Block-level primitives ``flash_block_fwd`` / ``flash_block_bwd`` are exposed
 for ring attention (parallel/sequence.py): the ring merges per-step (o, lse)
 pairs online and runs the backward with the *global* lse, so sequence-
@@ -102,28 +116,124 @@ def _kv_row(group):
 
 
 # --------------------------------------------------------------------------
+# the block-diffusion mask's tiles
+# --------------------------------------------------------------------------
+
+#: bits of a step's flag in a ``diffusion_pairs`` table: the step is the first
+#: of its outer block, the last of it, and (k-blocks outer) the first to reach
+#: its q-block, where the fused backward zeroes that block's rows of dq
+OPENS, CLOSES, FIRST_SIGHT = 1, 2, 4
+
+
+def _diffusion_key_spans(lo, hi, half, block):
+    """The key positions (``[first, last]`` spans) that queries ``lo .. hi`` of
+    the block-diffusion mask over ``2 * half`` positions see, together."""
+    spans = []
+    if lo < half:                       # noisy queries lo .. top
+        top = min(hi, half - 1)
+        spans.append(((lo // block) * block, (top // block) * block + block - 1))
+        if top // block:                # the clean blocks before the last one's
+            spans.append((half, half + (top // block) * block - 1))
+    if hi >= half:                      # clean queries: every clean block up to hi's
+        spans.append((half, half + ((hi - half) // block) * block + block - 1))
+    return spans
+
+
+def diffusion_tiles(seq, block_q, block_k, block):
+    """Boolean ``[q-blocks, k-blocks]``: which tiles of the block-diffusion mask
+    over ``seq`` positions (``seq / 2`` noisy then as many clean, blocks of
+    ``block``) hold a visible (query, key) pair."""
+    import numpy as np
+
+    half = seq // 2
+    nq, nk = -(-seq // block_q), -(-seq // block_k)
+    seen = np.zeros((nq, nk), bool)
+    for i in range(nq):
+        for first, last in _diffusion_key_spans(i * block_q, min(seq, (i + 1) * block_q) - 1,
+                                                half, block):
+            seen[i, first // block_k:last // block_k + 1] = True
+    return seen
+
+
+def diffusion_pairs(seq, block_q, block_k, block, q_outer=True):
+    """The visible tiles in grid order, as three int32 arrays for scalar
+    prefetch: each step's q-block, its k-block and its flags (``OPENS``,
+    ``CLOSES``, ``FIRST_SIGHT``). ``q_outer``: q-blocks outer (the forward,
+    dq), else k-blocks outer (dk / dv)."""
+    import numpy as np
+
+    seen = diffusion_tiles(seq, block_q, block_k, block)
+    qs, ks = np.nonzero(seen if q_outer else seen.T)
+    if not q_outer:
+        qs, ks = ks, qs
+    outer, inner = (qs, ks) if q_outer else (ks, qs)
+    flags = np.zeros(len(qs), np.int32)
+    flags[np.r_[True, outer[1:] != outer[:-1]]] |= OPENS
+    flags[np.r_[outer[1:] != outer[:-1], True]] |= CLOSES
+    _, first = np.unique(inner, return_index=True)
+    flags[first] |= FIRST_SIGHT
+    return qs.astype(np.int32), ks.astype(np.int32), flags
+
+
+def _diffusion_visible(qi, ki, *, block_q, block_k, half, block):
+    """``[bq, bk]`` block-diffusion mask of tile (qi, ki): a query sees a clean
+    key whose block is at most ``limit`` (the last clean block the query sees)
+    and a noisy key whose block is ``own`` (the noisy block it sees, -1:
+    none). Keys carry their block twice, as a clean one (a noisy key's is past
+    every limit) and as a noisy one (a clean key's, -2, is no one's own), so
+    that the row and column values are vectors and only two comparisons and
+    an ``or`` are tile-wide. A key past ``2 * half`` is clean and past every
+    limit."""
+    shift = block.bit_length() - 1
+    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    q_clean, k_clean = qpos >= half, kpos >= half
+    qb = (qpos - jnp.where(q_clean, half, 0)) >> shift
+    kb = (kpos - jnp.where(k_clean, half, 0)) >> shift
+    limit = jnp.where(q_clean, qb, qb - 1)
+    own = jnp.where(q_clean, -1, qb)
+    as_clean = jnp.where(k_clean, kb, 1 << 30)
+    as_noisy = jnp.where(k_clean, -2, kb)
+    return (as_clean <= limit) | (as_noisy == own)
+
+
+def _diffusion_step(tables):
+    """(q-block, k-block, flags) of this grid step, from the prefetched tables."""
+    q_tab, k_tab, flag_tab = tables
+    step = pl.program_id(1)
+    return q_tab[step], k_tab[step], flag_tab[step]
+
+
+# --------------------------------------------------------------------------
 # forward kernel
 # --------------------------------------------------------------------------
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
-                  seq_k, has_kmask, window=None):
+def _flash_kernel(*refs, causal, scale, block_q, block_k, seq_k, has_kmask, window=None,
+                  diffusion=None):
+    if diffusion is not None:
+        qi, ki, flags = _diffusion_step(refs[:3])
+        refs = refs[3:]
+    q_ref, k_ref, v_ref, *rest = refs
     if has_kmask:
         km_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         km_ref = None
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    steps = pl.num_programs(2)
+    if diffusion is None:
+        qi = pl.program_id(1)
+        step = pl.program_id(2)
+        steps = pl.num_programs(2)
 
-    @pl.when(step == 0)
+    @pl.when(step == 0 if diffusion is None else (flags & OPENS) != 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    if window is None:
+    if diffusion is not None:
+        visible = True              # the table lists visible tiles alone
+    elif window is None:
         ki = step
         # causal block skipping: a k-block whose first key is past this
         # q-block's last query contributes nothing — skip its FLOPs entirely
@@ -145,10 +255,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
         k = k_ref[0]                                      # [bk, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                       (block_q, block_k), 1)
-        # mask the ragged tail block (out-of-bounds key columns read padding)
-        s = jnp.where(kpos < seq_k, s, -jnp.inf)
+        if diffusion is None:
+            kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
+                                                           (block_q, block_k), 1)
+            # mask the ragged tail block (out-of-bounds key columns read padding)
+            s = jnp.where(kpos < seq_k, s, -jnp.inf)
+        else:
+            # the mask hides the padding keys too: they are clean and past every block
+            s = jnp.where(_diffusion_visible(qi, ki, block_q=block_q, block_k=block_k,
+                                             half=seq_k // 2, block=diffusion), s, -jnp.inf)
         if km_ref is not None:
             # key-padding mask [1, bk]: broadcast over the q rows. The
             # existing -inf machinery (m_safe / p guard / lse=+inf) already
@@ -178,7 +293,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
-    @pl.when(step == steps - 1)
+    @pl.when(step == steps - 1 if diffusion is None else (flags & CLOSES) != 0)
     def _finalize():
         l = l_scr[:]
         o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -189,7 +304,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, block_q, block_k,
 
 
 def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
-                   kmask=None, vma=None, window=None):
+                   kmask=None, vma=None, window=None, diffusion=None):
     """Returns (out [B,H,Tq,Dv], lse [B,H,Tq,1] float32). ``k`` is ``[B, Hkv,
     Tk, D]`` and ``v`` ``[B, Hkv, Tk, Dv]`` with ``Hkv`` dividing ``H``.
 
@@ -206,7 +321,14 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
     vf = v.reshape(B * Hkv, Tk, Dv)
     nq, nk = pl.cdiv(Tq, bq), pl.cdiv(Tk, bk)
     kv_row = _kv_row(H // Hkv)
-    if window is None:
+    q_block, tables = (lambda i, j: i), None
+    if diffusion is not None:
+        # the grid's second axis is the table's steps; an index map reads it
+        tables = diffusion_pairs(Tq, bq, bk, diffusion)
+        steps = None
+        q_block = lambda p, q_tab, k_tab, flags: q_tab[p]   # noqa: E731
+        k_block = lambda p, q_tab, k_tab, flags: k_tab[p]   # noqa: E731
+    elif window is None:
         steps, k_block = nk, lambda i, j: j
     else:
         band = (bq, bk, nk, window - 1, 0)
@@ -216,13 +338,12 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
             first, last = _band_blocks(i, *band)
             return jnp.minimum(first + j, last)
 
-    grid = (B * H, nq, steps)
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
+        pl.BlockSpec((1, bq, D), lambda b, *g: (b, q_block(*g), 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (kv_row(b), k_block(i, j), 0),
+        pl.BlockSpec((1, bk, D), lambda b, *g: (kv_row(b), k_block(*g), 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, Dv), lambda b, i, j: (kv_row(b), k_block(i, j), 0),
+        pl.BlockSpec((1, bk, Dv), lambda b, *g: (kv_row(b), k_block(*g), 0),
                      memory_space=pltpu.VMEM),
     ]
     operands = [qf, kf, vf]
@@ -231,32 +352,37 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
         # (8, 128)-divisibility rule applies to the last two dims and a
         # middle dim of exactly 1 satisfies the equal-to-array case
         in_specs.append(pl.BlockSpec((1, 1, bk),
-                                     lambda b, i, j: (b // H, 0, k_block(i, j)),
+                                     lambda b, *g: (b // H, 0, k_block(*g)),
                                      memory_space=pltpu.VMEM))
         operands.append(kmask.astype(jnp.float32).reshape(B, 1, Tk))
+    out_specs = (
+        pl.BlockSpec((1, bq, Dv), lambda b, *g: (b, q_block(*g), 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bq, 1), lambda b, *g: (b, q_block(*g), 0),
+                     memory_space=pltpu.VMEM),
+    )
+    scratch_shapes = [
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, Dv), jnp.float32),
+    ]
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, causal=causal, scale=scale,
                           block_q=bq, block_k=bk, seq_k=Tk,
-                          has_kmask=kmask is not None, window=window),
-        name="flash_attention_fwd",
+                          has_kmask=kmask is not None, window=window, diffusion=diffusion),
+        name=_kernel_name("fwd", diffusion),
         out_shape=(_sds((B * H, Tq, Dv), q.dtype, vma),
                    _sds((B * H, Tq, 1), jnp.float32, vma)),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
-        ],
         interpret=interpret,
-    )(*operands)
+        **_layout((B * H, nq, steps), in_specs, out_specs, scratch_shapes, tables),
+    )(*_with_tables(tables, operands))
     return out.reshape(B, H, Tq, Dv), lse.reshape(B, H, Tq, 1)
+
+
+def _kernel_name(part, diffusion):
+    """``flash_attention_<part>``; the block-diffusion mask's calls say so."""
+    return f"flash_attention_{part}" if diffusion is None else \
+        f"flash_attention_block_diffusion_{part}"
 
 
 # --------------------------------------------------------------------------
@@ -265,7 +391,7 @@ def _flash_forward(q, k, v, *, causal, scale, block_q, block_k, interpret,
 
 
 def _recompute_p(q_ref, k_ref, lse_ref, km_ref, *, qi, ki, causal, scale,
-                 block_q, block_k, seq_q, seq_k, window=None):
+                 block_q, block_k, seq_q, seq_k, window=None, diffusion=None):
     """Recompute one [bq, bk] probability tile exp(s - lse), fully masked."""
     q = q_ref[0]
     k = k_ref[0]
@@ -274,6 +400,14 @@ def _recompute_p(q_ref, k_ref, lse_ref, km_ref, *, qi, ki, causal, scale,
     s = scale * jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
     p = jnp.exp(s - lse_ref[0])                           # lse [bq, 1]
+    if diffusion is not None:
+        # the mask hides the padding keys; the padding queries by their rows
+        qrow = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        valid = _diffusion_visible(qi, ki, block_q=block_q, block_k=block_k,
+                                   half=seq_k // 2, block=diffusion) & (qrow < seq_q)
+        if km_ref is not None:
+            valid &= km_ref[0] > 0
+        return jnp.where(valid, p, 0.0), k, valid
     qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_k), 0)
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
@@ -288,23 +422,29 @@ def _recompute_p(q_ref, k_ref, lse_ref, km_ref, *, qi, ki, causal, scale,
     return jnp.where(valid, p, 0.0), k, valid
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                     causal, scale, block_q, block_k, seq_q, seq_k, has_kmask,
-                     window=None):
+def _flash_dq_kernel(*refs, causal, scale, block_q, block_k, seq_q, seq_k, has_kmask,
+                     window=None, diffusion=None):
+    if diffusion is not None:
+        qi, ki, flags = _diffusion_step(refs[:3])
+        refs = refs[3:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
     if has_kmask:
         km_ref, dq_ref, dq_scr = rest
     else:
         km_ref = None
         dq_ref, dq_scr = rest
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    steps = pl.num_programs(2)
+    if diffusion is None:
+        qi = pl.program_id(1)
+        step = pl.program_id(2)
+        steps = pl.num_programs(2)
 
-    @pl.when(step == 0)
+    @pl.when(step == 0 if diffusion is None else (flags & OPENS) != 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    if window is None:
+    if diffusion is not None:
+        visible = True
+    elif window is None:
         ki = step
         visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
     else:
@@ -318,7 +458,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         p, k, valid = _recompute_p(q_ref, k_ref, lse_ref, km_ref, qi=qi, ki=ki,
                                    causal=causal, scale=scale, block_q=block_q,
                                    block_k=block_k, seq_q=seq_q, seq_k=seq_k,
-                                   window=window)
+                                   window=window, diffusion=diffusion)
         do = do_ref[0]
         v = v_ref[0]
         vrow = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
@@ -330,32 +470,37 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(step == steps - 1)
+    @pl.when(step == steps - 1 if diffusion is None else (flags & CLOSES) != 0)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                      causal, scale, block_q, block_k, seq_q, seq_k,
-                      has_kmask, with_dq, window=None):
+def _flash_dkv_kernel(*refs, causal, scale, block_q, block_k, seq_q, seq_k,
+                      has_kmask, with_dq, window=None, diffusion=None):
     """dk and dv of one k-block, summed over its visible q-blocks; with
     ``with_dq`` dq too, from the same tiles: ``dq_ref`` is then the head's
     whole [Tq, D] float32, resident across every (ki, qi), and each tile adds
     its product to the rows of its q-block."""
-    rest = list(rest)
+    if diffusion is not None:
+        qi, ki, flags = _diffusion_step(refs[:3])
+        refs = refs[3:]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest = refs
     km_ref = rest.pop(0) if has_kmask else None
     dq_ref = rest.pop(0) if with_dq else None
     dk_ref, dv_ref, dk_scr, dv_scr = rest
-    ki = pl.program_id(1)
-    step = pl.program_id(2)
-    steps = pl.num_programs(2)
+    if diffusion is None:
+        ki = pl.program_id(1)
+        step = pl.program_id(2)
+        steps = pl.num_programs(2)
 
-    @pl.when(step == 0)
+    @pl.when(step == 0 if diffusion is None else (flags & OPENS) != 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if window is None:
+    if diffusion is not None:
+        first_sight = (flags & FIRST_SIGHT) != 0
+    elif window is None:
         # k-block 0 comes first for every q-block, and no mask hides it whole
         qi, first_sight = step, ki == 0
     else:
@@ -377,7 +522,9 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             dq_ref[0, rows, :] = jnp.zeros((block_q, dq_ref.shape[-1]),
                                            dq_ref.dtype)
 
-    if window is not None:
+    if diffusion is not None:
+        visible = True
+    elif window is not None:
         visible = in_band
     else:
         visible = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
@@ -387,7 +534,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         p, k, valid = _recompute_p(q_ref, k_ref, lse_ref, km_ref, qi=qi, ki=ki,
                                    causal=causal, scale=scale, block_q=block_q,
                                    block_k=block_k, seq_q=seq_q, seq_k=seq_k,
-                                   window=window)
+                                   window=window, diffusion=diffusion)
         q = q_ref[0]
         qrow = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
         q = jnp.where(qrow < seq_q, q, jnp.zeros((), q.dtype))
@@ -414,14 +561,14 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    @pl.when(step == steps - 1)
+    @pl.when(step == steps - 1 if diffusion is None else (flags & CLOSES) != 0)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
-                       interpret, kmask=None, vma=None, window=None):
+                       interpret, kmask=None, vma=None, window=None, diffusion=None):
     """The backward at ``tiles`` (a ``BwdTiles``). Fused: one call on the
     dk/dv kernel's grid, every visible tile worked once. Else a dq call
     (q-blocks outer) and a dk/dv call (k-blocks outer), each working every
@@ -441,21 +588,35 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
         operands.append(kmask.astype(jnp.float32).reshape(B, 1, Tk))
     kernel_kw = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
                      seq_q=Tq, seq_k=Tk, has_kmask=kmask is not None,
-                     window=window)
-    if window is None:
-        q_steps, k_steps = nq, nk
-        q_block = k_block = lambda outer, step: step
+                     window=window, diffusion=diffusion)
+    # the k-block and the q-block of a step of the dk/dv call's grid (its
+    # k-blocks outer, ``dkv_*``) and of the dq call's (q-blocks outer, ``dq_*``)
+    if diffusion is not None:
+        # a step of the table: the same lookup on either call's own table
+        tables = {"by_k": diffusion_pairs(Tq, bq, bk, diffusion, q_outer=False),
+                  "by_q": diffusion_pairs(Tq, bq, bk, diffusion)}
+        q_steps = k_steps = None
+        dkv_k = dq_k = lambda p, q_tab, k_tab, flags: k_tab[p]     # noqa: E731
+        dkv_q = dq_q = lambda p, q_tab, k_tab, flags: q_tab[p]     # noqa: E731
     else:
-        q_band, k_band = (bk, bq, nq, 0, window - 1), (bq, bk, nk, window - 1, 0)
-        q_steps, k_steps = _band_steps(nk, *q_band), _band_steps(nq, *k_band)
+        tables = {"by_k": None, "by_q": None}
+        if window is None:
+            q_steps, k_steps = nq, nk
+            q_block = k_block = lambda outer, step: step
+        else:
+            q_band, k_band = (bk, bq, nq, 0, window - 1), (bq, bk, nk, window - 1, 0)
+            q_steps, k_steps = _band_steps(nk, *q_band), _band_steps(nq, *k_band)
 
-        def q_block(j, step):       # the q-blocks of k-block j's band, clamped
-            first, last = _band_blocks(j, *q_band)
-            return jnp.minimum(first + step, last)
+            def q_block(j, step):       # the q-blocks of k-block j's band, clamped
+                first, last = _band_blocks(j, *q_band)
+                return jnp.minimum(first + step, last)
 
-        def k_block(i, step):       # the k-blocks of q-block i's band, clamped
-            first, last = _band_blocks(i, *k_band)
-            return jnp.minimum(first + step, last)
+            def k_block(i, step):       # the k-blocks of q-block i's band, clamped
+                first, last = _band_blocks(i, *k_band)
+                return jnp.minimum(first + step, last)
+
+        dkv_k, dkv_q = (lambda j, i: j), q_block
+        dq_q, dq_k = (lambda i, j: i), k_block
 
     def float32(rows, width=D):
         return _sds((B * H, rows, width), jnp.float32, vma)
@@ -472,21 +633,21 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
     # by what XLA's memory-space assignment did around it (alone at
     # T >= 8,192 it never did). The second buffer bought overlap once per
     # k-block only.
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, q_block(j, i), 0),
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, *g: (b, dkv_q(*g), 0),
                           memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (kv_row(b), j, 0),
+    k_spec = pl.BlockSpec((1, bk, D), lambda b, *g: (kv_row(b), dkv_k(*g), 0),
                           memory_space=pltpu.VMEM,
                           pipeline_mode=pl.Buffered(1))
     # dk and dv of one query head: the group's are summed below
-    dk_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
+    dk_spec = pl.BlockSpec((1, bk, D), lambda b, *g: (b, dkv_k(*g), 0),
                            memory_space=pltpu.VMEM,
                            pipeline_mode=pl.Buffered(1))
-    row_spec = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, q_block(j, i), 0),
+    row_spec = pl.BlockSpec((1, bq, 1), lambda b, *g: (b, dkv_q(*g), 0),
                             memory_space=pltpu.VMEM)
     in_specs = [q_spec, k_spec, as_v(k_spec), as_v(q_spec), row_spec, row_spec]
     if kmask is not None:
         in_specs.append(pl.BlockSpec((1, 1, bk),
-                                     lambda b, j, i: (b // H, 0, j),
+                                     lambda b, *g: (b // H, 0, dkv_k(*g)),
                                      memory_space=pltpu.VMEM))
     out_shape, out_specs = [float32(Tk), float32(Tk, Dv)], [dk_spec, as_v(dk_spec)]
     if tiles.fused:
@@ -495,48 +656,44 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
         # is masked there) and are cut off below
         out_shape.insert(0, float32(nq * bq))
         out_specs.insert(0, pl.BlockSpec((1, nq * bq, D),
-                                         lambda b, j, i: (b, 0, 0),
+                                         lambda b, *g: (b, 0, 0),
                                          memory_space=pltpu.VMEM,
                                          pipeline_mode=pl.Buffered(1)))
     *dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, with_dq=tiles.fused, **kernel_kw),
-        name="flash_attention_bwd" if tiles.fused else "flash_attention_bwd_dkv",
+        name=_kernel_name("bwd" if tiles.fused else "bwd_dkv", diffusion),
         out_shape=out_shape,
-        grid=(B * H, nk, q_steps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
-    )(*operands)
+        **_layout((B * H, nk, q_steps), in_specs, out_specs,
+                  [pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, Dv), jnp.float32)],
+                  tables["by_k"]),
+    )(*_with_tables(tables["by_k"], operands))
 
     if tiles.fused:
         dq, = dq
         if nq * bq != Tq:
             dq = dq[:, :Tq]
     else:
-        q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
+        q_spec = pl.BlockSpec((1, bq, D), lambda b, *g: (b, dq_q(*g), 0),
                               memory_space=pltpu.VMEM)
         k_spec = pl.BlockSpec((1, bk, D),
-                              lambda b, i, j: (kv_row(b), k_block(i, j), 0),
+                              lambda b, *g: (kv_row(b), dq_k(*g), 0),
                               memory_space=pltpu.VMEM)
-        row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
+        row_spec = pl.BlockSpec((1, bq, 1), lambda b, *g: (b, dq_q(*g), 0),
                                 memory_space=pltpu.VMEM)
         in_specs = [q_spec, k_spec, as_v(k_spec), as_v(q_spec), row_spec, row_spec]
         if kmask is not None:
             in_specs.append(pl.BlockSpec((1, 1, bk),
-                                         lambda b, i, j: (b // H, 0, k_block(i, j)),
+                                         lambda b, *g: (b // H, 0, dq_k(*g)),
                                          memory_space=pltpu.VMEM))
         dq = pl.pallas_call(
             functools.partial(_flash_dq_kernel, **kernel_kw),
-            name="flash_attention_bwd_dq",
+            name=_kernel_name("bwd_dq", diffusion),
             out_shape=float32(Tq),
-            grid=(B * H, nq, k_steps),
-            in_specs=in_specs,
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interpret,
-        )(*operands)
+            **_layout((B * H, nq, k_steps), in_specs, q_spec,
+                      [pltpu.VMEM((bq, D), jnp.float32)], tables["by_q"]),
+        )(*_with_tables(tables["by_q"], operands))
     if Hkv != H:
         # a key-value head's gradient is the sum over its group of query heads
         dk, dv = (t.reshape(B, Hkv, H // Hkv, Tk, t.shape[-1]).sum(axis=2) for t in (dk, dv))
@@ -544,8 +701,24 @@ def _flash_backward_at(tiles, q, k, v, do, lse, delta, *, causal, scale,
             dv.reshape(B, Hkv, Tk, Dv))
 
 
+def _layout(grid, in_specs, out_specs, scratch_shapes, tables=None):
+    """A ``pallas_call``'s grid and blocks: as they are, or with ``tables`` (a
+    ``diffusion_pairs``) prefetched, the grid's second axis then the table's
+    steps and its third none."""
+    if tables is None:
+        return dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
+                    scratch_shapes=scratch_shapes)
+    return dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(tables), grid=(grid[0], len(tables[0])), in_specs=in_specs,
+        out_specs=out_specs, scratch_shapes=scratch_shapes))
+
+
+def _with_tables(tables, operands):
+    return list(operands) if tables is None else [jnp.asarray(t) for t in tables] + list(operands)
+
+
 def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
-                    block_k, interpret, kmask=None, vma=None, window=None):
+                    block_k, interpret, kmask=None, vma=None, window=None, diffusion=None):
     """O(T*D)-memory flash backward. lse/delta: [B,H,Tq,1] float32.
 
     ``block_q`` / ``block_k`` are the least tiles the caller wants;
@@ -555,7 +728,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q,
                       q.dtype.itemsize, v.shape[-1])
     return _flash_backward_at(tiles, q, k, v, do, lse, delta, causal=causal,
                               scale=scale, interpret=interpret, kmask=kmask,
-                              vma=vma, window=window)
+                              vma=vma, window=window, diffusion=diffusion)
 
 
 # --------------------------------------------------------------------------
@@ -585,16 +758,16 @@ def flash_block_bwd(q, k, v, do, lse, delta, *, causal, scale,
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, kmask, causal, scale, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, kmask, causal, scale, block_q, block_k, window, diffusion):
     out, _ = _flash_forward(q, k, v, causal=causal, scale=scale,
                             block_q=block_q, block_k=block_k,
                             interpret=interpret_mode(), kmask=kmask,
-                            window=window)
+                            window=window, diffusion=diffusion)
     return out
 
 
-def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k, window):
+def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k, window, diffusion):
     """The forward under differentiation: ``out`` and the residuals the
     backward kernels read. ``out`` and ``lse`` carry names, so a
     ``jax.checkpoint`` whose policy keeps them (``checkpoint_layer``) stores
@@ -605,7 +778,7 @@ def _flash_fwd(q, k, v, kmask, causal, scale, block_q, block_k, window):
     out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
                               block_q=block_q, block_k=block_k,
                               interpret=interpret_mode(), kmask=kmask,
-                              window=window)
+                              window=window, diffusion=diffusion)
     # the named ``out`` is the primal result too: whatever reads it downstream
     # (the output projection's weight gradient) then reads the kept value
     out, lse = checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
@@ -681,7 +854,7 @@ def bwd_tiles(block_q, block_k, head_dim, seq_q, seq_k, itemsize, v_dim=None):
     return BwdTiles(min(bq, seq_q), min(bk, seq_k), False)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, window, diffusion, res, g):
     # flash backward: only [bq, bk] probability tiles are ever materialized,
     # recomputed from the saved logsumexp — HBM stays O(T*D), which is what
     # makes long-context *training* (not just inference) sub-quadratic
@@ -691,7 +864,7 @@ def _flash_bwd(causal, scale, block_q, block_k, window, res, g):
     dq, dk, dv = _flash_backward(q, k, v, g, lse, delta, causal=causal,
                                  scale=scale, block_q=block_q,
                                  block_k=block_k, interpret=interpret_mode(),
-                                 kmask=kmask, window=window)
+                                 kmask=kmask, window=window, diffusion=diffusion)
     dkm = None if kmask is None else jnp.zeros_like(kmask)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype), dkm
 
@@ -734,14 +907,17 @@ def _is_key_padding(mask, q, k):
 
 
 def flash_attention(q, k, v, *, mask=None, bias=None, scale=None,
-                    causal=False, window=None, block_q: int = 512,
-                    block_k: int = 1024):
+                    causal=False, window=None, block_diffusion=None,
+                    block_q: int = 512, block_k: int = 1024):
     """Public entry: same signature as the XLA dot_product_attention.
 
     ``k`` / ``v`` may hold fewer heads than ``q`` (a divisor of its count):
     query head h reads key-value head ``h // (H // Hkv)``. ``window`` (with
     ``causal``): query i sees keys j with ``0 <= i - j < window``. ``v`` may
-    have a width of its own; the result has it.
+    have a width of its own; the result has it. ``block_diffusion`` (a power
+    of two dividing ``T / 2``, not with ``causal``): the block-diffusion mask
+    over a noised copy and the clean sequence, at tiles of no more than
+    ``DIFFUSION_TILE`` keys, under the scope ``block_diffusion``.
 
     Default tiles are the v5e sweet spot measured at T=8192 (fwd 512x1024;
     the backward's come from ``bwd_tiles``): small 128-tiles leave >2x on the
@@ -761,19 +937,41 @@ def flash_attention(q, k, v, *, mask=None, bias=None, scale=None,
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if window is not None and not causal:
         raise ValueError("flash_attention's window is a causal one: pass causal=True")
-    return _flash(q, k, v, km, causal, float(scale), block_q, block_k,
-                  None if window is None else int(window))
+    if block_diffusion is None:
+        return _flash(q, k, v, km, causal, float(scale), block_q, block_k,
+                      None if window is None else int(window), None)
+    if not _diffusion_shapes_ok(q, k, block_diffusion) or causal or window is not None:
+        raise ValueError(f"block_diffusion={block_diffusion} over {q.shape[-2]} queries and "
+                         f"{k.shape[-2]} keys: needs as many of each, a power of two dividing "
+                         "half of them, and neither causal nor a window")
+    with jax.named_scope("block_diffusion"):
+        return _flash(q, k, v, km, False, float(scale), block_q, min(block_k, DIFFUSION_TILE),
+                      None, int(block_diffusion))
+
+
+#: the most keys a block-diffusion forward tile holds: at 512 x 512 a q-block's
+#: visible tiles are those the mask reaches at all (288 of 1,024 at T = 16,384)
+DIFFUSION_TILE = 512
+
+
+def _diffusion_shapes_ok(q, k, block) -> bool:
+    seq = q.shape[-2]
+    return (seq == k.shape[-2] and seq % 2 == 0 and block > 0 and block & (block - 1) == 0
+            and (seq // 2) % block == 0)
 
 
 def _flash_requires(q, k, v, *, mask=None, scale=None, causal=False,
-                    window=None, **kw):
+                    window=None, block_diffusion=None, **kw):
     # structural: masks are supported iff they reduce to a key-padding mask
     # over Tk; the kernel's causal mask is start-aligned (query i sees keys
     # <= i) which only matches the XLA lowering's end-aligned tril when
     # Tq == Tk. Additive logit biases (the import optimizer's fused
     # exporter-mask form) are not expressible in the kernel — XLA lowering.
     # A window is a band under the causal diagonal; k and v may hold a
-    # divisor of q's heads.
+    # divisor of q's heads. The block-diffusion mask takes no other mask.
+    if block_diffusion is not None and (mask is not None or causal or window is not None
+                                        or not _diffusion_shapes_ok(q, k, block_diffusion)):
+        return False
     return (kw.get("bias") is None
             and _is_key_padding(mask, q, k)
             and (not causal or q.shape[-2] == k.shape[-2])

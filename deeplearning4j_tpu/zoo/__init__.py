@@ -26,11 +26,12 @@ from deeplearning4j_tpu.zoo.ouro import Ouro
 from deeplearning4j_tpu.zoo.mellum2 import Mellum2
 from deeplearning4j_tpu.zoo.kimi_linear import KimiLinear
 from deeplearning4j_tpu.zoo.keye_vl2 import KeyeVL2
+from deeplearning4j_tpu.zoo.sdar import SDAR
 
 __all__ = [
     "ZooModel", "LeNet", "AlexNet", "SimpleCNN", "VGG16", "VGG19", "ResNet50",
     "Darknet19", "TinyYOLO", "YOLO2", "SqueezeNet", "Xception", "UNet",
     "InceptionResNetV1", "NASNet",
     "TextGenerationLSTM", "BidirectionalGravesLSTMCharRnn", "Bert", "BertBase",
-    "Ouro", "Mellum2", "KimiLinear", "KeyeVL2",
+    "Ouro", "Mellum2", "KimiLinear", "KeyeVL2", "SDAR",
 ]

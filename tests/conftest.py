@@ -118,6 +118,13 @@ SCOPED_METRIC_CASES = {
     "sparse_attention_kernel_roofline": "test_keye_vl2_cell.py",
     "sparse_tiles_visited_pct": "test_keye_vl2_cell.py",
     "indexer_kernel_roofline": "test_keye_vl2_cell.py",
+    "block_diffusion_attention_ms": "test_sdar_cell.py",
+    "block_diffusion_attention_roofline": "test_sdar_cell.py",
+    "diffusion_loss_ms": "test_sdar_cell.py",
+    "diffusion_moe_ms": "test_sdar_cell.py",
+    "diffusion_moe_route_ms": "test_sdar_cell.py",
+    "diffusion_expert_matmul_roofline": "test_sdar_cell.py",
+    "diffusion_moe_load_max_over_mean": "test_sdar_cell.py",
 }
 
 #: the set-up metrics every cell reports, added after each of these modules
@@ -175,7 +182,7 @@ def _metrics_that_list_another_cell(request, monkeypatch):
         monkeypatch.setitem(module.MANIFEST, "per_layer", [
             m for m in module.MANIFEST["per_layer"]
             if harness._reports(m, module.CELL, module.MANIFEST)])
-    elif name in ("test_ouro_cell", "test_kimi_linear_cell"):
+    elif name in ("test_ouro_cell", "test_kimi_linear_cell", "test_keye_vl2_cell"):
         # each holds its cell, its configuration and its metrics to be the
         # manifest's last: its copy is cut back to the manifest as its PR left
         # it (the cells up to its own, the metrics that list one of them)

@@ -911,6 +911,35 @@ class TestFlashBackwardCompilesForTheV5e:
         assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
     @pytest.mark.parametrize("fused,calls", [(True, 1), (False, 2)], ids=["fused", "two_calls"])
+    def test_the_block_diffusion_mask_compiles_at_the_diffusion_cells_shape(self, one_chip, fused,
+                                                                            calls):
+        """(1, 32 query heads over 4 key-value heads, 2 x 8,192, 128), blocks of 4:
+        the forward at 512 x 512 tiles and the backward in both layouts, each a
+        grid of the visible tiles alone with the tables in scalar memory; the
+        fused layout is the one ``bwd_tiles`` gives the shape."""
+        B, H, Hkv, T, D = 1, 32, 4, 16384, 128
+        tiles = flash_module.bwd_tiles(512, 512, D, T, T, 2)
+        assert tiles == (512, 512, True)
+        if not fused:
+            tiles = flash_module.BwdTiles(1024, 1024, False)
+
+        def sds(heads, last, dtype=jnp.bfloat16):
+            return jax.ShapeDtypeStruct((B, heads, T, last), dtype, sharding=one_chip)
+
+        q, kv, row = sds(H, D), sds(Hkv, D), sds(H, 1, jnp.float32)
+        backward = jax.jit(functools.partial(
+            flash_module._flash_backward_at, tiles, causal=False, scale=D ** -0.5,
+            interpret=False, diffusion=4)).lower(q, kv, kv, q, row, row).compile()
+        assert backward.as_text().count('custom_call_target="tpu_custom_call"') == calls
+        assert [o.shape for o in jax.tree.leaves(backward.out_info)] == [
+            (B, H, T, D), (B, Hkv, T, D), (B, Hkv, T, D)]
+        forward = jax.jit(functools.partial(
+            flash_module._flash_forward, causal=False, scale=D ** -0.5, block_q=512,
+            block_k=flash_module.DIFFUSION_TILE, interpret=False, diffusion=4)).lower(
+                q, kv, kv).compile()
+        assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+    @pytest.mark.parametrize("fused,calls", [(True, 1), (False, 2)], ids=["fused", "two_calls"])
     def test_keys_of_192_and_values_of_128_compile_at_the_latent_layers_shape(
             self, one_chip, fused, calls):
         """(1, 32, 8,192, 192 / 128): the forward and the backward in both
